@@ -8,8 +8,10 @@ Pallas kernel compiles natively or must run interpreted, and which NMS
 selection backend is the default. The DV201 lint rule
 (lint/distlint.py) now fails any such comparison OUTSIDE this module;
 routing decisions read a `BackendProfile` instead, so adding a new
-PJRT platform (or re-tuning what 'gpu' means once Mosaic-GPU lands) is
-one table row here, not a grep across the tree.
+PJRT platform is one table row here, not a grep across the tree. A
+platform WITHOUT a row is an error naming it: routing an unknown device
+like the CPU (interpreted Pallas, lax NMS) would run, and would hide the
+device from every number measured on it.
 
 Deliberately NOT wrapped: telemetry/fingerprint call sites that only
 RECORD the platform string (obs/journal.py run manifests, excache
@@ -27,9 +29,14 @@ from typing import Dict
 __all__ = [
     "BackendProfile",
     "BACKENDS",
+    "DevicePeaks",
+    "DEVICE_PEAKS",
+    "device_peaks",
     "current_platform",
     "get_backend",
     "is_tpu",
+    "local_tpu_chips",
+    "backend_initialized",
     "pallas_interpret",
     "default_nms_impl",
 ]
@@ -53,13 +60,34 @@ BACKENDS: Dict[str, BackendProfile] = {
                           nms_impl="pallas"),
     "cpu": BackendProfile(name="cpu", pallas_compiled=False,
                           nms_impl="lax"),
-    "gpu": BackendProfile(name="gpu", pallas_compiled=False,
-                          nms_impl="lax"),
 }
 
-#: any platform without a curated row (plugin PJRT backends) routes
-#: like CPU: interpret Pallas, lax NMS — slow beats wrong.
-_FALLBACK = BACKENDS["cpu"]
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks a utilisation or roofline share divides by."""
+
+    bf16_flops: float  # FLOP/s
+    hbm_bytes_per_s: float
+
+
+#: the ONE peaks table, keyed by jax's `device_kind`. Source: Google Cloud
+#: documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM per chip (jax
+#: reports a v5e chip as "TPU v5 lite"). A device that is not here is an
+#: error, never a default: a share of the wrong peak is a wrong number.
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(bf16_flops=197e12, hbm_bytes_per_s=819e9),
+}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r} (known: "
+            f"{sorted(DEVICE_PEAKS)}); add a sourced row to "
+            "core/backend.py DEVICE_PEAKS") from None
 
 
 def current_platform() -> str:
@@ -70,11 +98,40 @@ def current_platform() -> str:
 
 
 def get_backend() -> BackendProfile:
-    return BACKENDS.get(current_platform(), _FALLBACK)
+    platform = current_platform()
+    try:
+        return BACKENDS[platform]
+    except KeyError:
+        raise RuntimeError(
+            f"no BackendProfile for PJRT platform {platform!r} (known: "
+            f"{sorted(BACKENDS)}); add a row to core/backend.py BACKENDS "
+            "saying how Pallas and NMS route there") from None
 
 
 def is_tpu() -> bool:
     return current_platform() == "tpu"
+
+
+def local_tpu_chips() -> int:
+    """TPU chips this process would get, counted WITHOUT initialising a
+    backend (the PCI scan jax itself starts from), so a parent that must
+    leave the chips to its children can ask. 0 when the platform selection
+    (JAX_PLATFORMS / jax_platforms) leaves the TPU out."""
+    import jax
+    from jax._src import hardware_utils
+
+    selected = jax.config.jax_platforms
+    if selected and "tpu" not in selected.split(","):
+        return 0
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+def backend_initialized() -> bool:
+    """Has this process already created a PJRT client? On a TPU host that
+    means it holds the chips: a child that needs one then fails or hangs."""
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
 
 
 def pallas_interpret() -> bool:

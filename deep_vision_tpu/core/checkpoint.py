@@ -114,12 +114,12 @@ class CheckpointManager:
         #: Callers that blanket-replicate after a legacy restore consult
         #: this so they don't clobber a metadata-driven placement.
         self.last_restore_placed = False
-        self._options = ocp.CheckpointManagerOptions(
-            max_to_keep=max_to_keep,
-            save_interval_steps=save_interval_steps,
-            enable_async_checkpointing=True,
-        )
-        self._mgr = ocp.CheckpointManager(self.directory, options=self._options)
+        self._mgr = ocp.CheckpointManager(
+            self.directory, options=ocp.CheckpointManagerOptions(
+                max_to_keep=max_to_keep,
+                save_interval_steps=save_interval_steps,
+                enable_async_checkpointing=True,
+            ))
 
     # -- host-side sidecar -------------------------------------------------
     def _sidecar_path(self, step: int) -> str:
@@ -210,11 +210,7 @@ class CheckpointManager:
     # -- quarantine + fallback restore -------------------------------------
 
     def _reload(self) -> None:
-        try:
-            self._mgr.reload()
-        except Exception:  # older orbax: rebuild from the stored options
-            self._mgr = ocp.CheckpointManager(
-                self.directory, options=self._options)
+        self._mgr.reload()
 
     def _quarantine(self, step: int, reason: str) -> None:
         """Move a failed step (array dir + sidecar) under quarantine/ so the

@@ -44,12 +44,12 @@ it). Engine.warmup and the Trainer's cache-path jits therefore lower
 without ``donate_argnums`` when a cache is attached; the trade is one
 donated buffer's worth of transient memory per cached executable.
 
-The supplementary half is :func:`install_jax_compilation_cache`: JAX's
-own persistent compilation cache (``jax_compilation_cache_dir``) catches
-the jit-traced compiles this module's explicit AOT entries don't cover
-(the Trainer's eval step, one-off host utilities). Note its hits still
-count as backend compiles on some backends — the ZERO-compile warmup
-contract cache-smoke proves rides the explicit AOT entries only.
+The supplementary half is :func:`place_compile_cache`: JAX's own
+persistent compilation cache catches the jit-traced compiles this
+module's explicit AOT entries don't cover (the Trainer's eval step,
+one-off host utilities). Every entry point calls it first; the AOT store
+above never moves it. The ZERO-compile warmup contract cache-smoke proves
+rides the explicit AOT entries only.
 """
 from __future__ import annotations
 
@@ -66,7 +66,7 @@ from deep_vision_tpu.obs import locksmith
 __all__ = [
     "ExecutableCache",
     "env_fingerprint",
-    "install_jax_compilation_cache",
+    "place_compile_cache",
     "EXCACHE_INVALID_REASONS",
     "EXCACHE_ENV",
 ]
@@ -87,7 +87,7 @@ _TOPOLOGY_FIELDS = ("platform", "device_kind", "device_count", "mesh_shape")
 def env_fingerprint(mesh_shape=None) -> dict:
     """The environment half of the cache key: everything that, if it
     changes, makes a serialized executable unloadable or — worse —
-    silently wrong. Versions (the MULTICHIP_r01 skew axis), platform +
+    silently wrong. Versions (the skew axis), platform +
     device kind + device count (the topology axis), and the mesh shape
     when the caller compiles against one."""
     import jax
@@ -108,21 +108,28 @@ def env_fingerprint(mesh_shape=None) -> dict:
     }
 
 
-def install_jax_compilation_cache(path: str) -> None:
-    """Point JAX's own persistent compilation cache at ``path`` (created
-    if missing) and drop the min-compile-time/min-size gates so CPU CI
-    exercises the same code path a TPU run does. Idempotent; call before
-    the first compile."""
+def place_compile_cache() -> str:
+    """Decide where JAX's persistent compilation cache lives and return
+    that directory. Called first thing by every entry point (train_cli,
+    the serving replicas, tools/infer, bench.py, chip_smoke.py), before
+    anything compiles.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX already reads it — nothing is
+    set in code, so whoever launched the process owns the placement.
+    Unset: ``<checkout>/.jax_cache``, resolved from this package's
+    location. The path is part of the cache key's environment, so it is a
+    fixed place — never a temp dir, a pid or a time — and two processes
+    from one checkout share one cache."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
     import jax
 
-    os.makedirs(path, exist_ok=True)
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
-    for knob, value in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                        ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, value)
-        except Exception:
-            pass  # knob renamed/absent on this jax: the dir alone suffices
+    return path
 
 
 class ExecutableCache:
@@ -307,10 +314,14 @@ class ExecutableCache:
                 deserialize_and_load,
             )
 
+            # the devices the lowering was made for: the default is
+            # every device of the backend, and a one-device executable
+            # then fails at call time wanting one shard per device
             compiled = deserialize_and_load(
                 blob,
                 jtu.tree_structure(lowered.args_info),
-                jtu.tree_structure(lowered.out_info))
+                jtu.tree_structure(lowered.out_info),
+                execution_devices=lowered._lowering._device_list)
         except Exception as e:
             # crc-valid bytes the runtime refuses: a PJRT build drift the
             # fingerprint fields don't capture — condemn and recompile

@@ -138,7 +138,7 @@ KNOBS: Dict[str, Knob] = {k.name: k for k in (
        "compiles Pallas."),
     _k("DVT_PREFLIGHT_BUDGET_S", "float", 60.0,
        "Per-probe time budget (seconds) for tools/preflight.py backend "
-       "checks; raise it for slow relays."),
+       "checks; raise it for a slow cold start."),
     _k("DVT_RDZV_GENERATION", "int", None,
        "Rendezvous generation to re-attach to (resilience/"
        "rendezvous.py) — set for re-exec'd host agents."),

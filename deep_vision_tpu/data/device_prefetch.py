@@ -3,8 +3,7 @@
 The host-thread prefetch in data/pipeline.py hides decode/augment latency,
 but the batch still crosses PCIe/ICI *inside* the step: Trainer.train_step
 called `shard_batch` (a `jax.device_put`) on the critical path, so every
-step paid the H2D transfer before it could dispatch — the ~5% wall-vs-device
-gap BENCH_r03 measured. This module moves the device_put OFF the critical
+step paid the H2D transfer before it could dispatch. This module moves the device_put OFF the critical
 path: a producer thread pads/shards the NEXT batch(es) onto the mesh while
 the device executes the current step. jax's async dispatch makes the
 transfer itself non-blocking, so a depth-2 buffer is enough for full

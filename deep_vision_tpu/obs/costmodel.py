@@ -59,9 +59,11 @@ _SHAPE_RE = re.compile(r"\b([a-z]\w*)\[([0-9,]*)\]")
 # an HLO instruction line defining a collective:
 #   %name = <shape-or-tuple> all-reduce(...), channel_id=1, replica_groups=...
 # async pairs lower to `-start`/`-done`; only the start carries the
-# payload shape, so `-done` lines are skipped to avoid double counting
+# payload shape, so `-done` lines are skipped to avoid double counting.
+# A tuple shape may nest one level of parentheses: TPU layouts carry
+# tiling, `(f32[64]{0:T(128)S(1)}, f32[64]{0:T(128)S(1)})`.
 _COLLECTIVE_RE = re.compile(
-    r"=\s*(?P<shape>\([^)]*\)|\S+)\s+"
+    r"=\s*(?P<shape>\((?:[^()]|\([^()]*\))*\)|\S+)\s+"
     r"(?P<kind>" + "|".join(re.escape(k) for k in COLLECTIVE_KINDS) + r")"
     r"(?P<suffix>-start|-done)?\(")
 
@@ -165,17 +167,14 @@ def cost_summary(compiled) -> dict:
         {"flops", "bytes_accessed", "argument_bytes", "output_bytes",
          "temp_bytes", "generated_code_bytes"}
 
-    cost_analysis() keys are per-device under SPMD; older jax returns a
-    one-element list. Missing analyses leave fields as None — a probe,
-    not a requirement.
+    cost_analysis() keys are per-device under SPMD. Missing analyses
+    leave fields as None — a probe, not a requirement.
     """
     out = {"flops": None, "bytes_accessed": None, "argument_bytes": None,
            "output_bytes": None, "temp_bytes": None,
            "generated_code_bytes": None}
     try:
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
         if ca.get("flops", -1) >= 0:
             out["flops"] = float(ca["flops"])
         ba = ca.get("bytes accessed")

@@ -43,9 +43,26 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deep_vision_tpu.core import backend as dvt_backend
 from deep_vision_tpu.core import knobs
+from deep_vision_tpu.ops.pallas.partition import data_shards, over_data_axis
 
 _LANES = 128
-_BLOCK_ROWS = 256  # rows of the (R, C) view per grid step
+# elements of ONE operand's block per grid step. The pipeline holds x, the
+# residual and the output double-buffered (6 io blocks) and the kernel body
+# computes in f32 whatever the io dtype, so the budget is counted in
+# elements, not io bytes: 256 Ki is 1 MiB per f32 block or temporary, well
+# inside the ~16 MiB scoped-VMEM default at every width and dtype (compiling
+# for a v5e, 512 Ki-element bf16 blocks still fit and 1 Mi-element ones run
+# out of VMEM). A fixed ROW count cannot do this: 256 rows is 512 Ki
+# elements at C=2048 and 32 Ki (8x the grid steps) at C=64.
+_BLOCK_ELEMS = 256 * 1024
+_ROW_ALIGN = 32  # a sublane-tile multiple for every io dtype (f32 8, bf16 16)
+
+
+def _block_rows(rows: int, lane_c: int) -> int:
+    """Rows of the (R, lane_c) view per grid step: ~_BLOCK_ELEMS elements,
+    a sublane-tile multiple, never more than the array."""
+    r = _BLOCK_ELEMS // lane_c
+    return min(max(_ROW_ALIGN, r - r % _ROW_ALIGN), rows)
 
 
 def fusion_enabled() -> bool:
@@ -108,6 +125,16 @@ def _lane_layout(c: int):
 
 def _pallas_apply(x, scale, bias, residual, act: str | None,
                   interpret: bool):
+    """The kernel over x, one call per data-axis shard of a multi-device
+    program (partition.py; row-wise, so the split is exact)."""
+    rows_fn = functools.partial(_pallas_rows, act=act, interpret=interpret)
+    # a None residual is an empty pytree: its spec applies to no leaf
+    return over_data_axis(rows_fn, (True, False, False, True))(
+        x, scale, bias, residual)
+
+
+def _pallas_rows(x, scale, bias, residual, *, act: str | None,
+                 interpret: bool):
     """Run the kernel on the (R, lane_c) row view; assumes _lane_layout
     accepted C and total elements divide lane_c."""
     c = x.shape[-1]
@@ -117,7 +144,7 @@ def _pallas_apply(x, scale, bias, residual, act: str | None,
     x2 = x.reshape(rows, lane_c)
     a2 = jnp.tile(scale.astype(jnp.float32), repeat).reshape(1, lane_c)
     b2 = jnp.tile(bias.astype(jnp.float32), repeat).reshape(1, lane_c)
-    block_r = min(_BLOCK_ROWS, rows)
+    block_r = _block_rows(rows, lane_c)
     grid = (pl.cdiv(rows, block_r),)
     row_spec = pl.BlockSpec((block_r, lane_c), lambda i: (i, 0))
     par_spec = pl.BlockSpec((1, lane_c), lambda i: (0, 0))
@@ -214,7 +241,8 @@ def fused_scale_bias_act(x, scale, bias, residual=None,
         raise ValueError(
             f"scale/bias must be ({c},), got {scale.shape}/{bias.shape}")
     lane = _lane_layout(c)
-    if lane is None or x.size % lane[0] != 0:
+    n = data_shards()  # the kernel sees one data-axis shard of x
+    if lane is None or x.shape[0] % n != 0 or (x.size // n) % lane[0] != 0:
         return reference_scale_bias_act(x, scale, bias, residual, act)
     if residual is not None:
         if residual.shape != x.shape:

@@ -44,6 +44,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deep_vision_tpu.core import backend as dvt_backend
 from deep_vision_tpu.core import knobs
+from deep_vision_tpu.ops.pallas.partition import over_data_axis
 
 NEG_INF = -1e30
 
@@ -136,8 +137,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
             lse_ref[0] = jnp.broadcast_to(lse, lse_ref[0].shape)
 
 
-def _flash_forward(q, k, v, *, causal: bool, scale: float, block_q: int,
-                   block_k: int, interpret: bool, need_lse: bool = True):
+def _flash_forward(q, k, v, **kw):
+    """`_flash_forward_shard` per data-axis shard of a multi-device program
+    (partition.py): attention is per (batch, head), so the split is exact,
+    and the b-major (B*H, ...) lse rows concatenate back in order."""
+    return over_data_axis(functools.partial(_flash_forward_shard, **kw),
+                          (True, True, True))(q, k, v)
+
+
+def _flash_forward_shard(q, k, v, *, causal: bool, scale: float, block_q: int,
+                         block_k: int, interpret: bool, need_lse: bool = True):
     """Returns (out (B,T,H,D), lse (B*H, T, 128) f32 lane-broadcast).
 
     With need_lse=False (the inference-only primal) the lse output and its
@@ -278,9 +287,16 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, out, lse, g, *, causal: bool, scale: float,
-                    block_q: int, block_k: int, interpret: bool,
-                    delta_shift=None):
+def _flash_backward(q, k, v, out, lse, g, *, delta_shift=None, **kw):
+    """`_flash_backward_shard` per data-axis shard (see _flash_forward); a
+    None delta_shift is an empty pytree its spec does not touch."""
+    return over_data_axis(functools.partial(_flash_backward_shard, **kw),
+                          (True,) * 7)(q, k, v, out, lse, g, delta_shift)
+
+
+def _flash_backward_shard(q, k, v, out, lse, g, delta_shift=None, *,
+                          causal: bool, scale: float, block_q: int,
+                          block_k: int, interpret: bool):
     b, t, h, d = q.shape
     tk = k.shape[1]
     block_q = min(block_q, t)

@@ -16,11 +16,14 @@ agreement on indices and scores. `interpret=True` runs the same kernel on
 CPU (the tier-1 path); `ops/nms.py non_maximum_suppression(impl=...)` picks
 lax vs pallas (env DVT_NMS_IMPL overrides, TPU defaults to pallas).
 
-Layout: coordinates travel as four (B, N) rows (lane-major over candidates)
-rather than (B, N, 4) — a 4-wide lane dim would waste 124 of the VPU's 128
-lanes on every op. N and max_detections are padded to lane multiples in the
-wrapper; padded candidates carry score -1 so the `best > 0` selection gate
-never picks them.
+Layout: coordinates travel as four (B, 1, N) rows (lane-major over
+candidates) rather than (B, N, 4) — a 4-wide lane dim would waste 124 of the
+VPU's 128 lanes on every op. The unit middle dim is what lets one image be
+one block: the TPU lowering wants a block's last two dims to be (8, 128)
+multiples or the array's own, and a (1, N) block of a (B, N) array is
+neither once B > 1. N and max_detections are padded to lane multiples in
+the wrapper; padded candidates carry score -1 so the `best > 0` selection
+gate never picks them.
 """
 from __future__ import annotations
 
@@ -31,6 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from deep_vision_tpu.core import backend as dvt_backend
+from deep_vision_tpu.ops.pallas.partition import over_data_axis
 
 _LANES = 128
 
@@ -96,7 +100,7 @@ def pallas_nms(boxes, scores, max_detections: int, iou_threshold: float,
     """
     if interpret is None:
         interpret = dvt_backend.pallas_interpret()
-    b, n, _ = boxes.shape
+    n = boxes.shape[1]
     np_ = _round_up(max(n, 1), _LANES)
     dp = _round_up(max(max_detections, 1), _LANES)
     scores = jnp.where(scores >= score_threshold, scores, -1.0)
@@ -106,20 +110,27 @@ def pallas_nms(boxes, scores, max_detections: int, iou_threshold: float,
         scores = jnp.pad(scores, ((0, 0), (0, np_ - n)),
                          constant_values=-1.0)
         boxes = jnp.pad(boxes, ((0, 0), (0, np_ - n), (0, 0)))
-    x1, y1, x2, y2 = (boxes[..., i] for i in range(4))
+    x1, y1, x2, y2 = (boxes[..., i][:, None, :] for i in range(4))
 
-    row = pl.BlockSpec((1, np_), lambda i: (i, 0))
-    out_row = pl.BlockSpec((1, dp), lambda i: (i, 0))
-    out_s, out_i = pl.pallas_call(
-        functools.partial(_nms_kernel, max_detections=max_detections,
-                          iou_threshold=float(iou_threshold)),
-        out_shape=[
-            jax.ShapeDtypeStruct((b, dp), jnp.float32),
-            jax.ShapeDtypeStruct((b, dp), jnp.int32),
-        ],
-        grid=(b,),
-        in_specs=[row, row, row, row, row],
-        out_specs=[out_row, out_row],
-        interpret=bool(interpret),
-    )(x1, y1, x2, y2, scores)
-    return out_s[:, :max_detections], out_i[:, :max_detections]
+    # the kernel sees (1, Np) / (1, Dp): the batch dim is squeezed away
+    row = pl.BlockSpec((None, 1, np_), lambda i: (i, 0, 0))
+    out_row = pl.BlockSpec((None, 1, dp), lambda i: (i, 0, 0))
+
+    def select(*rows):  # one data-axis shard of the images (partition.py)
+        bs = rows[0].shape[0]
+        return pl.pallas_call(
+            functools.partial(_nms_kernel, max_detections=max_detections,
+                              iou_threshold=float(iou_threshold)),
+            out_shape=[
+                jax.ShapeDtypeStruct((bs, 1, dp), jnp.float32),
+                jax.ShapeDtypeStruct((bs, 1, dp), jnp.int32),
+            ],
+            grid=(bs,),
+            in_specs=[row] * 5,
+            out_specs=[out_row, out_row],
+            interpret=bool(interpret),
+        )(*rows)
+
+    out_s, out_i = over_data_axis(select, (True,) * 5)(
+        x1, y1, x2, y2, scores[:, None, :])
+    return out_s[:, 0, :max_detections], out_i[:, 0, :max_detections]

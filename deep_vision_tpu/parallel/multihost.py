@@ -184,7 +184,7 @@ def host_shard() -> tuple[int, int]:
 
 def _bounded_collective(fn, name: str, deadline_s: Optional[float]):
     """Run a jax collective with a deadline: the op blocks in C++ when a
-    peer is dead (BENCH_r04's failure shape, at the host layer), so the
+    peer is dead (a hang, never an exception), so the
     only honest bound is a worker-thread join — on timeout the orphaned
     thread stays wedged and the caller gets the typed `HostLostError`
     the supervision layer turns into a re-rendezvous."""

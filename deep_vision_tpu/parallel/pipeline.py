@@ -88,8 +88,8 @@ def _pipeline_local(stacked_params, x, *, stage_fn, axis_name: str,
 
     act0 = jnp.zeros_like(micro[0])
     out0 = jnp.zeros_like(micro)
-    act0 = jax.lax.pvary(act0, (axis_name,))
-    out0 = jax.lax.pvary(out0, (axis_name,))
+    act0 = jax.lax.pcast(act0, (axis_name,), to="varying")
+    out0 = jax.lax.pcast(out0, (axis_name,), to="varying")
     (_, out), _ = jax.lax.scan(
         tick, (act0, out0), jnp.arange(n_micro + s - 1)
     )

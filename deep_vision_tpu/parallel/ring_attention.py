@@ -124,8 +124,8 @@ def _ring_attention_local_flash(q, k, v, *, axis_name: str, causal: bool,
 
     out0 = jnp.zeros((b, t_loc, h, d), jnp.float32)
     lse0 = jnp.full((b, h, t_loc), NEG, jnp.float32)
-    out0 = jax.lax.pvary(out0, (axis_name,))
-    lse0 = jax.lax.pvary(lse0, (axis_name,))
+    out0 = jax.lax.pcast(out0, (axis_name,), to="varying")
+    lse0 = jax.lax.pcast(lse0, (axis_name,), to="varying")
     out, _, _, _ = jax.lax.fori_loop(0, n, step, (out0, lse0, k, v))
     return out.astype(out_dtype)
 
@@ -176,8 +176,8 @@ def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool,
     l0 = jnp.zeros((q.shape[0], q.shape[2], t_loc), q.dtype)
     # constants start axis-unvarying under shard_map; mark them varying so the
     # loop carry type is stable across iterations
-    m0 = jax.lax.pvary(m0, (axis_name,))
-    l0 = jax.lax.pvary(l0, (axis_name,))
+    m0 = jax.lax.pcast(m0, (axis_name,), to="varying")
+    l0 = jax.lax.pcast(l0, (axis_name,), to="varying")
     o, m, l, _, _ = jax.lax.fori_loop(0, n, step, (o0, m0, l0, k, v))
     denom = jnp.maximum(l, 1e-20).transpose(0, 2, 1)[..., None]
     return (o / denom).astype(out_dtype)

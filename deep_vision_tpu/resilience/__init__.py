@@ -10,7 +10,7 @@ clean lab run):
   bench.py's rebuild-replay loop, the checkpoint sidecar writer, and
   shard opens in the tolerant record reader.
 - `elastic`: the accelerator-layer arc — backend-failure classification
-  (connection loss / dead-tunnel timeout / libtpu version skew),
+  (connection loss / hung-backend timeout / libtpu version skew),
   `BackendSupervisor` rebuild-replay choreography with typed
   `backend_lost`/`backend_recovered` journal events, cross-mesh
   checkpoint sharding metadata (restore a run saved on N devices onto

@@ -4,10 +4,9 @@ PR 10 made a single process preemption-native; this module is its
 multi-HOST half (ROADMAP item 1's declared leftover). Today a dead host
 makes every `multihost.sync_hosts` / `agree_flag` collective hang until
 a watchdog dumps stacks — the run dies by timeout, not by policy. The
-real fleet failures in the repo's own history are host-MEMBERSHIP
-events: MULTICHIP_r01 was a version-skewed host admitted into the world
-(fatal 4 minutes in), r04/r05 were dead tunnels every surviving host
-then hung on. The standard answer (torchelastic-style generation-
+real fleet failures are host-MEMBERSHIP events: a version-skewed host
+admitted into the world (fatal minutes into the compile), a dead host
+every survivor then hangs on. The standard answer (torchelastic-style generation-
 numbered rendezvous) is a coordinator that treats an N→M world-size
 change as an *expected input*:
 
@@ -20,7 +19,7 @@ change as an *expected input*:
 - every barrier/agree is deadline-bounded and lease-checked, so a dead
   peer yields `HostLostError` within the heartbeat deadline;
 - joiners exchange client/platform versions through the coordinator at
-  join time: a skewed host (the MULTICHIP_r01 failure) is refused in
+  join time: a skewed host is refused in
   seconds with kind `version_skew`, never admitted into a generation.
 
 The backing store is a directory on a shared filesystem (the same
@@ -106,8 +105,8 @@ class RendezvousTimeout(RendezvousError):
 class RendezvousRefused(RendezvousError):
     """This host was refused admission (kind `version_skew`: its
     client/platform versions disagree with the incumbent world's —
-    the MULTICHIP_r01 failure, caught at join in seconds instead of
-    minutes into the first compile)."""
+    caught at join in seconds instead of minutes into the first
+    compile)."""
 
     def __init__(self, kind: str, detail: str = ""):
         self.kind = kind
@@ -201,8 +200,8 @@ def versions_compatible(mine: Dict[str, str],
     """The join-time version handshake, as a pure function.
 
     Compares `client_version` (jax/jaxlib pair) and `platform_version`
-    (the libtpu build string — the terminal half of the MULTICHIP_r01
-    skew) field by field; a field one side did not report is not a
+    (the libtpu build string — the terminal half of a skew) field by
+    field; a field one side did not report is not a
     mismatch (heterogeneous probes must not fail closed on missing
     introspection). Returns (ok, detail)."""
     for key in ("client_version", "platform_version"):

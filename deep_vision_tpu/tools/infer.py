@@ -187,6 +187,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     p.add_argument("images", nargs="+")
     args = p.parse_args(argv)
 
+    from deep_vision_tpu.core.excache import place_compile_cache
+
+    place_compile_cache()  # before anything compiles
     import jax.numpy as jnp
 
     from deep_vision_tpu.models import get_model

@@ -1,15 +1,15 @@
 """Parallel scaling-efficiency measurement over data-axis sub-meshes.
 
-The MULTICHIP evidence gap this closes: five rounds of multi-chip runs
-proved `loss=OK` on a `{'data': 4, 'model': 2}` dryrun and nothing else —
-no number ever said what the second through eighth chip BUY. This module
+The evidence gap this closes: multi-chip dry runs proved `loss=OK` on a
+`{'data': 4, 'model': 2}` mesh and nothing else — no number ever said
+what the second through eighth chip BUY. This module
 measures it: the same table-sharded train step timed at data={1,2,4,8}
 sub-meshes of the available devices, reporting throughput, per-device
 examples/s, and the efficiency fraction vs the 1-device baseline (1.0 =
 linear scaling; the gap is the collective/dispatch cost).
 
-Shared by `bench.py --multichip` (the journal/bench-JSON emitter, the
-MULTICHIP_r0N artifact source), the `__graft_entry__.dryrun_multichip`
+Shared by `bench.py --multichip` (the journal/bench-JSON emitter), the
+`__graft_entry__.dryrun_multichip`
 scaling section, and `make shard-smoke` — one measurement, three
 consumers, so the numbers are comparable.
 
@@ -151,7 +151,7 @@ def measure_scaling(
         comm = _comm_profile(step, state)
         for _ in range(warmup):
             state, loss = step(state, batch)
-        float(loss)  # close warmup: a scalar fetch cannot return early
+        float(loss)  # close warmup (the scalar fetch waits for the device)
         t0 = time.perf_counter()
         for _ in range(steps):
             state, loss = step(state, batch)
@@ -182,8 +182,8 @@ def measure_scaling(
 
 def scaling_result(rows: list, *, metric: str = "multichip_scaling") -> dict:
     """The bench-contract payload for a scaling run: headline `value` is
-    the efficiency fraction at the LARGEST sub-mesh (the number the
-    MULTICHIP_r0N trajectory tracks), rows carry the full curve."""
+    the efficiency fraction at the LARGEST sub-mesh, rows carry the full
+    curve."""
     import jax
 
     result = {

@@ -51,9 +51,13 @@ _global_sum = jax.jit(jnp.sum)
 
 
 def _set_lr(opt_state, lr: float):
-    """Set the injected learning_rate hyperparam to an absolute value."""
+    """Set the injected learning_rate hyperparam to an absolute value, on
+    the devices the old leaf lives on: a leaf placed anywhere else changes
+    the jitted step's input layout and recompiles the whole step."""
     hp = dict(opt_state.hyperparams)
-    hp["learning_rate"] = jnp.asarray(lr, jnp.asarray(hp["learning_rate"]).dtype)
+    old = hp["learning_rate"]
+    hp["learning_rate"] = jax.device_put(
+        np.asarray(lr, jnp.asarray(old).dtype), old.sharding)
     return opt_state._replace(hyperparams=hp)
 
 
@@ -179,7 +183,7 @@ class Trainer:
         self.preempted = False  # latched by the SIGTERM escalation path
         # backend-loss recovery (resilience/elastic.py BackendSupervisor):
         # with one installed, fit() treats a classified backend failure
-        # (dropped connection, dead-tunnel timeout) as an expected input —
+        # (dropped connection, hung-backend timeout) as an expected input —
         # rebuild the jitted step from host-side seeds + checkpoint, replay
         # from the last completed step. The host-side ingredients of that
         # rebuild are kept here; everything device-resident is derived.
@@ -520,6 +524,14 @@ class Trainer:
                                                   **state_pin)
         self._aot_steps: dict = {}
 
+    def _mesh_context(self):
+        """JAX's mesh context, entered wherever a step is traced or
+        dispatched (it is part of jit's cache key, so always or never):
+        code under the trace reads the mesh from it — the Pallas kernels
+        to run per data-axis shard, since XLA cannot partition a Mosaic
+        call itself (ops/pallas/partition.py)."""
+        return jax.set_mesh(self.mesh)
+
     def profile_step(self, batch, kind: str = "train"):
         """Journal the XLA cost + collective inventory of the step
         executable for `batch`'s signature (typed perf_profile /
@@ -546,7 +558,8 @@ class Trainer:
                              "('train', 'multi')")
         # jaxlint: disable=DV003 -- profiling probe: non-donating on purpose (the compiled artifact is inspected, not dispatched on the training hot path)
         jitted = jax.jit(impl, **self._state_pin)
-        compiled = jitted.lower(self.state, batch).compile()
+        with self._mesh_context():
+            compiled = jitted.lower(self.state, batch).compile()
         return perfwatch.profile_compiled(f"trainer/{kind}", compiled,
                                           journal=self.journal,
                                           registry=self.clock.registry)
@@ -752,14 +765,16 @@ class Trainer:
         else:
             batch = shard_batch(self.mesh, self._pad_and_mask(batch),
                                 axes=self._batch_axes)
-        if self._checkify:
-            err, (new_state, metrics) = self._train_step_err(self.state, batch)
-            err.throw()  # located NaN/OOB/div0 inside the step, if any
-            self.state = new_state
-        else:
-            step_fn = self._cached_step("train_step", self._train_step,
-                                        self._train_step_cache, batch)
-            self.state, metrics = step_fn(self.state, batch)
+        with self._mesh_context():
+            if self._checkify:
+                err, (new_state, metrics) = self._train_step_err(self.state,
+                                                                 batch)
+                err.throw()  # located NaN/OOB/div0 inside the step, if any
+                self.state = new_state
+            else:
+                step_fn = self._cached_step("train_step", self._train_step,
+                                            self._train_step_cache, batch)
+                self.state, metrics = step_fn(self.state, batch)
         if self.ema is not None:
             self.ema.update(self.state.params)
         return metrics
@@ -782,9 +797,10 @@ class Trainer:
                 f"superstep got {k} batches, configured multistep is "
                 f"{self.multistep} (the epoch tail must use train_step)"
             )
-        multi_fn = self._cached_step("superstep", self._train_multi,
-                                     self._train_multi_cache, stacked)
-        self.state, metrics = multi_fn(self.state, stacked)
+        with self._mesh_context():
+            multi_fn = self._cached_step("superstep", self._train_multi,
+                                         self._train_multi_cache, stacked)
+            self.state, metrics = multi_fn(self.state, stacked)
         return [jax.tree_util.tree_map(lambda v, i=i: v[i], metrics)
                 for i in range(k)]
 
@@ -794,7 +810,8 @@ class Trainer:
         state = self.state
         if self.ema is not None:
             state = state.replace(params=self.ema.params)
-        return self._eval_step(state, batch)
+        with self._mesh_context():
+            return self._eval_step(state, batch)
 
     def lr_at(self, step: int) -> float:
         """LR for a step the caller already fetched (the hot loop passes its

@@ -620,3 +620,75 @@ def test_env_fingerprint_fields():
     for field in ("jax", "jaxlib", "platform", "device_kind",
                   "device_count"):
         assert field in fp
+
+
+# -- where JAX's own compile cache lives (place_compile_cache) ---------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def _restore_cache_dir():
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def _tiny_train(tmp_path, *extra):
+    from deep_vision_tpu.train_cli import main
+
+    return main(["-m", "lenet5", "--fake-data", "--epochs", "1",
+                 "--batch-size", "16", "--fake-batches", "1",
+                 "--skip-preflight", "--ckpt-dir", str(tmp_path / "ck"),
+                 *extra])
+
+
+@pytest.mark.parametrize("with_excache", [False, True])
+def test_compile_cache_placed_from_outside_is_left_alone(
+        tmp_path, monkeypatch, mesh8, _restore_cache_dir, with_excache):
+    """JAX_COMPILATION_CACHE_DIR set: no code path sets another directory,
+    with or without --executable-cache (which used to repoint it)."""
+    outside = str(tmp_path / "placed-by-the-launcher")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+    # what JAX itself did with the variable when it was imported
+    jax.config.update("jax_compilation_cache_dir", outside)
+    extra = (["--executable-cache", str(tmp_path / "aot")]
+             if with_excache else [])
+    assert _tiny_train(tmp_path, *extra) == 0
+    assert jax.config.jax_compilation_cache_dir == outside
+    assert not os.path.exists(tmp_path / "aot" / "xla")
+
+
+def test_compile_cache_defaults_to_the_checkout(
+        tmp_path, monkeypatch, mesh8, _restore_cache_dir):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.chdir(tmp_path)  # not derived from the working directory
+    assert _tiny_train(tmp_path) == 0
+    assert jax.config.jax_compilation_cache_dir == os.path.join(
+        REPO, ".jax_cache")
+
+
+def test_compile_cache_default_is_the_same_in_every_process(tmp_path):
+    """A fixed place, never a temp dir, a pid or a time: the path is part
+    of the cache key's environment, so a directory that moves never hits."""
+    import subprocess
+    import sys
+
+    code = ("import jax; "
+            "from deep_vision_tpu.core.excache import place_compile_cache; "
+            "print(place_compile_cache()); "
+            "print(jax.config.jax_compilation_cache_dir)")
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = REPO
+    outs = [subprocess.run([sys.executable, "-c", code], cwd=cwd, env=env,
+                           capture_output=True, text=True, timeout=120)
+            for cwd in (REPO, str(tmp_path))]
+    for res in outs:
+        assert res.returncode == 0, res.stderr[-2000:]
+        assert res.stdout.split() == [os.path.join(REPO, ".jax_cache")] * 2
+    placed = str(tmp_path / "elsewhere")
+    res = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                         env={**env, "JAX_COMPILATION_CACHE_DIR": placed},
+                         capture_output=True, text=True, timeout=120)
+    assert res.stdout.split() == [placed, placed], res.stderr[-2000:]

@@ -1,6 +1,6 @@
 """End-to-end inference path + detection/pose quality metrics.
 
-Covers VERDICT.md missing #1: model -> decode -> NMS -> boxes for a user,
+Covers model -> decode -> NMS -> boxes for a user,
 and mAP/PCKh computed on synthetic fixtures with known answers.
 """
 import jax

@@ -468,8 +468,8 @@ def test_bench_stub_carries_multistep():
 
 
 def test_bench_emit_journals_every_path(monkeypatch):
-    """_emit (the one funnel for train/sweep/data/watchdog lines) must
-    write the bench journal event exactly once."""
+    """_emit (the one funnel for train/sweep/data lines) must write the
+    bench journal event exactly once."""
     import bench
 
     class Spy:
@@ -484,7 +484,7 @@ def test_bench_emit_journals_every_path(monkeypatch):
 
     spy = Spy()
     monkeypatch.setattr(bench, "_JOURNAL", spy)
-    monkeypatch.setattr(bench, "_EMITTED", False)
+    monkeypatch.setattr(bench, "_EMITTED", None)
     assert bench._emit({"metric": "dispatch_sweep", "rows": []})
     assert not bench._emit({"metric": "late_duplicate"})  # latched
     assert len(spy.events) == 1

@@ -95,6 +95,24 @@ def test_collective_inventory_parses_hlo_forms():
         == ar["bytes"]
 
 
+def test_collective_inventory_parses_tpu_tiled_tuple_shapes():
+    """TPU layouts carry tiling — parentheses INSIDE the tuple shape. The
+    line is from the four-chip resnet50 step (PR 21), where the inventory
+    came back empty until the shape pattern allowed one nested level."""
+    hlo = (
+        "  %all-reduce.370 = (f32[64]{0:T(128)S(1)}, f32[64]{0:T(128)S(1)})"
+        " all-reduce(%get-tuple-element.46, %get-tuple-element.44),"
+        " channel_id=2, replica_groups=[1,4]<=[4],"
+        " use_global_device_ids=true, to_apply=%region_1.2.clone\n"
+        "  %ar.2 = f32[7,7,512]{2,1,0:T(8,128)} all-reduce(%fusion.9),"
+        " channel_id=3, replica_groups=[1,4]<=[4]\n"
+    )
+    inv = costmodel.collective_inventory(hlo)
+    assert [i["kind"] for i in inv] == ["all-reduce", "all-reduce"]
+    assert inv[0]["bytes"] == 2 * 64 * 4 and inv[0]["group_size"] == 4
+    assert inv[1]["bytes"] == 7 * 7 * 512 * 4
+
+
 def test_collective_inventory_empty_on_single_device_hlo():
     hlo = costmodel.hlo_text(_compiled_matmul())
     assert hlo  # compiled text must be available on this jax

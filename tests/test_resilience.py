@@ -105,7 +105,7 @@ class TestRetryPolicy:
         def flaky():
             calls.append(1)
             if len(calls) < 2:
-                raise RuntimeError("UNAVAILABLE: tunnel fell over")
+                raise RuntimeError("UNAVAILABLE: connection reset")
             return 7
 
         assert p.call(flaky) == 7

@@ -1,0 +1,265 @@
+"""Every Pallas entry point lowers for the TPU, checked from the CPU.
+
+`jax.jit(f).trace(*specs).lower(lowering_platforms=("tpu",))` runs the
+Pallas-to-Mosaic lowering with no chip: block shapes the TPU refuses, ops
+Mosaic has no rule for, and a kernel left inside a multi-device program all
+fail here. The second half goes further where libtpu is installed: a
+compile-only v5e topology runs the real Mosaic and XLA:TPU compilers, so
+running out of VMEM fails here too. Only execution (numbers, times) is left
+to chip_smoke.py. Shapes are the production ones chip_smoke.py runs.
+"""
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deep_vision_tpu.ops.pallas.bn_act import fused_scale_bias_act
+from deep_vision_tpu.ops.pallas.flash_attention import flash_attention
+from deep_vision_tpu.ops.pallas.nms import pallas_nms
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+S = jax.ShapeDtypeStruct
+
+
+def lower_for_tpu(fn, *specs):
+    text = jax.jit(fn).trace(*specs).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text  # the kernel is in the program, compiled
+    return text
+
+
+def _bn_fwd_bwd(x, a, b, r):
+    def loss(x, a, b, r):
+        y = fused_scale_bias_act(x, a, b, residual=r, interpret=False)
+        return jnp.sum(y.astype(jnp.float32))
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(x, a, b, r)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("hw,c", [(56, 64), (56, 256), (28, 512),
+                                  (14, 1024), (7, 2048)])
+def test_bn_act_lowers_at_resnet50_stage_shapes(hw, c, dtype):
+    x = S((128, hw, hw, c), dtype)
+    p = S((c,), jnp.float32)
+    lower_for_tpu(_bn_fwd_bwd, x, p, p, x)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("t", [1024, 4096])
+def test_flash_attention_lowers_fwd_bwd(t, causal):
+    def fwd_bwd(q, k, v):
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, causal=causal, interpret=False)
+            return jnp.sum(out.astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    q = S((2, t, 12, 64), jnp.bfloat16)
+    lower_for_tpu(fwd_bwd, q, q, q)
+
+
+@pytest.mark.parametrize("batch", [1, 8])
+def test_nms_lowers_at_yolo_scale(batch):
+    # batch 8 is the case a (1, N) block of a (B, N) array cannot lower
+    lower_for_tpu(
+        lambda boxes, scores: pallas_nms(boxes, scores, 100, 0.5, 0.5,
+                                         interpret=False),
+        S((batch, 10647, 4), jnp.float32), S((batch, 10647), jnp.float32))
+
+
+def test_kernel_in_a_multi_device_program_needs_the_mesh_context(mesh8):
+    """XLA cannot partition a Mosaic call: in a program over 8 devices the
+    lowering refuses it, and under the trainers' `jax.set_mesh` context the
+    kernel runs per data-axis shard instead (ops/pallas/partition.py)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    rows = NamedSharding(mesh8, P("data"))
+    rep = NamedSharding(mesh8, P())
+    x = S((128, 7, 7, 2048), jnp.float32, sharding=rows)
+    p = S((2048,), jnp.float32, sharding=rep)
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        lower_for_tpu(_bn_fwd_bwd, x, p, p, x)
+    with jax.set_mesh(mesh8):
+        text = lower_for_tpu(_bn_fwd_bwd, x, p, p, x)
+    assert "all-gather" not in text and "all_gather" not in text
+
+
+def test_unknown_platform_is_an_error_not_the_cpu_row(monkeypatch):
+    from deep_vision_tpu.core import backend
+
+    monkeypatch.setattr(backend, "current_platform", lambda: "rocm")
+    with pytest.raises(RuntimeError, match="'rocm'"):
+        backend.get_backend()
+    with pytest.raises(RuntimeError, match="'rocm'"):
+        backend.pallas_interpret()
+
+
+def test_no_interpret_on_tpu(monkeypatch):
+    from deep_vision_tpu.core import backend
+
+    monkeypatch.setattr(backend, "current_platform", lambda: "tpu")
+    assert backend.pallas_interpret() is False
+    assert backend.default_nms_impl() == "pallas"
+
+
+def test_chip_smoke_refuses_the_cpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    res = subprocess.run([sys.executable, "chip_smoke.py"], cwd=REPO, env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert res.returncode != 0
+    assert "needs a TPU" in res.stderr and "'cpu'" in res.stderr
+    assert '"ok"' not in res.stdout  # no result line
+
+
+# -- the per-shard wrap computes the same numbers (interpreted, 8 devices) ---
+
+def _sharded(mesh, *arrays):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return [jax.device_put(a, NamedSharding(mesh, P("data"))) for a in arrays]
+
+
+def test_bn_act_per_shard_matches_reference(mesh8):
+    import numpy as np
+
+    from deep_vision_tpu.ops.pallas.bn_act import reference_scale_bias_act
+
+    rng = np.random.RandomState(0)
+    x, r = _sharded(mesh8, *(rng.randn(16, 4, 4, 128).astype(np.float32)
+                             for _ in range(2)))
+    a = jnp.asarray(rng.rand(128).astype(np.float32) + 0.5)
+    b = jnp.asarray(rng.randn(128).astype(np.float32))
+
+    def loss(impl):
+        return lambda x, a, b, r: jnp.sum(
+            impl(x, a, b, residual=r, act="relu") ** 2)
+
+    fused = jax.jit(jax.value_and_grad(loss(
+        lambda *args, **kw: fused_scale_bias_act(*args, interpret=True, **kw)),
+        argnums=(0, 1, 2, 3)))
+    with jax.set_mesh(mesh8):
+        assert "shard_map" in str(jax.make_jaxpr(fused)(x, a, b, r))
+        got = fused(x, a, b, r)
+    want = jax.value_and_grad(loss(reference_scale_bias_act),
+                              argnums=(0, 1, 2, 3))(x, a, b, r)
+    for u, v in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(u), np.asarray(v),
+                                   rtol=2e-5, atol=2e-5)
+
+
+def test_bn_act_takes_the_lax_route_when_a_shard_does_not_tile(mesh8):
+    # 8 rows of 64 channels over 8 shards: one shard is 64 elements, half a
+    # lane row — the shape rule sends it to the reference, never a gather
+    x = jnp.ones((8, 1, 1, 64), jnp.float32)
+    p = jnp.ones((64,), jnp.float32)
+    with jax.set_mesh(mesh8):
+        jaxpr = str(jax.make_jaxpr(lambda x, a, b: fused_scale_bias_act(
+            x, a, b, interpret=True))(x, p, p))
+    assert "pallas_call" not in jaxpr and "shard_map" not in jaxpr
+
+
+def test_flash_and_nms_per_shard_match_their_references(mesh8):
+    import numpy as np
+
+    from deep_vision_tpu.ops import nms as lax_nms
+    from deep_vision_tpu.ops.pallas import flash_attention as _  # noqa: F401
+
+    fa = sys.modules["deep_vision_tpu.ops.pallas.flash_attention"]
+    rng = np.random.RandomState(1)
+    q, k, v = _sharded(mesh8, *(rng.randn(8, 32, 2, 8).astype(np.float32)
+                                for _ in range(3)))
+
+    def attn(impl):
+        return jax.jit(jax.value_and_grad(
+            lambda q, k, v: jnp.sum(impl(q, k, v) ** 2), argnums=(0, 1, 2)))
+
+    with jax.set_mesh(mesh8):
+        got = attn(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=16, block_k=16,
+            interpret=True))(q, k, v)
+    want = attn(lambda q, k, v: fa._dense_reference(
+        q, k, v, True, 8 ** -0.5))(q, k, v)
+    for u, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(u), np.asarray(w),
+                                   rtol=2e-4, atol=2e-4)
+
+    xy = rng.rand(8, 200, 2).astype(np.float32) * 0.8
+    wh = rng.rand(8, 200, 2).astype(np.float32) * 0.25 + 0.02
+    boxes, scores = _sharded(mesh8, np.concatenate([xy, xy + wh], -1),
+                             rng.rand(8, 200).astype(np.float32))
+    with jax.set_mesh(mesh8):
+        sel_s, sel_i = jax.jit(lambda b, s: pallas_nms(
+            b, s, 20, 0.5, 0.3, interpret=True))(boxes, scores)
+    ref_s, ref_i = jax.vmap(lambda b, s: lax_nms._nms_single(
+        b, s, 20, 0.5, 0.3))(boxes, scores)
+    np.testing.assert_array_equal(np.asarray(sel_i), np.asarray(ref_i))
+    np.testing.assert_array_equal(np.asarray(sel_s), np.asarray(ref_s))
+
+
+# -- the real compiler, no chip: a compile-only v5e topology -----------------
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One compile-only TPU v5e device (libtpu installed, no TPU attached)."""
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here: nothing to compile with
+        pytest.skip(f"no compile-only TPU topology: {type(e).__name__}: {e}")
+    return topo.devices[0]
+
+
+def compile_for_v5e(device, fn, *specs):
+    from jax.sharding import SingleDeviceSharding
+
+    here = SingleDeviceSharding(device)
+    specs = [S(s.shape, s.dtype, sharding=here) for s in specs]
+    return jax.jit(fn).lower(*specs).compile()
+
+
+@pytest.mark.parametrize("hw,c,dtype", [(56, 64, jnp.bfloat16),
+                                        (56, 256, jnp.float32),
+                                        (7, 2048, jnp.float32),
+                                        (7, 2048, jnp.bfloat16)])
+def test_bn_act_blocks_fit_vmem(v5e, hw, c, dtype):
+    """Block rows come from the element budget, not a fixed 256: the widest
+    f32 stage and the narrowest bf16 one both fit the scoped-VMEM default
+    (a 1 Mi-element block does not — the compiler says so here)."""
+    x = S((128, hw, hw, c), dtype)
+    p = S((c,), jnp.float32)
+    compile_for_v5e(v5e, _bn_fwd_bwd, x, p, p, x)
+
+
+def test_bn_act_block_over_budget_is_refused_by_the_compiler(
+        v5e, monkeypatch):
+    from deep_vision_tpu.ops.pallas import bn_act
+
+    monkeypatch.setattr(bn_act, "_BLOCK_ELEMS", 4 * 1024 * 1024)
+    x = S((128, 7, 7, 2048), jnp.float32)
+    p = S((2048,), jnp.float32)
+    with pytest.raises(Exception, match="vmem"):
+        # a fresh function: jit would hand back the cached lowering
+        compile_for_v5e(v5e, lambda *args: _bn_fwd_bwd(*args), x, p, p, x)
+
+
+def test_nms_and_flash_compile_for_v5e(v5e):
+    compile_for_v5e(
+        v5e, lambda boxes, scores: pallas_nms(boxes, scores, 100, 0.5, 0.5,
+                                              interpret=False),
+        S((8, 10647, 4), jnp.float32), S((8, 10647), jnp.float32))
+
+    def fwd_bwd(q, k, v):
+        return jax.value_and_grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, interpret=False).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    q = S((1, 4096, 12, 64), jnp.bfloat16)
+    compile_for_v5e(v5e, fwd_bwd, q, q, q)

@@ -120,6 +120,27 @@ def test_fit_with_plateau_and_eval(mesh8, tmp_path):
     assert len(trainer.eval_logger.history["top1"]) == 3  # eval_first + 2 epochs
 
 
+def test_plateau_write_does_not_recompile_the_step(mesh8):
+    """The plateau writes the LR into opt_state after every epoch's eval; a
+    leaf that lands anywhere but where the old one lived changes the step's
+    input layout, and the whole step compiles a second time at the first
+    step of epoch 2 (seen on the flagship: one extra full compile)."""
+    trainer = Trainer(
+        get_model("lenet5", num_classes=4),
+        build_optimizer("sgd", 0.05, momentum=0.9), classification_loss_fn,
+        sample_input=jnp.zeros((8, 32, 32, 1)), mesh=mesh8,
+        plateau=ReduceLROnPlateau(patience=0, mode="max"),
+    )
+    images, labels = synthetic_mnist(n=64)
+    data = lambda: batches(images, labels, 32)
+    lr_leaf = trainer.state.opt_state.hyperparams["learning_rate"]
+    trainer.fit(data, data, epochs=3)
+    new_leaf = trainer.state.opt_state.hyperparams["learning_rate"]
+    assert new_leaf.sharding == lr_leaf.sharding
+    assert float(new_leaf) < 0.05  # the plateau did write
+    assert trainer._train_step._cache_size() == 1
+
+
 @pytest.mark.slow
 def test_fit_raises_on_diverged_loss(mesh8):
     """Failure detection: a NaN epoch must stop the run loudly (SURVEY §5)."""

@@ -612,3 +612,76 @@ class TestProcessFleet:
                           if e.get("event") == "transport_request"]
         hops = [e for e in child_evs if e.get("trace_id") == ctx.trace_id]
         assert len(hops) == 1 and hops[0]["status"] == 200
+
+
+class TestOneProcessPerChip:
+    """On a TPU host a chip belongs to one process at a time: the pool
+    parent stays off the chips, pins each child to its own, and refuses up
+    front what cannot work — a child hanging on a held chip is the failure
+    these refusals replace. (The pinned children themselves need the
+    four-chip host; the chip run in CHANGES.md PR 21 is their proof.)"""
+
+    def _pool(self, tmp_path, replicas, spawned):
+        from deep_vision_tpu.serve import procpool
+        from tools.loadgen import fleet_builder
+
+        pool = procpool.ProcReplicaPool(fleet_builder, replicas=replicas,
+                                        run_dir=str(tmp_path))
+        pool._spawn = lambda slot, generation: spawned.append(slot)
+        return pool
+
+    def test_more_replicas_than_chips_is_refused_before_any_spawn(
+            self, tmp_path, monkeypatch):
+        from deep_vision_tpu.core import backend
+        from deep_vision_tpu.serve.engine import ServeError
+
+        monkeypatch.setattr(backend, "local_tpu_chips", lambda: 1)
+        spawned = []
+        with pytest.raises(ServeError, match="2 replicas .* 1 TPU chip"):
+            self._pool(tmp_path, 2, spawned).start()
+        assert spawned == []
+
+    def test_a_parent_that_holds_the_chips_is_refused(
+            self, tmp_path, monkeypatch):
+        import jax
+
+        from deep_vision_tpu.core import backend
+        from deep_vision_tpu.serve.engine import ServeError
+
+        jax.devices()  # this process HAS created a client
+        assert backend.backend_initialized()
+        monkeypatch.setattr(backend, "local_tpu_chips", lambda: 4)
+        spawned = []
+        with pytest.raises(ServeError, match="already initialised"):
+            self._pool(tmp_path, 2, spawned).start()
+        assert spawned == []
+
+    def test_children_are_pinned_one_chip_each_and_no_template_is_built(
+            self, tmp_path, monkeypatch):
+        from deep_vision_tpu.core import backend
+        from deep_vision_tpu.serve import procpool
+        from deep_vision_tpu.serve.engine import ServeError
+
+        monkeypatch.setattr(backend, "local_tpu_chips", lambda: 4)
+        monkeypatch.setattr(backend, "backend_initialized", lambda: False)
+        spawned = []
+        pool = self._pool(tmp_path, 2, spawned)
+        pool._wait_ready = lambda slot, deadline: None
+        pool.start()
+        try:
+            assert [s.chip for s in spawned] == [0, 1]
+            with pytest.raises(ServeError, match="no parent-side engine"):
+                pool.primary_engine()
+            with pytest.raises(ServeError, match="add_canary"):
+                pool.add_canary(None, 10)
+        finally:
+            pool._stop.set()
+        a, b = procpool._chip_env(0), procpool._chip_env(1)
+        assert a["TPU_VISIBLE_CHIPS"] == "0" and b["TPU_VISIBLE_CHIPS"] == "1"
+        assert a["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert a["TPU_MESH_CONTROLLER_PORT"] != b["TPU_MESH_CONTROLLER_PORT"]
+
+    def test_no_chips_are_counted_when_the_tpu_is_not_selected(self):
+        from deep_vision_tpu.core import backend
+
+        assert backend.local_tpu_chips() == 0  # conftest pins the CPU

@@ -118,7 +118,6 @@ def main(out_path="artifacts/ablate_r04.json", skip_flash=False,
                    "compile_s": round(time.perf_counter() - t0, 1)}
             try:
                 ca = step.cost_analysis()
-                ca = ca[0] if isinstance(ca, (list, tuple)) else ca
                 row["bytes_gb_per_step"] = round(
                     float(ca["bytes accessed"]) / 1e9, 3
                 )

@@ -6,10 +6,9 @@
     PYTHONPATH=. JAX_PLATFORMS=cpu python tools/perf_gate.py --smoke \
         [--workdir artifacts/perf_gate]
 
-The repo's perf story used to be write-only: BENCH_*/MULTICHIP_* JSON
-artifacts accumulated with no consumer, so BENCH_r01's `vs_baseline
-0.949` regression would sail through verify unnoticed. This tool is the
-consumer. Every bench/smoke result appends one row to an append-only
+The repo's perf story used to be write-only: bench JSON artifacts
+accumulated with no consumer, so a regression would sail through verify
+unnoticed. This tool is the consumer. Every bench/smoke result appends one row to an append-only
 `perf_ledger.jsonl` — stamped with the excache-style env fingerprint
 (jax/jaxlib/platform/device kind+count/mesh shape), carrying its own
 crc32c so torn or hand-edited rows quarantine instead of poisoning the

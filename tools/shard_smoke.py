@@ -238,9 +238,8 @@ def main(argv=None) -> int:
 
     import jax
 
-    # the env var alone is read too early when a sitecustomize imported
-    # jax at interpreter startup (conftest.py precedent): pin the config
-    # too, then hard-check the forced device count actually took
+    # hard CPU pin (conftest.py precedent), then hard-check the forced
+    # device count actually took
     jax.config.update("jax_platforms", "cpu")
     n = len(jax.devices())
     f.check(n == 8, f"forced 8-device CPU mesh up (have {n})")
