@@ -1,0 +1,68 @@
+"""Floating-point operations of a function, counted from its jaxpr.
+
+The count is of the mathematics: 2 x the multiply-accumulates of every
+`dot_general` and `conv_general_dilated`, found by walking the jaxpr and
+every jaxpr nested in it. Counted on the plain reference's
+`value_and_grad`, so no remat, kernel or fusion in the program can change
+it. The zeros an input-gradient convolution puts between the rows of a
+strided convolution's output (lhs dilation) are not counted: the forward
+convolution did not multiply by them either.
+"""
+from __future__ import annotations
+
+import math
+
+import jax
+
+
+def _conv_flops(eqn) -> float:
+    lhs, rhs = (v.aval.shape for v in eqn.invars[:2])
+    out = eqn.outvars[0].aval.shape
+    dn = eqn.params["dimension_numbers"]
+    spatial = math.prod(rhs[d] for d in dn.rhs_spec[2:])
+    c_in = rhs[dn.rhs_spec[1]]  # already divided by feature_group_count
+    macs = math.prod(out) * spatial * c_in
+    macs /= math.prod(eqn.params["lhs_dilation"] or (1,))
+    return 2.0 * macs / eqn.params.get("batch_group_count", 1)
+
+
+def _dot_flops(eqn) -> float:
+    lhs = eqn.invars[0].aval.shape
+    (contract, _), _ = eqn.params["dimension_numbers"]
+    return 2.0 * math.prod(eqn.outvars[0].aval.shape) * math.prod(
+        lhs[d] for d in contract)
+
+
+def jaxpr_flops(jaxpr) -> float:
+    total = 0.0
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "conv_general_dilated":
+            total += _conv_flops(eqn)
+        elif name == "dot_general":
+            total += _dot_flops(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            total += jaxpr_flops(sub)
+    return total
+
+
+def flops_of(fn, *args) -> float:
+    """FLOPs of `fn(*args)`; args may be `jax.ShapeDtypeStruct`s."""
+    return jaxpr_flops(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+def train_step_flops(module, cfg, batch_shape, label_shape) -> float:
+    """FLOPs of one training step of the plain reference `module` at
+    `batch_shape`: forward, and backward to every parameter."""
+    import jax.numpy as jnp
+
+    variables = jax.eval_shape(lambda: module.init(cfg, jax.random.PRNGKey(0)))
+
+    def step(params, stats, images, labels):
+        return jax.value_and_grad(
+            lambda p: module.loss_fn(cfg, p, stats, images, labels),
+            has_aux=True)(params)
+
+    return flops_of(step, variables["params"], variables["batch_stats"],
+                    jax.ShapeDtypeStruct(batch_shape, jnp.float32),
+                    jax.ShapeDtypeStruct(label_shape, jnp.int32))

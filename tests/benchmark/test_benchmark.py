@@ -1,0 +1,326 @@
+"""Tests of the benchmark's own yardstick (benchmark/), on the CPU.
+
+The rehearsal cells (tests/benchmark/rehearsal.json) are tiny float32
+models on the 8-device virtual mesh; they are never cells of
+BENCHMARK.json and state no device number.
+"""
+import itertools
+import json
+import os
+import re
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import compare, flops, run, trace  # noqa: E402
+from benchmark import traffic as traffic_mod  # noqa: E402
+from benchmark.adapters import train as train_adapter  # noqa: E402
+from benchmark.reference import resnet, vit  # noqa: E402
+
+REHEARSAL = os.path.join(ROOT, "tests", "benchmark", "rehearsal.json")
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+@pytest.fixture(scope="module")
+def tiny_models():
+    """The rehearsal configurations' models, registered as the program
+    registers its own (width and depth cut; the code paths are the same)."""
+    from deep_vision_tpu.models import register_model
+    from deep_vision_tpu.models.resnet import ResNet
+    from deep_vision_tpu.models.vit import ViT
+
+    register_model("bench_tiny_resnet")(
+        lambda num_classes=10, dtype=None, stem="s2d", **_: ResNet(
+            stage_sizes=(1, 1), width=8, num_classes=num_classes, stem=stem,
+            dtype=dtype))
+    register_model("bench_tiny_vit")(
+        lambda num_classes=10, dtype=None, **_: ViT(
+            depth=2, dim=32, num_heads=2, patch=8, num_classes=num_classes,
+            dtype=dtype))
+    return {"resnet": ResNet(stage_sizes=(1, 1), width=8, num_classes=10,
+                             stem="s2d"),
+            "vit": ViT(depth=2, dim=32, num_heads=2, patch=8,
+                       num_classes=10)}
+
+
+def rehearsal_config(name):
+    with open(os.path.join(ROOT, "tests", "benchmark", "configs",
+                           name + ".json")) as f:
+        return json.load(f)
+
+
+# -- trace reduction ---------------------------------------------------------
+
+def test_interval_union_counts_overlap_and_nesting_once():
+    spans = [(0, 10), (5, 12), (6, 7), (20, 30), (30, 31), (40, 41)]
+    assert trace.union_ns(spans) == 12 + 11 + 1
+    assert trace.union_ns([]) == 0
+    gaps = trace.gaps_ns(spans, 0, 50)
+    assert [(s, e) for s, e, _ in gaps] == [(12, 20), (31, 40), (41, 50)]
+    assert [before for _, _, before in gaps] == [1, 4, 5]
+
+
+def test_idle_share_of_a_hand_made_device_plane():
+    # three executions of the step, 100 ns apart; 60 ns of ops in each
+    # period (two overlapping, one nested); a short other module between
+    modules = [("step", 1000, 70), ("other", 1075, 5), ("step", 1100, 70),
+               ("step", 1200, 70)]
+    ops = []
+    for start in (1000, 1100, 1200):
+        ops += [("%fusion.1 = f32[8] fusion(f32[8] %p)", start, 40),
+                ("%copy.2 = f32[8] custom-call(f32[8] %q)", start + 30, 30),
+                ("%nested = f32[] add()", start + 35, 5)]
+    red = trace.reduce_device(modules, ops)
+    assert red["step_module"] == "step" and red["periods"] == 2
+    assert red["window_s"] == pytest.approx(200e-9)
+    assert red["busy_s"] == pytest.approx(120e-9)
+    assert red["step_device_ms"] == pytest.approx(70e-6)
+    assert red["op_s_per_step"]["fusion.1"] == pytest.approx(40e-9)
+    assert red["gap_s_per_step"] == {"after:copy.2": pytest.approx(40e-9)}
+    assert trace.reduce_device([("step", 0, 5)], ops) is None
+
+
+# -- FLOP count --------------------------------------------------------------
+
+def test_flops_of_one_conv_and_one_dot_general():
+    x = jax.ShapeDtypeStruct((2, 8, 8, 3), jnp.float32)
+    w = jax.ShapeDtypeStruct((3, 3, 3, 16), jnp.float32)
+    conv = lambda x, w: jax.lax.conv_general_dilated(
+        x, w, (2, 2), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    assert flops.flops_of(conv, x, w) == 2 * (2 * 4 * 4 * 16) * (3 * 3 * 3)
+    a = jax.ShapeDtypeStruct((4, 5, 6), jnp.float32)
+    b = jax.ShapeDtypeStruct((4, 6, 7), jnp.float32)
+    assert flops.flops_of(lambda a, b: jnp.einsum("bij,bjk->bik", a, b),
+                          a, b) == 2 * 4 * 5 * 7 * 6
+    # the backward pass of the strided conv: its input gradient multiplies
+    # no inserted zeros, so forward + 2 backward = 3 x forward
+    both = lambda x, w: jax.grad(lambda x, w: conv(x, w).sum(),
+                                 argnums=(0, 1))(x, w)
+    assert flops.flops_of(both, x, w) == 3 * flops.flops_of(conv, x, w)
+
+
+def test_flops_of_resnet50_per_image():
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "resnet50.json")) as f:
+        config = json.load(f)
+    per_image = flops.train_step_flops(resnet, config, (2, 112, 112, 12),
+                                       (2,)) / 2
+    assert per_image == pytest.approx(24.6e9, rel=0.05)
+
+
+# -- the plain references against the program's models -----------------------
+
+@pytest.mark.parametrize("family,module,config_name,image_shape", [
+    ("resnet", resnet, "tiny_resnet", (16, 16, 12)),
+    ("vit", vit, "tiny_vit", (32, 32, 3)),
+])
+def test_reference_matches_the_programs_model(tiny_models, family, module,
+                                              config_name, image_shape):
+    from deep_vision_tpu.losses import classification_loss_fn
+
+    # every BN scale 1 here, so that every leaf has a gradient to compare
+    config = {**rehearsal_config(config_name), "tail_bn_scale": 1.0}
+    model = tiny_models[family]
+    variables = module.init(config, jax.random.PRNGKey(3))
+    rng = np.random.RandomState(0)
+    x = rng.rand(8, *image_shape).astype(np.float32)
+    y = rng.randint(0, 10, (8,)).astype(np.int32)
+    stats = variables["batch_stats"]
+
+    def program_loss(params):
+        out = model.apply({"params": params, **({"batch_stats": stats}
+                                                if stats else {})},
+                          x, train=True,
+                          mutable=["batch_stats"] if stats else False)
+        logits = out[0] if stats else out
+        return classification_loss_fn(logits, {"label": y})[0]
+
+    with jax.default_matmul_precision("highest"):
+        lp, gp = jax.jit(jax.value_and_grad(program_loss))(
+            variables["params"])
+        (lr, _), gr = jax.jit(jax.value_and_grad(
+            lambda p: module.loss_fn(config, p, stats, x, y),
+            has_aux=True))(variables["params"])
+    assert float(lp) == pytest.approx(float(lr), rel=1e-5)
+    for a, b in zip(jax.tree.leaves(gp), jax.tree.leaves(gr)):
+        assert float(jnp.linalg.norm(a - b)) <= 1e-3 * float(
+            jnp.linalg.norm(b)) + 1e-7
+
+
+# -- the manifest ------------------------------------------------------------
+
+@pytest.mark.parametrize("path", [os.path.join(ROOT, "BENCHMARK.json"),
+                                  REHEARSAL])
+def test_manifest_is_consistent(path):
+    m = run.load_manifest(path)
+    configs = {c["name"]: c for c in m["configs"]}
+    cells = {c["name"]: c for c in m["workloads"]}
+    e2e = {x["name"]: x for x in m["end_to_end"]}
+    assert len(cells) == len(m["workloads"]) and "setup_s" in e2e
+    assert len({(c["config"], c["traffic"]) for c in cells.values()}) \
+        == len(cells)
+    for entry in configs.values():
+        assert os.path.exists(os.path.join(ROOT, entry["file"]))
+        assert any(c["config"] == entry["name"] for c in cells.values())
+    if path != REHEARSAL:
+        four = [c for c in cells.values() if c["chips"] == 4]
+        assert all(c["chips"] in (1, 4) for c in cells.values())
+        assert len(four) <= max(1, len(cells) // 4)
+    for cell in cells.values():
+        assert cell["config"] in configs and len(cell["why"]) <= 200
+        resolved, _, traffic = run.resolve(m, cell["name"])  # files parse
+        # every number compared has a limit of the cell's own, and is held
+        assert set(resolved["limits"]) == set(compare.NUMBERS)
+        assert all(0 < v < 1 for v in resolved["limits"].values())
+        assert "limits" not in traffic
+    for metric in m["end_to_end"] + m["per_layer"]:
+        assert NAME.match(metric["name"]) and UNIT.match(metric["unit"])
+        assert metric["better"] in ("lower", "higher")
+        assert set(metric.get("workloads", cells)) <= set(cells)
+        run.find_file(m, "metrics", metric["name"] + ".py")
+    for metric in m["end_to_end"]:
+        assert 0 < metric["bound"] <= 0.1
+    for metric in m["per_layer"]:
+        assert metric["moves"] in e2e
+        moved = e2e[metric["moves"]]
+        for name in metric.get("workloads", cells):
+            assert run.applies(moved, name)
+    for name in list(configs) + list(cells) + [c["traffic"]
+                                               for c in cells.values()]:
+        assert NAME.match(name)
+
+
+def test_run_refuses_a_real_cell_without_the_chip():
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    done = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+         "--workload", "resnet50_train_b128", "--seed", "1", "--seconds",
+         "1", "--trace", "0"], cwd=ROOT, env=env, capture_output=True,
+        text=True, timeout=120)
+    assert done.returncode == run.NO_CHIP
+    assert "{" not in done.stdout  # no result line
+
+
+# -- a whole run, rehearsed; and the same with the timed path broken ---------
+
+def rehearse(cell):
+    return run.run_cell(run.load_manifest(REHEARSAL), cell, 2 ** 31 + 11,
+                        0.3, 0, require_chip=False)
+
+
+@pytest.mark.parametrize("cell", ["tiny_resnet_train", "tiny_vit_train"])
+def test_rehearsal_run_is_correct(tiny_models, cell):
+    result = rehearse(cell)
+    assert result["correct"], (result["compared"], result["faults"])
+    assert result["device"]["platform"] == "cpu"
+    assert set(result["metrics"]) == {"img_per_s_chip", "step_ms_p95",
+                                      "setup_s"}
+    assert result["attempted"] > 0 and result["failed"] == 0
+    assert list(result)[-1] == "compared"
+
+
+def _state_unchanged(impl):
+    def broken(self, state, batch):
+        _, metrics = impl(self, state, batch)
+        return state, metrics
+    return broken
+
+
+def _rows_left_out(share):
+    def wrap(impl):
+        def broken(self, state, batch):
+            keep = batch[self.input_key].shape[0] // share
+            return impl(self, state, jax.tree.map(lambda x: x[:keep], batch))
+        return broken
+    return wrap
+
+
+@pytest.mark.parametrize("fault,wrap", [
+    ("state_unchanged", _state_unchanged),
+    ("half_of_the_batch_left_out", _rows_left_out(2)),
+    ("exchange_between_chips_left_out", _rows_left_out(8)),
+])
+def test_a_broken_timed_path_is_not_correct(tiny_models, monkeypatch, fault,
+                                            wrap):
+    """Each fault a training cell can have, planted under `Trainer.fit`:
+    the step returns its state unchanged; half of the batch is left out
+    and the mean taken over the rest; only one of the 8 devices' rows
+    count, as with the gradient exchange left out."""
+    from deep_vision_tpu.train.trainer import Trainer
+
+    monkeypatch.setattr(Trainer, "_train_step_impl",
+                        wrap(Trainer._train_step_impl))
+    result = rehearse("tiny_resnet_train")
+    compared = result["compared"]
+    assert not result["correct"], (fault, compared)
+    assert any(c["value"] > c["limit"] for c in compared.values())
+    if fault == "state_unchanged":  # both read 1: the upper reading
+        assert compared["grad_gap"]["value"] == pytest.approx(1, abs=0.05)
+        assert compared["delta_gap"]["value"] == pytest.approx(1, abs=1e-6)
+
+
+def test_the_first_gradient_is_read_after_exactly_one_closed_step(
+        tiny_models, monkeypatch):
+    """`first_steps` reads the optimizer's state between two `fit` calls,
+    the first of one batch: a loop that pulls its feed ahead of the step
+    (prefetch) cannot move the reading."""
+    from deep_vision_tpu.train.trainer import Trainer
+
+    fed, real_fit = [], Trainer.fit
+
+    def fit_pulling_ahead(self, train_data_fn, *a, **kw):
+        feed = iter(train_data_fn())
+        ahead = list(itertools.islice(feed, 4))  # pulled before any step
+        fed.append(len(ahead))
+        return real_fit(self, lambda: itertools.chain(ahead, feed), *a, **kw)
+
+    monkeypatch.setattr(Trainer, "fit", fit_pulling_ahead)
+    result = rehearse("tiny_resnet_train")
+    assert fed[:2] == [1, train_adapter.COMPARED_STEPS - 1]
+    assert result["correct"], result["compared"]
+
+
+def test_every_number_is_held_to_its_limit():
+    values = {"loss_gap": 1e-5, "grad_gap": 0.03, "delta_gap": 0.01}
+    limits = {"loss_gap": 1e-4, "grad_gap": 0.3, "delta_gap": 0.1}
+    ok, compared = compare.judge(values, limits)
+    assert ok and compared["grad_gap"] == {"value": 0.03, "limit": 0.3}
+    for name in compare.NUMBERS:
+        assert not compare.judge({**values, name: 0.5}, limits)[0]
+        assert not compare.judge({**values, name: float("nan")}, limits)[0]
+        # a number the cell's file does not name, or leaves null, fails
+        assert not compare.judge(values, {**limits, name: None})[0]
+        assert not compare.judge(
+            values, {k: v for k, v in limits.items() if k != name})[0]
+
+
+@pytest.mark.parametrize("cell", ["tiny_resnet_train", "tiny_vit_train"])
+def test_the_lower_precision_control_fails(cell):
+    """The reference computed in bfloat16, the precision below the float32
+    these rehearsal configurations state, put in the program's place and
+    judged at the cell's limits."""
+    cell, config, traffic = run.resolve(run.load_manifest(REHEARSAL), cell)
+    shape = (16, 16, 12) if config["reference"] == "resnet" else (32, 32, 3)
+    pool = traffic_mod.make_pool(traffic, shape, config["num_classes"], 5)
+    devices = jax.devices()[:1]
+    reference = train_adapter.reference_steps(config, pool, 5, devices)
+    control = train_adapter.reference_steps(config, pool, 5, devices,
+                                            control=True)
+    normalised = train_adapter.normalised_update(config)
+    same, _ = compare.judge(compare.gaps(
+        train_adapter.as_program(reference), reference, normalised),
+        cell["limits"])
+    ok, compared = compare.judge(compare.gaps(
+        train_adapter.as_program(control), reference, normalised),
+        cell["limits"])
+    assert same and not ok, compared
