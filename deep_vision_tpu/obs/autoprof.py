@@ -37,6 +37,7 @@ from collections import deque
 from typing import Callable, Optional, Tuple
 
 from deep_vision_tpu.obs.registry import Registry, get_registry
+from deep_vision_tpu.obs.trace import start_profiler
 
 REASONS = ("static_window", "step_time_z", "data_wait_z",
            "recompile_burst", "hbm_jump", "manual")
@@ -270,10 +271,8 @@ class AutoProfiler:
         self._seq += 1
         d = os.path.join(self.profile_dir, f"cap-{self._seq:03d}-{reason}")
         try:
-            import jax
-
             os.makedirs(d, exist_ok=True)
-            jax.profiler.start_trace(d)
+            start_profiler(d)
         except Exception as e:
             _release_capture()
             self._journal(reason, "failed", step=step,

@@ -12,9 +12,18 @@ data-wait, dispatch, and device work. StepClock separates them:
                  the device pipeline — dispatch_ms + sync_ms on those
                  steps is the true per-step cost
 
-The fence runs every `sample_every` steps (default 16) so steady-state
-throughput stays async and unperturbed; between fences the device queue
-absorbs the timing. Recompiles are counted process-wide from the
+The fence runs every `sample_every` steps (default 16). It was meant to
+leave the steps between two fences asynchronous; `Trainer`'s loop is not:
+after every dispatch it fetches `int(state.step)`, the learning rate and
+each metric for the journal, the loggers and the health guard, so the
+host waits for every step to finish before it takes the next batch, and
+the device waits for the host in turn (PERF.md §5: one idle gap a step).
+`{name}_host_fetches_total` counts those blocking device->host fetches;
+the spans `train/data_wait` (here) and `train/place`, `train/dispatch`,
+`train/fetch`, `train/log` (train/trainer.py) say where in the gap the
+host was. Only the GAN loops, which keep their metrics on the device
+until the epoch ends, run ahead between fences. Recompiles are counted
+process-wide from the
 `/jax/core/compile/backend_compile_duration` monitoring event (fires per
 backend compile, silent on cache hits — verified against jit cache
 behavior in tests), HBM from `device.memory_stats()` where the backend
@@ -27,6 +36,7 @@ import time
 from typing import Iterable, Iterator, Optional
 
 from deep_vision_tpu.obs.registry import Registry, get_registry
+from deep_vision_tpu.obs.trace import span
 
 # -- recompile tracking ------------------------------------------------------
 
@@ -76,8 +86,10 @@ def compile_seconds() -> float:
 
 
 def hbm_stats(device=None) -> "tuple[Optional[int], Optional[int]]":
-    """(bytes_in_use, peak_bytes_in_use) for one device; None where the
-    backend has no stats (CPU).
+    """(bytes_in_use, peak bytes) for one device; None where the backend
+    has no stats (CPU). The peak is the larger of `peak_bytes_in_use` and
+    `peak_bytes_reserved`: live arrays alone leave out what the step
+    program reserves while it runs (11 GB of ResNet-50's on a v5e).
 
     The peak matters more than the instant: OOMs and fragmentation are
     high-water phenomena, an autoprof HBM trigger keyed on the
@@ -93,8 +105,9 @@ def hbm_stats(device=None) -> "tuple[Optional[int], Optional[int]]":
         if not stats:
             return None, None
         in_use = int(stats.get("bytes_in_use", stats.get("bytes_in_use_", 0)))
-        peak = stats.get("peak_bytes_in_use")
-        return in_use, (int(peak) if peak is not None else None)
+        peaks = [stats[k] for k in ("peak_bytes_in_use",
+                                    "peak_bytes_reserved") if k in stats]
+        return in_use, (int(max(peaks)) if peaks else None)
     except Exception:
         return None, None
 
@@ -163,6 +176,9 @@ class StepClock:
         self._c_starved = r.counter(
             f"{name}_data_starved_steps_total",
             "steps whose data wait exceeded their dispatch time")
+        self._c_fetches = r.counter(
+            f"{name}_host_fetches_total",
+            "blocking device->host fetches made by the step loop")
 
     # -- data-wait side ----------------------------------------------------
 
@@ -178,12 +194,15 @@ class StepClock:
         (productive), never double-counted as data_wait
         (tests/test_goodput.py pins this with a depth-2 prefetcher)."""
         it = iter(data)
+        wait_span = f"{self.name}/data_wait"
         while True:
             t0 = time.perf_counter()
-            try:
-                batch = next(it)
-            except StopIteration:
-                return
+            # `step`: the dispatch this batch feeds (see `step()`)
+            with span(wait_span, step=self._steps_seen + 1):
+                try:
+                    batch = next(it)
+                except StopIteration:
+                    return
             self._last_data_wait_ms = (time.perf_counter() - t0) * 1e3
             yield batch
 
@@ -233,6 +252,10 @@ class StepClock:
         if self.journal is not None:
             self.journal.step(rec.step if rec.step is not None
                               else self._steps_seen, **rec.fields())
+
+    def note_host_fetches(self, n: int) -> None:
+        """Count `n` blocking device->host fetches the loop just made."""
+        self._c_fetches.inc(n)
 
     @property
     def sync_samples(self) -> int:
@@ -284,7 +307,10 @@ class _StepRecord:
             import jax
 
             t1 = time.perf_counter()
-            jax.block_until_ready(self._fenced)
+            # the host blocked on the device, as in the loop's own fetches
+            with span(f"{self._clock.name}/fetch",
+                      step=self._clock.steps_seen, n=0):
+                jax.block_until_ready(self._fenced)
             self.sync_ms = (time.perf_counter() - t1) * 1e3
         if exc_type is None and self._auto_commit:
             self.commit()

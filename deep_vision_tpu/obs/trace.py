@@ -9,11 +9,18 @@ PRs the same way journals do.
 
 Design constraints, in order:
 
-- **Zero cost when off.** Every instrumentation site calls the
-  module-level `span(...)`; with no tracer installed it returns a shared
-  no-op context manager (no allocation, no branching in callers). The
-  data pipeline and spawned workers import this module, so it stays
-  jax-free at import like registry.py.
+- **One call, two sinks.** Every instrumentation site calls the
+  module-level `span(...)`. Where `jax` is loaded the span is also a
+  `jax.profiler.TraceAnnotation` of the same name and args: whenever any
+  profiler session is live (`--profile`, an autoprof capture, a
+  benchmark's) the span lies in that session's host plane, on the clock
+  its device planes use, and a reader can lay it over the device's idle
+  gaps. With no session and no tracer it costs one annotation
+  enter/exit (about a microsecond) and nothing else.
+- **jax-free at import.** The data pipeline and spawned workers import
+  this module, so it never imports jax: the annotation class is taken
+  only once `jax` is in `sys.modules`; until then `span(...)` returns a
+  shared no-op context manager.
 - **Always-valid JSON on disk.** A hung or SIGKILLed run is exactly when
   the trace matters most, so flush() rewrites the whole file atomically
   (tmp + os.replace) instead of streaming an unterminated array. Spans
@@ -34,6 +41,7 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import sys
 import threading
 import time
 from typing import Callable, Dict, List, Optional
@@ -71,29 +79,59 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """One in-flight span; records a complete ("X") event on exit."""
+_annotation = None  # the profiler's annotation class, once jax is loaded
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+
+def _annotation_class():
+    """`jax.profiler.TraceAnnotation` with the spans' `set`, or None while
+    `jax` is not in `sys.modules` (this module never imports it)."""
+    global _annotation
+    if _annotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        base = getattr(profiler, "TraceAnnotation", None)
+        if base is not None:
+            class _Annotation(base):
+                __slots__ = ()
+                set = base.set_metadata  # recorded only inside a session
+
+            _annotation = _Annotation
+    return _annotation
+
+
+class _Span:
+    """One in-flight span; records a complete ("X") event on exit, and is
+    the profiler's annotation meanwhile."""
+
+    __slots__ = ("_tracer", "name", "args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._annotation = None
 
     def set(self, **args) -> None:
         """Attach args discovered mid-span (e.g. the optimizer step, which
         is only known after the state fetch)."""
         self.args.update(args)
+        if self._annotation is not None:
+            self._annotation.set(**args)
 
     def __enter__(self):
+        cls = _annotation or _annotation_class()
+        if cls is not None:
+            self._annotation = cls(self.name, **self.args)
+            self._annotation.__enter__()
         self._t0 = _now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        t1 = _now_us()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
-        self._tracer._record(self.name, self._t0, _now_us(), self.args)
+        self._tracer._record(self.name, self._t0, t1, self.args)
         return False
 
 
@@ -284,7 +322,9 @@ def get_tracer() -> Optional[Tracer]:
 
 
 def span(name: str, **args):
-    """A span on the active tracer, or a shared no-op when tracing is off.
+    """A span: on the active tracer if one is installed, and in the host
+    plane of any live `jax.profiler` session. A shared no-op in a process
+    that has neither a tracer nor jax.
 
     The instrumentation idiom used by every layer:
 
@@ -292,9 +332,12 @@ def span(name: str, **args):
             batch = q.get()
     """
     t = _active
-    if t is None:
+    if t is not None:
+        return t.span(name, **args)
+    cls = _annotation or _annotation_class()
+    if cls is None:
         return _NULL_SPAN
-    return t.span(name, **args)
+    return cls(name, **args)
 
 
 def trace_event(name: str, t0_us: float, t1_us: Optional[float] = None,
@@ -308,6 +351,26 @@ def trace_event(name: str, t0_us: float, t1_us: Optional[float] = None,
 def now_us() -> float:
     """The tracer's clock, for callers building explicit trace_event()s."""
     return _now_us()
+
+
+def start_profiler(log_dir: str) -> None:
+    """Start a `jax.profiler` session as every capture of this program is
+    taken (`--profile-dir`, autoprof's triggers; stop it with
+    `jax.profiler.stop_trace()`): the host plane holds the spans of this
+    module and the runtime's own events, on the device planes' clock. The
+    Python tracer stays off: with it a ResNet-50 step loop ran four times
+    slower and a 20-step capture was 79-247 MB. The host plane is not
+    free either: on a TPU the runtime re-tiles every host batch it copies
+    and records one `Transpose` event a tile (100,000 a step at 77 MB),
+    which stretched the wait for the copy from ~10 ms to 13-400 ms in
+    captured steps; the loop's own spans read the same (PERF.md,
+    section 6, PR 26). Read a capture's device times with that in mind."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(log_dir, profiler_options=options)
 
 
 def traced(name: Optional[str] = None, **static_args) -> Callable:

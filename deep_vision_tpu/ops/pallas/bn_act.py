@@ -156,6 +156,7 @@ def _pallas_rows(x, scale, bias, residual, *, act: str | None,
             in_specs=[row_spec, row_spec, par_spec, par_spec],
             out_specs=row_spec,
             interpret=interpret,
+            name="bn_act_res_fwd",
         )(x2, residual.reshape(rows, lane_c), a2, b2)
     else:
         out = pl.pallas_call(
@@ -165,6 +166,7 @@ def _pallas_rows(x, scale, bias, residual, *, act: str | None,
             in_specs=[row_spec, par_spec, par_spec],
             out_specs=row_spec,
             interpret=interpret,
+            name="bn_act_fwd",
         )(x2, a2, b2)
     return out.reshape(x.shape)
 

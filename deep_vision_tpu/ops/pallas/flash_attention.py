@@ -193,6 +193,7 @@ def _flash_forward_shard(q, k, v, *, causal: bool, scale: float, block_q: int,
             pltpu.VMEM((block_q, d), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qr, kr, vr)
     out, lse = res if need_lse else (res[0], None)
     return out.reshape(b, h, t, d).transpose(0, 2, 1, 3), lse
@@ -330,6 +331,7 @@ def _flash_backward_shard(q, k, v, out, lse, g, delta_shift=None, *,
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qr, kr, vr, dor, lse, delta)
 
     # swapped grid: k blocks outer, q blocks inner
@@ -352,6 +354,7 @@ def _flash_backward_shard(q, k, v, out, lse, g, delta_shift=None, *,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qr, kr, vr, dor, lse, delta)
 
     unshape = lambda x, tt: x.reshape(b, h, tt, d).transpose(0, 2, 1, 3)

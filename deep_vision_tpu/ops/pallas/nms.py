@@ -129,6 +129,7 @@ def pallas_nms(boxes, scores, max_detections: int, iou_threshold: float,
             in_specs=[row] * 5,
             out_specs=[out_row, out_row],
             interpret=bool(interpret),
+            name="nms",
         )(*rows)
 
     out_s, out_i = over_data_axis(select, (True,) * 5)(

@@ -50,6 +50,11 @@ from deep_vision_tpu.resilience.rendezvous import HostLostError, WorldResized
 _global_sum = jax.jit(jnp.sum)
 
 
+def _host_bytes(batch: dict) -> int:
+    """Bytes of a batch's leaves, as handed to `device_put`."""
+    return sum(int(getattr(v, "nbytes", 0)) for v in batch.values())
+
+
 def _set_lr(opt_state, lr: float):
     """Set the injected learning_rate hyperparam to an absolute value, on
     the devices the old leaf lives on: a leaf placed anywhere else changes
@@ -599,6 +604,8 @@ class Trainer:
                                        registry=self.clock.registry)
         return compiled
 
+    # (the scopes name every op of a step in the program's HLO metadata)
+    @jax.named_scope("train_step")
     def _train_step_impl(self, state: TrainState, batch):
         step_rng = jax.random.fold_in(state.rng, state.step)
 
@@ -639,6 +646,7 @@ class Trainer:
             metrics["skipped"] = 1.0 - ok.astype(jnp.float32)
         return new_state, metrics
 
+    @jax.named_scope("eval_step")
     def _eval_step_impl(self, state: TrainState, batch):
         variables = {"params": state.params}
         if state.batch_stats:
@@ -760,12 +768,15 @@ class Trainer:
 
     def train_step(self, batch) -> dict:
         self._profiler_hook()
+        i = self.clock.steps_seen  # the host's dispatch index (fit's loop)
         if isinstance(batch, PlacedBatch):
             batch = batch.data  # device prefetcher already padded + placed
         else:
-            batch = shard_batch(self.mesh, self._pad_and_mask(batch),
-                                axes=self._batch_axes)
-        with self._mesh_context():
+            with span("train/place", step=i) as sp:
+                batch = self._pad_and_mask(batch)
+                sp.set(bytes=_host_bytes(batch))
+                batch = shard_batch(self.mesh, batch, axes=self._batch_axes)
+        with span("train/dispatch", step=i), self._mesh_context():
             if self._checkify:
                 err, (new_state, metrics) = self._train_step_err(self.state,
                                                                  batch)
@@ -788,16 +799,19 @@ class Trainer:
         if self._train_multi is None:
             raise ValueError("train_superstep needs Trainer(multistep=K>1)")
         self._profiler_hook()
+        i = self.clock.steps_seen
         if isinstance(batches, PlacedBatch):
             k, stacked = batches.group, batches.data
         else:
-            k, stacked = len(batches), self._stack_batches(batches)
+            with span("train/place", step=i) as sp:
+                k, stacked = len(batches), self._stack_batches(batches)
+                sp.set(bytes=_host_bytes(stacked))
         if k != self.multistep:
             raise ValueError(
                 f"superstep got {k} batches, configured multistep is "
                 f"{self.multistep} (the epoch tail must use train_step)"
             )
-        with self._mesh_context():
+        with span("train/dispatch", step=i), self._mesh_context():
             multi_fn = self._cached_step("superstep", self._train_multi,
                                          self._train_multi_cache, stacked)
             self.state, metrics = multi_fn(self.state, stacked)
@@ -1206,7 +1220,9 @@ class Trainer:
             n = batch.n
         else:
             n = np.shape(batch[self.input_key])[0]
-        with span("train/step", epoch=epoch) as sp:
+        i = self.clock.steps_seen + 1  # this dispatch, known before any fetch
+        with jax.profiler.StepTraceAnnotation("train", step_num=i), \
+                span("train/step", step=i, epoch=epoch) as sp:
             with self.clock.step(batch_size=n, auto_commit=False) as rec:
                 metrics = self.train_step(batch)
                 self._host_fetch(lambda: rec.fence_on(metrics))
@@ -1215,13 +1231,25 @@ class Trainer:
             # starvation signal compares data_wait against it);
             # commit() folds their cost into step_time_ms. Lease-checked
             # (_host_fetch): in a multi-host world a dead peer wedges
-            # them forever otherwise.
-            opt_step = self._host_fetch(lambda: int(self.state.step))
-            lr = self.lr_at(opt_step)
-            sp.set(step=opt_step)
-            rec.commit(step=opt_step,
-                       metrics={"loss": metrics["loss"], "lr": lr}
-                       if "loss" in metrics else {"lr": lr})
+            # them forever otherwise. One fetch for the step counter, one
+            # for the LR, one per metric (loggers + health share them).
+            n_fetch = 2 + len(metrics)
+            with span("train/fetch", step=i, n=n_fetch):
+                opt_step = self._host_fetch(lambda: int(self.state.step))
+                lr = self.lr_at(opt_step)
+                metrics_f = {k: float(v) for k, v in metrics.items()}
+            self.clock.note_host_fetches(n_fetch)
+            sp.set(opt_step=opt_step)
+            with span("train/log", step=i):
+                return self._log_single_step(rec, opt_step, lr, metrics_f,
+                                             n, epoch)
+
+    def _log_single_step(self, rec, opt_step, lr, metrics_f, n, epoch):
+        """Every sink fed after a step: clock (registry + journal),
+        anomaly triggers, loggers, health guard, preemption poll."""
+        rec.commit(step=opt_step,
+                   metrics={"loss": metrics_f["loss"], "lr": lr}
+                   if "loss" in metrics_f else {"lr": lr})
         # publish the host-side mirror the telemetry scraper reads (plain
         # attribute writes: benign to race, never a device fetch)
         self._live_step, self._live_epoch = opt_step, epoch
@@ -1231,9 +1259,6 @@ class Trainer:
         # capture that the NEXT step's _profiler_hook starts
         if self.prof is not None:
             self.prof.observe_step(opt_step, rec.fields())
-        # one host fetch for loggers + health (log_step floats every
-        # metric anyway, so this adds no extra device sync)
-        metrics_f = {k: float(v) for k, v in metrics.items()}
         loss_f = metrics_f.get("loss")
         grad_norm_f = metrics_f.get("grad_norm")
         skipped = (self._skip_nonfinite
@@ -1273,27 +1298,39 @@ class Trainer:
             n_total = item.n
         else:
             n_total = sum(int(np.shape(b[self.input_key])[0]) for b in item)
-        with span("train/step", epoch=epoch) as sp:
+        i = self.clock.steps_seen + 1
+        with jax.profiler.StepTraceAnnotation("train", step_num=i), \
+                span("train/step", step=i, epoch=epoch, multistep=k) as sp:
             with self.clock.step(batch_size=n_total,
                                  auto_commit=False) as rec:
                 metrics_k = self.train_superstep(item)
                 self._host_fetch(lambda: rec.fence_on(metrics_k))
-            opt_step = self._host_fetch(lambda: int(self.state.step))
-            lr = self.lr_at(opt_step)
-            sp.set(step=opt_step, multistep=k)
-            last = metrics_k[-1]
-            # journal: ONE step event per dispatch (the thing that actually
-            # happened), stamped multistep=K; loggers below keep per-
-            # microstep series so histories stay comparable across K
-            rec.commit(step=opt_step,
-                       metrics={"loss": last["loss"], "lr": lr}
-                       if "loss" in last else {"lr": lr},
-                       extra={"multistep": k})
+            with span("train/fetch", step=i, n=3):
+                opt_step = self._host_fetch(lambda: int(self.state.step))
+                lr = self.lr_at(opt_step)
+                # ONE fetch for all K microsteps
+                floats = jax.device_get(metrics_k)
+            self.clock.note_host_fetches(3)
+            sp.set(opt_step=opt_step)
+            with span("train/log", step=i):
+                return self._log_superstep(rec, opt_step, lr, floats,
+                                           n_total, epoch)
+
+    def _log_superstep(self, rec, opt_step, lr, floats, n_total, epoch):
+        """`_log_single_step` for the K microsteps of one dispatch."""
+        k = self.multistep
+        last = floats[-1]
+        # journal: ONE step event per dispatch (the thing that actually
+        # happened), stamped multistep=K; loggers below keep per-
+        # microstep series so histories stay comparable across K
+        rec.commit(step=opt_step,
+                   metrics={"loss": last["loss"], "lr": lr}
+                   if "loss" in last else {"lr": lr},
+                   extra={"multistep": k})
         self._live_step, self._live_epoch = opt_step, epoch
         self._live_eps = rec.examples_per_sec
         if self.prof is not None:
             self.prof.observe_step(opt_step, rec.fields())
-        floats = jax.device_get(metrics_k)  # ONE fetch for all K microsteps
         n_each = max(1, n_total // k)
         for i, mf in enumerate(floats):
             step_i = opt_step - (k - 1) + i

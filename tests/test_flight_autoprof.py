@@ -314,6 +314,19 @@ def test_hbm_stats_reads_peak():
 
     assert hbm_stats(FakeDev()) == (100, 250)
 
+    class ReservedIsLarger:  # the step program's own memory (ROADMAP C11)
+        def memory_stats(self):
+            return {"bytes_in_use": 100, "peak_bytes_in_use": 250,
+                    "peak_bytes_reserved": 900}
+
+    assert hbm_stats(ReservedIsLarger()) == (100, 900)
+
+    class OnlyReserved:
+        def memory_stats(self):
+            return {"bytes_in_use": 100, "peak_bytes_reserved": 300}
+
+    assert hbm_stats(OnlyReserved()) == (100, 300)
+
     class NoPeak:
         def memory_stats(self):
             return {"bytes_in_use": 7}

@@ -342,14 +342,51 @@ def test_trace_digest_on_real_cpu_capture(tmp_path):
     assert "error" not in d
     assert d["totals"]["compute_ms"] > 0
     assert d["totals"]["collective_ms"] == 0  # single-device program
-    ops = {r["op"]: r for r in d["ops"]}
-    assert "dot" in ops and ops["dot"]["category"] == "compute"
-    assert ops["dot"]["count"] == 3
+    # (XLA:CPU calls the matmul `dot_general.N`; older ones `dot.N`)
+    dot, = [r for r in d["ops"] if r["op"].startswith("dot")]
+    assert dot["category"] == "compute"
+    assert dot["count"] == 3
     assert any(r["category"] == "host" for r in d["ops"])
+    assert d["spans"] == []  # no span of the program's in this capture
     text = render_digest(d)
-    assert "step-time decomposition" in text and "dot" in text
+    assert "step-time decomposition" in text and dot["op"] in text
     # the in-process run surfaces on /statusz
     assert perfwatch.telemetry_status()["digest"]["compute_ms"] > 0
+
+
+def test_trace_digest_lists_the_programs_spans(tmp_path):
+    """A capture taken as the program takes them holds its spans in the
+    host plane; nested events count once in the totals."""
+    from deep_vision_tpu.obs.trace import span, start_profiler
+    from tools.trace_digest import digest, render_digest
+
+    f = jax.jit(lambda x: jnp.tanh(x @ x).sum())
+    x = jnp.ones((32, 32))
+    f(x).block_until_ready()
+    cap = str(tmp_path / "cap")
+    start_profiler(cap)
+    try:
+        for i in range(3):
+            with span("train/step", step=i):
+                with span("train/dispatch", step=i):
+                    y = f(x)
+                with span("train/fetch", step=i, n=1):
+                    float(y)
+    finally:
+        jax.profiler.stop_trace()
+    d = digest(cap)
+    assert "error" not in d
+    spans = {r["span"]: r for r in d["spans"]}
+    assert set(spans) == {"train/step", "train/dispatch", "train/fetch"}
+    assert all(r["count"] == 3 for r in spans.values())
+    assert spans["train/step"]["total_ms"] >= (
+        spans["train/dispatch"]["total_ms"] + spans["train/fetch"]["total_ms"])
+    # host events nest (PjitFunction > Execute > ...): their union, not
+    # their sum, is the host's total
+    host_sum = sum(r["total_ms"] for r in digest(cap, top_k=10_000)["ops"]
+                   if r["category"] == "host")
+    assert 0 < d["totals"]["host_ms"] < host_sum
+    assert "train/fetch" in render_digest(d)
 
 
 def test_trace_digest_missing_capture_degrades(tmp_path):

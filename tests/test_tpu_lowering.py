@@ -69,6 +69,43 @@ def test_nms_lowers_at_yolo_scale(batch):
         S((batch, 10647, 4), jnp.float32), S((batch, 10647), jnp.float32))
 
 
+def _bn_no_residual(x, a, b):
+    def loss(x, a, b):
+        y = fused_scale_bias_act(x, a, b, interpret=False)
+        return jnp.sum(y.astype(jnp.float32))
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(x, a, b)
+
+
+def _flash_fwd_bwd(q, k, v):
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, interpret=False)
+                       .astype(jnp.float32))
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+_X = S((128, 56, 56, 64), jnp.bfloat16)
+_P = S((64,), jnp.float32)
+_Q = S((2, 1024, 12, 64), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("name, fn, specs", [
+    ("bn_act_fwd", _bn_no_residual, (_X, _P, _P)),
+    ("bn_act_res_fwd", _bn_fwd_bwd, (_X, _P, _P, _X)),
+    ("flash_fwd", _flash_fwd_bwd, (_Q, _Q, _Q)),
+    ("flash_bwd_dq", _flash_fwd_bwd, (_Q, _Q, _Q)),
+    ("flash_bwd_dkv", _flash_fwd_bwd, (_Q, _Q, _Q)),
+    ("nms", lambda b, s: pallas_nms(b, s, 100, 0.5, 0.5, interpret=False),
+     (S((1, 10647, 4), jnp.float32), S((1, 10647), jnp.float32))),
+])
+def test_each_kernel_carries_its_name_into_the_program(name, fn, specs):
+    """A `name=` on every `pallas_call`: a trace's reader finds the kernel
+    by it, on one chip and on four, not by its position in the program."""
+    import re
+
+    text = lower_for_tpu(fn, *specs)
+    assert re.search(rf"\b{name}\b", text), name
+
+
 def test_kernel_in_a_multi_device_program_needs_the_mesh_context(mesh8):
     """XLA cannot partition a Mosaic call: in a program over 8 devices the
     lowering refuses it, and under the trainers' `jax.set_mesh` context the

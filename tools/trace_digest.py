@@ -18,28 +18,29 @@ table inside the postmortem report), and — when called in-process —
 `perfwatch.note_digest` so the telemetry /statusz perf section carries
 the last decomposition next to the live step-time quantiles.
 
-Parsing needs the pure-python protobuf fallback (the xplane pb2 modules
-ship without C extensions here); the env var is set before any protobuf
-import, and a missing/foreign proto degrades to an explanatory error,
-never a crash.
+The capture is read with `jax.profiler.ProfileData`, as the benchmark's
+reduction reads it (`benchmark/trace.py`, whose interval union and op
+names this file uses): no TensorFlow, no protobuf bindings. Captures taken
+by the program (`obs.trace.start_profiler`) also hold its own spans
+(`train/fetch`, `train/log`, ... — obs/README.md) in the host plane; their
+totals are printed beside the op table, so "the step got slower" splits
+into device ops and what the host loop was doing meanwhile.
 """
 from __future__ import annotations
 
+import argparse
+import json
 import os
-
-# must precede the first protobuf import anywhere in the process; a
-# setdefault so an operator's explicit choice wins
-os.environ.setdefault("PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python")
-
-import argparse  # noqa: E402
-import json  # noqa: E402
-import re  # noqa: E402
-import sys  # noqa: E402
-from typing import Dict, List, Optional  # noqa: E402
+import re
+import sys
+from typing import Dict, List
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-__all__ = ["find_xplanes", "digest", "render_digest", "CATEGORIES"]
+from benchmark.trace import OPS_LINE, short_name, union_ns  # noqa: E402
+
+__all__ = ["find_xplanes", "digest", "render_digest", "CATEGORIES",
+           "SPAN_PREFIXES"]
 
 CATEGORIES = ("compute", "collective", "host")
 
@@ -51,6 +52,13 @@ _COLLECTIVE_TOKENS = ("all-reduce", "all-gather", "reduce-scatter",
 
 # `fusion.123` / `all-reduce.5` -> the base op name the table keys on
 _OP_SUFFIX_RE = re.compile(r"\.\d+$")
+
+#: the program's own spans (obs/trace.py `span(...)`; table in obs/README.md)
+SPAN_PREFIXES = ("train/", "data/", "checkpoint/", "gan/", "serve/", "infer/")
+
+
+def _is_span(name: str) -> bool:
+    return name == "eval" or name.startswith(SPAN_PREFIXES)
 
 
 def _classify(op: str, device_line: bool) -> str:
@@ -81,73 +89,71 @@ def find_xplanes(path: str) -> List[str]:
     return sorted(found, reverse=True)
 
 
-def _load_xspace(path: str):
-    """Parsed XSpace proto, or None with a reason when the proto stack
-    can't read it (missing dep / truncated file / foreign format)."""
-    try:
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2
-    except Exception:
-        try:  # older tensorboard_plugin_profile layouts
-            from tensorboard_plugin_profile.protobuf import xplane_pb2
-        except Exception:
-            return None, "no xplane proto bindings available"
-    space = xplane_pb2.XSpace()
-    try:
-        with open(path, "rb") as f:
-            space.ParseFromString(f.read())
-    except Exception as e:
-        return None, f"unreadable xplane proto: {e}"
-    return space, None
-
-
 def digest(path: str, *, top_k: int = 12) -> dict:
     """Per-op time decomposition of the newest capture under `path`.
 
     Returns {"source", "ops": [{"op", "category", "count", "total_ms",
     "mean_us"}...] top-k by total time, "totals": {compute_ms,
-    collective_ms, host_ms}, "op_count", and "error" instead when the
-    capture can't be parsed}. Device planes are `/device:*` (TPU/GPU)
-    plus the XLA CPU client line of the host plane; everything else on
-    the host plane is host-side Python/runtime time.
+    collective_ms, host_ms} (each the union of its intervals per line, so
+    nested events count once), "spans": the program's own spans by name,
+    "op_count", and "error" instead when the capture can't be parsed}.
     """
     planes = find_xplanes(path)
     if not planes:
         return {"source": path, "error": "no .xplane.pb captures found"}
     src = planes[0]
-    space, err = _load_xspace(src)
-    if space is None:
-        return {"source": src, "error": err}
+    try:
+        import jax
+
+        data = jax.profiler.ProfileData.from_file(src)
+        planes = list(data.planes)
+    except Exception as e:
+        return {"source": src, "error": f"unreadable xplane capture: {e}"}
     agg: Dict[str, dict] = {}
-    for plane in space.planes:
-        meta = {mid: m.name for mid, m in plane.event_metadata.items()}
-        plane_is_device = plane.name.startswith("/device:")
+    spans: Dict[str, dict] = {}
+    covered = {c: 0.0 for c in CATEGORIES}
+    for plane in planes:
+        on_device = plane.name.startswith("/device:")
         for line in plane.lines:
-            # the CPU backend runs XLA executables on a host-plane line
-            # named after the PjRt client; those are device ops too
-            device_line = plane_is_device or line.name.startswith("tf_XLA")
+            if on_device and line.name != OPS_LINE:
+                continue  # modules, steps: the same ops' time once more
+            # the CPU backend runs its ops on a host-plane line named
+            # after the PjRt client
+            device_line = on_device or line.name.startswith("tf_XLA")
+            intervals = {c: [] for c in CATEGORIES}
             for ev in line.events:
-                op = meta.get(ev.metadata_id, "?")
-                if not device_line and op.startswith("$"):
-                    # Python-tracer stack frames ($file.py:line fn) nest:
-                    # summing them counts the same wall time once per
-                    # stack depth, drowning the runtime host events
+                op = short_name(ev.name)
+                if op.startswith(("$", "end: ")):
+                    # Python-tracer stack frames ($file.py:line fn) nest
+                    # once per stack depth; `end:` marks an op's completion
+                    continue
+                if not device_line and _is_span(op):
+                    row = spans.setdefault(op, {"span": op, "count": 0,
+                                                "total_ms": 0.0})
+                    row["count"] += 1
+                    row["total_ms"] += ev.duration_ns / 1e6
                     continue
                 cat = _classify(op, device_line)
-                key = _OP_SUFFIX_RE.sub("", op) if device_line else op
+                key = _OP_SUFFIX_RE.sub("", op) if cat != "host" else op
                 row = agg.setdefault(
                     f"{cat}:{key}",
                     {"op": key, "category": cat, "count": 0, "total_ms": 0.0})
                 row["count"] += 1
-                row["total_ms"] += ev.duration_ps / 1e9
+                row["total_ms"] += ev.duration_ns / 1e6
+                intervals[cat].append((ev.start_ns,
+                                       ev.start_ns + ev.duration_ns))
+            for cat, iv in intervals.items():
+                covered[cat] += union_ns(iv) / 1e6
     ops = sorted(agg.values(), key=lambda r: -r["total_ms"])
     for r in ops:
         r["total_ms"] = round(r["total_ms"], 4)
         r["mean_us"] = round(r["total_ms"] * 1e3 / max(1, r["count"]), 2)
-    totals = {f"{c}_ms": round(sum(r["total_ms"] for r in ops
-                                   if r["category"] == c), 3)
-              for c in CATEGORIES}
+    totals = {f"{c}_ms": round(covered[c], 3) for c in CATEGORIES}
+    span_rows = sorted(spans.values(), key=lambda r: -r["total_ms"])
+    for r in span_rows:
+        r["total_ms"] = round(r["total_ms"], 4)
     out = {"source": src, "op_count": len(ops), "totals": totals,
-           "ops": ops[:max(1, int(top_k))]}
+           "ops": ops[:max(1, int(top_k))], "spans": span_rows}
     try:  # surface the decomposition on the live /statusz perf section
         from deep_vision_tpu.obs import perfwatch
 
@@ -174,6 +180,12 @@ def render_digest(d: dict) -> str:
             lines.append(f"{r['op']:<{w}}  {r['category']:<10}  "
                          f"{r['count']:>6}  {r['total_ms']:>9.3f}  "
                          f"{r['mean_us']:>9.2f}")
+    if d.get("spans"):
+        w = max(len(r["span"]) for r in d["spans"])
+        lines.append(f"{'span':<{w}}  {'count':>6}  {'total ms':>9}")
+        for r in d["spans"]:
+            lines.append(f"{r['span']:<{w}}  {r['count']:>6}  "
+                         f"{r['total_ms']:>9.3f}")
     return "\n".join(lines)
 
 
