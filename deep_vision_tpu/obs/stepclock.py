@@ -7,22 +7,34 @@ data-wait, dispatch, and device work. StepClock separates them:
 
   data_wait_ms   host blocked in the data iterator's next()
   dispatch_ms    host time to trace/shard/enqueue the step
-  step_time_ms   full wall time of the step iteration (wait + dispatch)
-  sync_ms        on sampled steps only: block_until_ready fence closing
-                 the device pipeline — dispatch_ms + sync_ms on those
-                 steps is the true per-step cost
+  step_time_ms   wall from the previous step's commit (for the first step
+                 of a feed: from `iter_data`'s start) to this step's; over
+                 an epoch they add up to the epoch's wall. A clock driven
+                 without `iter_data`: data wait + enter -> commit
+  sync_ms        on sampled steps only: how long the host waited, in a
+                 block_until_ready, for the device to finish the step
 
-The fence runs every `sample_every` steps (default 16). It was meant to
-leave the steps between two fences asynchronous; `Trainer`'s loop is not:
-after every dispatch it fetches `int(state.step)`, the learning rate and
-each metric for the journal, the loggers and the health guard, so the
-host waits for every step to finish before it takes the next batch, and
-the device waits for the host in turn (PERF.md §5: one idle gap a step).
-`{name}_host_fetches_total` counts those blocking device->host fetches;
-the spans `train/data_wait` (here) and `train/place`, `train/dispatch`,
-`train/fetch`, `train/log` (train/trainer.py) say where in the gap the
-host was. Only the GAN loops, which keep their metrics on the device
-until the epoch ends, run ahead between fences. Recompiles are counted
+`Trainer`'s loop keeps one step in flight: it dispatches step N, then
+reads step N-1's report (post-update step counter, learning rate and
+metrics, outputs of the step program, in one `device_get`), commits and
+logs it, and goes to fetch batch N+1 while the device runs step N. It
+never waits for the step it has just dispatched but at a flush (end of
+an epoch, a preemption save, an exception leaving the epoch), so the
+sampled fence (`await_report`, every `sample_every` steps, default 16)
+waits for the report the loop is about to read anyway and `sync_ms` is
+the host's wait for the device. One step late, therefore: the health
+guard (an `abort` names step N after step N+1 was dispatched), the
+preemption poll (keyed to the step just read; the save flushes the step
+in flight first) and autoprof's `observe_step`.
+`{name}_steps_covered_total` counts the steps whose report was not ready
+when the loop came to read it: the host was back first and the device
+never waited for it (covered / steps near 1 is a device-bound loop, near
+0 a host-bound one). `{name}_host_fetches_total` counts the blocking
+device->host fetches, one a dispatch; the spans `train/data_wait` (here)
+and `train/place`, `train/dispatch`, `train/fetch`, `train/log`
+(train/trainer.py) say where the host was. The GAN loops keep their
+metrics on the device until the epoch ends and fence at the end of the
+sampled step itself (`fence_on`). Recompiles are counted
 process-wide from the
 `/jax/core/compile/backend_compile_duration` monitoring event (fires per
 backend compile, silent on cache hits — verified against jit cache
@@ -124,14 +136,17 @@ class StepClock:
 
         clock.start_epoch()
         for batch in clock.iter_data(data):      # times next() = data wait
-            with clock.step(batch_size=n) as rec:  # times dispatch
-                out = train_step(batch)
-                rec.fence_on(out)                # sampled block_until_ready
-            journal fields: rec.fields()
+            with clock.step(batch_size=n, auto_commit=False) as rec:
+                report = dispatch(batch)         # times dispatch
+            ... one step later ...
+            rec.await_report(report)             # covered? sampled fence
+            rec.commit(step=..., metrics=...)    # registry + journal row
 
-    All timing is host-side perf_counter; the only device interaction is
-    the sampled fence, and `examples_per_sec` is computed from the wall
-    step time so it matches what an operator observes end to end.
+    (a loop that reads nothing a step: `rec.fence_on(out)` inside the
+    with-block, and the record commits itself at its end.) All timing is
+    host-side perf_counter; the only device interaction is the sampled
+    fence, and `examples_per_sec` is computed from the wall step time so
+    it matches what an operator observes end to end.
     """
 
     def __init__(self, registry: Optional[Registry] = None,
@@ -145,6 +160,10 @@ class StepClock:
         self._steps_seen = 0
         self._sync_samples = 0
         self._last_data_wait_ms = 0.0
+        # where the next committed step's wall begins: the previous commit,
+        # or the start of the feed (`iter_data`); None for a clock driven
+        # without `iter_data`
+        self._t_mark: Optional[float] = None
         self._recompiles_at_start: Optional[int] = None
         _install_compile_listener()
         # compile-seconds high-water at construction: step rows carry the
@@ -179,6 +198,10 @@ class StepClock:
         self._c_fetches = r.counter(
             f"{name}_host_fetches_total",
             "blocking device->host fetches made by the step loop")
+        self._c_covered = r.counter(
+            f"{name}_steps_covered_total",
+            "steps whose report was not ready when the loop came to read "
+            "it: the device never waited for the host")
 
     # -- data-wait side ----------------------------------------------------
 
@@ -195,6 +218,7 @@ class StepClock:
         (tests/test_goodput.py pins this with a depth-2 prefetcher)."""
         it = iter(data)
         wait_span = f"{self.name}/data_wait"
+        self._t_mark = time.perf_counter()
         while True:
             t0 = time.perf_counter()
             # `step`: the dispatch this batch feeds (see `step()`)
@@ -211,10 +235,10 @@ class StepClock:
     def step(self, batch_size: int = 0,
              auto_commit: bool = True) -> "_StepRecord":
         """`auto_commit=False` defers the registry/journal write to an
-        explicit `rec.commit(step=..., metrics=...)` AFTER the with-block,
-        so host-side device fetches (optimizer step, LR) the caller makes
-        between dispatch and logging count toward step_time_ms but never
-        pollute dispatch_ms."""
+        explicit `rec.commit(step=..., metrics=...)` AFTER the with-block
+        (the Trainer's loop: one step later, once it has read the step's
+        report), so what the host does between dispatch and logging counts
+        toward step_time_ms but never pollutes dispatch_ms."""
         self._steps_seen += 1
         do_sample = (self._steps_seen % self.sample_every) == 0
         return _StepRecord(self, batch_size, self._last_data_wait_ms,
@@ -222,6 +246,8 @@ class StepClock:
 
     def _finish(self, rec: "_StepRecord") -> None:
         self._c_steps.inc()
+        if rec.covered:
+            self._c_covered.inc()
         if rec.batch_size:
             self._c_examples.inc(rec.batch_size)
         self._g_data_wait.set(rec.data_wait_ms)
@@ -275,6 +301,8 @@ class _StepRecord:
         self.batch_size = batch_size
         self.data_wait_ms = data_wait_ms
         self.sampled = sampled
+        self.index = clock.steps_seen  # this dispatch, as the spans count it
+        self.covered = False  # see `await_report`
         self.step: Optional[int] = None  # caller may set the optimizer step
         self.metrics: dict = {}
         self.extra: dict = {}  # caller-supplied journal fields (e.g. the
@@ -303,25 +331,42 @@ class _StepRecord:
 
     def __exit__(self, exc_type, exc, tb):
         self.dispatch_ms = (time.perf_counter() - self._t0) * 1e3
-        if self.sampled and self._fenced is not None and exc_type is None:
-            import jax
-
-            t1 = time.perf_counter()
-            # the host blocked on the device, as in the loop's own fetches
-            with span(f"{self._clock.name}/fetch",
-                      step=self._clock.steps_seen, n=0):
-                jax.block_until_ready(self._fenced)
-            self.sync_ms = (time.perf_counter() - t1) * 1e3
+        if self._fenced is not None and exc_type is None:
+            self._fence(self._fenced)
         if exc_type is None and self._auto_commit:
             self.commit()
         return False
 
+    def _fence(self, out) -> None:
+        if not self.sampled:
+            return
+        import jax
+
+        t1 = time.perf_counter()
+        # the host blocked on the device, as in the loop's own fetches
+        with span(f"{self._clock.name}/fetch", step=self.index, n=0):
+            jax.block_until_ready(out)
+        self.sync_ms = (time.perf_counter() - t1) * 1e3
+
+    def await_report(self, report) -> None:
+        """For a loop that reads this step's `report` (a pytree of the
+        step program's outputs) after it has dispatched the next step:
+        call when about to read it. Notes whether the device still had it
+        in work (`covered`: the host was back first, so the device never
+        waited for the host), and on a sampled step times the wait for it
+        as `sync_ms`."""
+        import jax
+
+        self.covered = not jax.tree_util.tree_leaves(report)[0].is_ready()
+        self._fence(report)
+
     def commit(self, step: Optional[int] = None,
                metrics: Optional[dict] = None,
                extra: Optional[dict] = None) -> None:
-        """Close the record and write registry/journal. step_time_ms spans
-        enter -> commit, so deferred-commit callers fold their post-dispatch
-        host fetches into the step total without widening dispatch_ms.
+        """Close the record and write registry/journal. step_time_ms runs
+        from the clock's mark (the previous commit, or the feed's start) to
+        now, so a loop that commits late stays honest about the wall; with
+        no mark (no `iter_data`) it is data wait + enter -> commit.
         `extra` fields ride the journal step event verbatim (unknown step
         fields are forward-compatible by the check_journal schema)."""
         if self._committed:
@@ -333,8 +378,13 @@ class _StepRecord:
             self.metrics = metrics
         if extra:
             self.extra.update(extra)
-        self.step_time_ms = self.data_wait_ms + (
-            time.perf_counter() - self._t0) * 1e3
+        now = time.perf_counter()
+        mark = self._clock._t_mark
+        if mark is None:
+            self.step_time_ms = self.data_wait_ms + (now - self._t0) * 1e3
+        else:
+            self.step_time_ms = (now - mark) * 1e3
+            self._clock._t_mark = now
         if self.batch_size and self.step_time_ms > 0:
             self.examples_per_sec = self.batch_size / self.step_time_ms * 1e3
         self._clock._finish(self)

@@ -19,7 +19,7 @@ mesh:
 from __future__ import annotations
 
 import time
-from typing import Callable, Iterable, Optional
+from typing import Callable, Iterable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +48,35 @@ from deep_vision_tpu.resilience.rendezvous import HostLostError, WorldResized
 # one shared jitted sum: evaluate() calls it per masked multi-host batch,
 # and a fresh jax.jit wrapper there would retrace every time
 _global_sum = jax.jit(jnp.sum)
+
+
+# The step program's report: its metrics and, under these two keys, the
+# post-update step counter and the injected learning rate. The state that
+# holds them is donated into the next dispatch; outputs that are not fed
+# back survive it, so the loop can read a step after dispatching the next.
+# Popped before anything is logged.
+_STEP, _LR = "_step", "_lr"
+
+
+def _metrics_of(report: dict) -> dict:
+    return {k: v for k, v in report.items() if k not in (_STEP, _LR)}
+
+
+def _injected_lr(opt_state):
+    """`inject_hyperparams`' learning-rate leaf, or None without one."""
+    try:
+        return opt_state.hyperparams["learning_rate"]
+    except (AttributeError, KeyError, TypeError):
+        return None
+
+
+class _InFlight(NamedTuple):
+    """A dispatched step whose report the loop has not read yet."""
+    rec: object    # its StepClock record, committed at the read
+    report: dict   # device scalars; every leaf stacked (K,) for a superstep
+    n: int         # examples in the dispatch
+    k: int         # optimizer steps in the dispatch: 0 = a single step
+    epoch: int
 
 
 def _host_bytes(batch: dict) -> int:
@@ -184,6 +213,7 @@ class Trainer:
             self.prof.fence = lambda: jax.block_until_ready(
                 self.state.params)
         self._pguard = None  # PreemptionGuard, live only inside fit
+        self._in_flight: Optional[_InFlight] = None  # fit's loop, depth 1
         self._closed = False
         self.preempted = False  # latched by the SIGTERM escalation path
         # backend-loss recovery (resilience/elastic.py BackendSupervisor):
@@ -382,7 +412,7 @@ class Trainer:
         # live telemetry plane (obs/telemetry.py): register host-side
         # status + readiness sources. The scraper thread must never touch
         # the device, so /statusz reads the plain-Python step mirror kept
-        # by the *_and_log paths, not `int(self.state.step)` (a device
+        # by `_log_report`, not `int(self.state.step)` (a device
         # fetch that could fence against an in-flight dispatch).
         self._live_step: Optional[int] = None
         self._live_epoch: Optional[int] = None
@@ -644,6 +674,10 @@ class Trainer:
                 lambda n, o: jnp.where(ok, n, o), new_state, state
             )
             metrics["skipped"] = 1.0 - ok.astype(jnp.float32)
+        metrics[_STEP] = new_state.step
+        lr = _injected_lr(new_state.opt_state)
+        if lr is not None:
+            metrics[_LR] = lr
         return new_state, metrics
 
     @jax.named_scope("eval_step")
@@ -661,9 +695,9 @@ class Trainer:
         The scan body is the exact single-step impl: state.step advances
         inside apply_gradients, so per-microstep RNG derivation
         (fold_in(rng, step)) and the skip_step finiteness select match K
-        separate dispatches bit for bit. Returns (state, metrics) with
-        every metric leaf stacked (K,) — the per-microstep record the host
-        loop un-stacks for loggers/health."""
+        separate dispatches bit for bit. Returns (state, report) with
+        every leaf of the report stacked (K,) — the per-microstep record the
+        host loop un-stacks for loggers/health."""
         return jax.lax.scan(
             lambda s, b: self._train_step_impl(s, b), state, batches
         )
@@ -752,13 +786,13 @@ class Trainer:
     def _profiler_hook(self):
         if self.prof is None:
             return
-        # int() blocks on the in-flight state — pay it ONLY while a
-        # pending static window needs the true optimizer step to anchor
-        # (e.g. after a resume). An --autoprof-only run would otherwise
-        # drain the device pipeline every step; its internal counter is
-        # recalibrated by observe_step's committed opt_step instead.
-        self.prof.on_step_start(int(self.state.step)
-                                if self.prof.needs_step_index else None)
+        # a pending static window anchors to the true optimizer step (e.g.
+        # after a resume). Reading it waits for whatever holds the state,
+        # so only with no step in flight: the first dispatch of an epoch,
+        # a caller driving train_step by hand. In between, the profiler's
+        # own counter runs on, recalibrated by observe_step.
+        anchor = self.prof.needs_step_index and self._in_flight is None
+        self.prof.on_step_start(int(self.state.step) if anchor else None)
 
     def _stop_trace(self, step: Optional[int] = None) -> None:
         """Close an in-flight profiler capture (idempotent); journaled as
@@ -767,6 +801,11 @@ class Trainer:
             self.prof.interrupt()
 
     def train_step(self, batch) -> dict:
+        """One optimizer step on `batch`; -> its metrics (device scalars)."""
+        return _metrics_of(self._dispatch_step(batch))
+
+    def _dispatch_step(self, batch) -> dict:
+        """Place `batch`, enqueue the step program; -> its report."""
         self._profiler_hook()
         i = self.clock.steps_seen  # the host's dispatch index (fit's loop)
         if isinstance(batch, PlacedBatch):
@@ -796,6 +835,13 @@ class Trainer:
         `batches`: a list of K host batch dicts, or a PlacedBatch the
         device prefetcher stacked ahead of time. Returns K per-microstep
         metric dicts (device scalars — fetch once, not per key)."""
+        metrics = _metrics_of(self._dispatch_superstep(batches))
+        return [jax.tree_util.tree_map(lambda v, i=i: v[i], metrics)
+                for i in range(self.multistep)]
+
+    def _dispatch_superstep(self, batches) -> dict:
+        """Stack and place K batches, enqueue the scan; -> its report,
+        every leaf stacked (K,)."""
         if self._train_multi is None:
             raise ValueError("train_superstep needs Trainer(multistep=K>1)")
         self._profiler_hook()
@@ -815,8 +861,7 @@ class Trainer:
             multi_fn = self._cached_step("superstep", self._train_multi,
                                          self._train_multi_cache, stacked)
             self.state, metrics = multi_fn(self.state, stacked)
-        return [jax.tree_util.tree_map(lambda v, i=i: v[i], metrics)
-                for i in range(k)]
+        return metrics
 
     def eval_step(self, batch) -> dict:
         batch = shard_batch(self.mesh, self._pad_and_mask(batch),
@@ -828,14 +873,15 @@ class Trainer:
             return self._eval_step(state, batch)
 
     def lr_at(self, step: int) -> float:
-        """LR for a step the caller already fetched (the hot loop passes its
-        opt_step so the fallback costs no extra device round-trip)."""
-        try:
-            return float(self.state.opt_state.hyperparams["learning_rate"])
-        except (AttributeError, KeyError, TypeError):
-            pass
-        # optimizer built without inject_hyperparams: evaluate the schedule
-        # at the given step instead of logging NaN forever
+        """LR for a step the caller already fetched (`current_lr`)."""
+        lr = _injected_lr(self.state.opt_state)
+        if lr is not None:
+            return float(lr)
+        return self._scheduled_lr(step)
+
+    def _scheduled_lr(self, step: int) -> float:
+        """Optimizer built without inject_hyperparams: evaluate the schedule
+        at the given step instead of logging NaN forever."""
         if self._lr_schedule is not None:
             if callable(self._lr_schedule):
                 return float(self._lr_schedule(step))
@@ -1145,6 +1191,9 @@ class Trainer:
         operator must know whether the step made it to disk)."""
         from deep_vision_tpu.obs import flight as _flight
 
+        # first the step in flight: the checkpoint's step, the last journal
+        # row and the data position then agree
+        self._flush()
         step = int(self.state.step)
         self.preempted = True
         if self.ckpt is None:
@@ -1194,175 +1243,172 @@ class Trainer:
         multistep groups (one dispatch = K optimizer steps) — the latter
         two composed by the prefetcher itself when both are on. The
         grouping/prefetch stage sits INSIDE clock.iter_data so data_wait
-        honestly covers the whole wait for a dispatch's worth of input."""
+        honestly covers the whole wait for a dispatch's worth of input.
+
+        The loop keeps one step in flight: it dispatches step N, then reads
+        and logs step N-1's report, and takes batch N+1 while the device
+        runs step N. The epoch ends, by its last batch or by an exception,
+        with the step in flight read and logged."""
         self.logger.start_epoch()
         data = train_data_fn()
         if self._prefetcher is not None:
             data = self._prefetcher(data)
         elif self.multistep > 1:
             data = self._grouped(data)
-        for item in self.clock.iter_data(data):
-            is_group = isinstance(item, list) or (
-                isinstance(item, PlacedBatch) and item.group > 1)
-            if is_group:
-                status = self._superstep_and_log(item, epoch)
-            else:
-                status = self._single_step_and_log(item, epoch)
-            if status == "preempted":
-                # no end_epoch: a partial-epoch summary would pollute the
-                # history/TensorBoard rows the re-run epoch writes again
+        try:
+            for item in self.clock.iter_data(data):
+                if self._step_and_log(item, epoch) == "preempted":
+                    # no end_epoch: a partial-epoch summary would pollute
+                    # the history/TensorBoard rows the re-run epoch writes
+                    # again
+                    return "preempted", None
+            last, self._in_flight = self._in_flight, None
+            if last is not None and self._close_step(last) == "preempted":
                 return "preempted", None
+        except BaseException:
+            # the step in flight did run: its row belongs in the journal.
+            # What reading it may raise in turn (a second non-finite step,
+            # the same lost host) gives way to the exception on its way out
+            try:
+                self._flush()
+            except Exception:
+                pass
+            raise
         return None, self.logger.end_epoch(epoch)
 
-    def _single_step_and_log(self, batch, epoch):
-        """The classic one-batch step body; `batch` may be a PlacedBatch."""
-        if isinstance(batch, PlacedBatch):
-            n = batch.n
+    def _step_and_log(self, item, epoch):
+        """Dispatch one item of the feed — a batch, or a multistep group
+        (one scan dispatch = K optimizer steps); either may be a
+        PlacedBatch — then read and log the dispatch before it."""
+        group = isinstance(item, list) or (
+            isinstance(item, PlacedBatch) and item.group > 1)
+        if isinstance(item, PlacedBatch):
+            n = item.n
+        elif group:
+            n = sum(int(np.shape(b[self.input_key])[0]) for b in item)
         else:
-            n = np.shape(batch[self.input_key])[0]
+            n = np.shape(item[self.input_key])[0]
         i = self.clock.steps_seen + 1  # this dispatch, known before any fetch
+        args = {"multistep": self.multistep} if group else {}
         with jax.profiler.StepTraceAnnotation("train", step_num=i), \
-                span("train/step", step=i, epoch=epoch) as sp:
+                span("train/step", step=i, epoch=epoch, **args):
+            # dispatch_ms is enqueue-only (the starvation signal compares
+            # data_wait against it); the record commits when it is read
             with self.clock.step(batch_size=n, auto_commit=False) as rec:
-                metrics = self.train_step(batch)
-                self._host_fetch(lambda: rec.fence_on(metrics))
-            # these fetches block on the in-flight state — outside the
-            # with-block so dispatch_ms stays enqueue-only (the
-            # starvation signal compares data_wait against it);
-            # commit() folds their cost into step_time_ms. Lease-checked
-            # (_host_fetch): in a multi-host world a dead peer wedges
-            # them forever otherwise. One fetch for the step counter, one
-            # for the LR, one per metric (loggers + health share them).
-            n_fetch = 2 + len(metrics)
-            with span("train/fetch", step=i, n=n_fetch):
-                opt_step = self._host_fetch(lambda: int(self.state.step))
-                lr = self.lr_at(opt_step)
-                metrics_f = {k: float(v) for k, v in metrics.items()}
-            self.clock.note_host_fetches(n_fetch)
-            sp.set(opt_step=opt_step)
-            with span("train/log", step=i):
-                return self._log_single_step(rec, opt_step, lr, metrics_f,
-                                             n, epoch)
+                report = (self._dispatch_superstep(item) if group
+                          else self._dispatch_step(item))
+            late = self._in_flight
+            self._in_flight = _InFlight(rec, report, n,
+                                        self.multistep if group else 0,
+                                        epoch)
+            return self._close_step(late) if late is not None else None
 
-    def _log_single_step(self, rec, opt_step, lr, metrics_f, n, epoch):
-        """Every sink fed after a step: clock (registry + journal),
-        anomaly triggers, loggers, health guard, preemption poll."""
+    def _close_step(self, flight: _InFlight):
+        """Read and log a dispatched step, then poll for preemption."""
+        try:
+            opt_step = self._read_and_log(flight)
+        except BaseException:
+            # the run ends on what this step's report showed (a health
+            # abort) or on the fetch itself: the step dispatched after it
+            # goes with the state, unread
+            self._in_flight = None
+            raise
+        # poll keyed to the optimizer step — globally consistent across
+        # hosts, immune to unequal agreed() call counts elsewhere
+        if self._pguard is not None and self._pguard.agreed(step=opt_step):
+            # epoch-1: this epoch is incomplete, resume re-runs it
+            self._preempt_save(flight.epoch - 1)
+            return "preempted"
+        return None
+
+    def _flush(self) -> None:
+        """Read and log the step in flight, if any. After it the loop's
+        state is whole: journal, loggers, health guard and clock have
+        every step that was dispatched."""
+        flight, self._in_flight = self._in_flight, None
+        if flight is not None:
+            self._read_and_log(flight)
+
+    def _read_and_log(self, flight: _InFlight) -> int:
+        """Fetch a dispatched step's report and feed every sink with it;
+        -> the optimizer step after that dispatch."""
+        rec = flight.rec
+
+        def read():
+            rec.await_report(flight.report)
+            return jax.device_get(flight.report)
+
+        # the one blocking fetch of a dispatch. Lease-checked
+        # (_host_fetch): in a multi-host world a dead peer wedges it
+        # forever otherwise
+        with span("train/fetch", step=rec.index, n=1):
+            host = self._host_fetch(read)
+        self.clock.note_host_fetches(1)
+        with span("train/log", step=rec.index) as sp:
+            opt_step = self._log_report(flight, host)
+            sp.set(opt_step=opt_step)
+        return opt_step
+
+    def _log_report(self, flight: _InFlight, host: dict) -> int:
+        """Every sink fed after a dispatch: clock (registry + journal),
+        anomaly triggers, loggers, health guard. A superstep's K
+        microsteps are recovered from the scanned stack and logged and
+        health-checked exactly as K single steps would have been."""
+        rec, k, epoch = flight.rec, flight.k, flight.epoch
+        steps = [int(s) for s in np.atleast_1d(host.pop(_STEP))]
+        opt_step = steps[-1]
+        if _LR in host:
+            lrs = [float(v) for v in np.atleast_1d(host.pop(_LR))]
+        elif k and callable(self._lr_schedule):
+            # update t uses schedule(t-1)
+            lrs = [self._scheduled_lr(s - 1) for s in steps]
+        else:
+            lrs = [self._scheduled_lr(opt_step)] * len(steps)
+        rows = [{name: float(np.atleast_1d(v)[i]) for name, v in host.items()}
+                for i in range(len(steps))]
+        last, lr = rows[-1], lrs[-1]
+        # journal: ONE step event per dispatch (the thing that actually
+        # happened), a superstep's stamped multistep=K; loggers below keep
+        # per-microstep series so histories stay comparable across K
         rec.commit(step=opt_step,
-                   metrics={"loss": metrics_f["loss"], "lr": lr}
-                   if "loss" in metrics_f else {"lr": lr})
+                   metrics={"loss": last["loss"], "lr": lr}
+                   if "loss" in last else {"lr": lr},
+                   extra={"multistep": k} if k else None)
         # publish the host-side mirror the telemetry scraper reads (plain
         # attribute writes: benign to race, never a device fetch)
         self._live_step, self._live_epoch = opt_step, epoch
         self._live_eps = rec.examples_per_sec
         # anomaly triggers see the committed record (step-time/data-wait
         # z-scores, recompile bursts, HBM high-water jumps) and arm a
-        # capture that the NEXT step's _profiler_hook starts
+        # capture that the NEXT dispatch's _profiler_hook starts
         if self.prof is not None:
             self.prof.observe_step(opt_step, rec.fields())
-        loss_f = metrics_f.get("loss")
-        grad_norm_f = metrics_f.get("grad_norm")
-        skipped = (self._skip_nonfinite
-                   and metrics_f.get("skipped", 0.0) > 0)
-        if skipped:
-            # the discarded update's loss/grads are garbage: keep them
-            # out of the epoch means and TB series — the health event
-            # and skipped counter (below) carry the record instead
-            metrics_f = {k: v for k, v in metrics_f.items()
-                         if v == v and abs(v) != float("inf")}
-        # (train_learning_rate gauge: MetricLogger's NaN-guarded write)
-        self.logger.log_step(
-            opt_step, metrics_f, batch_size=n, epoch=epoch,
-            lr=lr, data_wait_ms=rec.data_wait_ms,
-            examples_per_sec=rec.examples_per_sec,
-        )
-        # health guard AFTER the step/log writes: an abort's journal
-        # then reads step -> health(non_finite) -> crash, in order
-        if self.health is not None:
-            self.health.check_step(opt_step, loss=loss_f,
-                                   grad_norm=grad_norm_f,
-                                   skipped=skipped)
-        # poll keyed to the optimizer step — globally consistent across
-        # hosts, immune to unequal agreed() call counts elsewhere
-        if self._pguard is not None and self._pguard.agreed(step=opt_step):
-            # epoch-1: this epoch is incomplete, resume re-runs it
-            self._preempt_save(epoch - 1)
-            return "preempted"
-        return None
-
-    def _superstep_and_log(self, item, epoch):
-        """One scan dispatch = K optimizer steps; per-microstep metrics are
-        recovered from the scanned stack and logged/health-checked exactly
-        as K single steps would have been."""
-        k = self.multistep
-        if isinstance(item, PlacedBatch):
-            n_total = item.n
-        else:
-            n_total = sum(int(np.shape(b[self.input_key])[0]) for b in item)
-        i = self.clock.steps_seen + 1
-        with jax.profiler.StepTraceAnnotation("train", step_num=i), \
-                span("train/step", step=i, epoch=epoch, multistep=k) as sp:
-            with self.clock.step(batch_size=n_total,
-                                 auto_commit=False) as rec:
-                metrics_k = self.train_superstep(item)
-                self._host_fetch(lambda: rec.fence_on(metrics_k))
-            with span("train/fetch", step=i, n=3):
-                opt_step = self._host_fetch(lambda: int(self.state.step))
-                lr = self.lr_at(opt_step)
-                # ONE fetch for all K microsteps
-                floats = jax.device_get(metrics_k)
-            self.clock.note_host_fetches(3)
-            sp.set(opt_step=opt_step)
-            with span("train/log", step=i):
-                return self._log_superstep(rec, opt_step, lr, floats,
-                                           n_total, epoch)
-
-    def _log_superstep(self, rec, opt_step, lr, floats, n_total, epoch):
-        """`_log_single_step` for the K microsteps of one dispatch."""
-        k = self.multistep
-        last = floats[-1]
-        # journal: ONE step event per dispatch (the thing that actually
-        # happened), stamped multistep=K; loggers below keep per-
-        # microstep series so histories stay comparable across K
-        rec.commit(step=opt_step,
-                   metrics={"loss": last["loss"], "lr": lr}
-                   if "loss" in last else {"lr": lr},
-                   extra={"multistep": k})
-        self._live_step, self._live_epoch = opt_step, epoch
-        self._live_eps = rec.examples_per_sec
-        if self.prof is not None:
-            self.prof.observe_step(opt_step, rec.fields())
-        n_each = max(1, n_total // k)
-        for i, mf in enumerate(floats):
-            step_i = opt_step - (k - 1) + i
-            mf = {kk: float(v) for kk, v in mf.items()}
+        n_each = max(1, flight.n // len(rows))
+        for step_i, lr_i, mf in zip(steps, lrs, rows):
             loss_f = mf.get("loss")
             grad_norm_f = mf.get("grad_norm")
             skipped = (self._skip_nonfinite and mf.get("skipped", 0.0) > 0)
-            logged = mf
             if skipped:
-                logged = {kk: v for kk, v in mf.items()
-                          if v == v and abs(v) != float("inf")}
-            # per-microstep LR: the post-dispatch hyperparam only reflects
-            # the LAST microstep — under a schedule, re-evaluate it at each
-            # microstep's pre-update count (update t uses schedule(t-1),
-            # matching what lr_at reads after a single-step dispatch)
-            lr_i = (float(self._lr_schedule(step_i - 1))
-                    if callable(self._lr_schedule) else lr)
+                # the discarded update's loss/grads are garbage: keep them
+                # out of the epoch means and TB series — the health event
+                # and skipped counter (below) carry the record instead
+                mf = {kk: v for kk, v in mf.items()
+                      if v == v and abs(v) != float("inf")}
+            # (train_learning_rate gauge: MetricLogger's NaN-guarded write)
             # data_wait amortizes over the K microsteps the one gather fed;
             # examples_per_sec is the dispatch's wall rate (same for all K)
             self.logger.log_step(
-                step_i, logged, batch_size=n_each, epoch=epoch, lr=lr_i,
-                data_wait_ms=rec.data_wait_ms / k,
+                step_i, mf, batch_size=n_each, epoch=epoch, lr=lr_i,
+                data_wait_ms=rec.data_wait_ms / len(rows),
                 examples_per_sec=rec.examples_per_sec,
             )
+            # health guard AFTER the step/log writes: an abort's journal
+            # then reads step -> health(non_finite) -> crash, in order
             if self.health is not None:
                 self.health.check_step(step_i, loss=loss_f,
                                        grad_norm=grad_norm_f,
                                        skipped=skipped)
-        if self._pguard is not None and self._pguard.agreed(step=opt_step):
-            self._preempt_save(epoch - 1)
-            return "preempted"
-        return None
+        return opt_step
 
     def _post_epoch(self, summary, eval_data_fn, epoch, save_every):
         # failure detection the reference has none of (SURVEY §5): a
