@@ -57,31 +57,40 @@ def reference_module(config):
 
 
 def experiment_config(config, global_batch):
-    """The program's `ExperimentConfig` of a configuration's file."""
+    """The program's `ExperimentConfig` of a configuration's file: the task
+    (classification where the file names none), and the input shape, number
+    of classes and loss arguments where the file has them."""
     import jax.numpy as jnp
 
     from deep_vision_tpu.configs import ExperimentConfig
 
+    stated = {k: config[k] for k in ("num_classes", "loss_kwargs")
+              if k in config}
+    if "input_shape" in config:
+        stated["input_shape"] = tuple(config["input_shape"])
     return ExperimentConfig(
-        name=config["model"], task="classification", model=config["model"],
+        name=config["model"], task=config.get("task", "classification"),
+        model=config["model"],
         model_kwargs={**config.get("model_kwargs", {}),
                       "dtype": jnp.dtype(config["compute_dtype"])},
-        input_shape=tuple(config["input_shape"]),
-        num_classes=config["num_classes"], batch_size=global_batch,
-        optimizer=dict(config["optimizer"]), schedule=config.get("schedule"),
-        plateau=config.get("plateau"))
+        batch_size=global_batch, optimizer=dict(config["optimizer"]),
+        schedule=config.get("schedule"), plateau=config.get("plateau"),
+        **stated)
 
 
 def build_trainer(config, global_batch):
     """The trainer as `train.py -m <model> --fake-data` builds it, at the
-    configuration's stated precision, with no checkpoints and no eval."""
+    configuration's stated precision (the optimizer's state in its
+    `optimizer_state_dtype`, float32 where it states none), with no
+    checkpoints and no eval."""
     from deep_vision_tpu import train_cli
 
     cfg = experiment_config(config, global_batch)
     journal = MemoryJournal()
     trainer = train_cli.build_trainer(
         cfg, None, ckpt_dir=None, journal=journal,
-        steps_per_epoch=config["steps_per_epoch"])
+        steps_per_epoch=config["steps_per_epoch"],
+        opt_state_dtype=config.get("optimizer_state_dtype"))
     return trainer, journal, train_cli.model_input_shape(cfg)
 
 
@@ -134,18 +143,21 @@ def _find(state, name):
 
 def first_gradient_norms(config, opt_state, params0):
     """Per-leaf norms of the first gradient as the optimizer got it, worked
-    out from its state after one step."""
+    out from its state after one step (upcast first, where the state is
+    stored below float32)."""
     import jax
 
     o = config["optimizer"]
     if o["name"] == "sgd":  # trace_1 = g_1 + weight_decay * p_0
         wd = o.get("weight_decay", 0.0)
         grad = jax.jit(lambda t, p: jax.tree.map(
-            lambda a, b: a - wd * b, t, p))(_find(opt_state, "trace"), params0)
+            lambda a, b: a.astype("float32") - wd * b, t, p))(
+                _find(opt_state, "trace"), params0)
     elif o["name"] == "adamw":  # mu_1 = (1 - b1) * g_1
         b1 = o.get("b1", 0.9)
         grad = jax.jit(lambda m: jax.tree.map(
-            lambda a: a / (1 - b1), m))(_find(opt_state, "mu"))
+            lambda a: a.astype("float32") / (1 - b1), m))(
+                _find(opt_state, "mu"))
     else:
         raise ValueError(f"no first gradient for optimizer {o['name']!r}")
     return compare.leaf_norms(grad)
@@ -286,8 +298,7 @@ def run(cell, config, traffic, seed, seconds, trace, t_process_start):
     trainer, journal, image_shape = build_trainer(config,
                                                   traffic["global_batch"])
     t_built = time.perf_counter()
-    pool = traffic_mod.make_pool(traffic, image_shape, config["num_classes"],
-                                 seed)
+    pool = traffic_mod.make_pool(traffic, config, image_shape, seed)
     module = reference_module(config)
     variables = jax.jit(lambda k: module.init(config, k))(seed_key(seed))
     t_warm = time.perf_counter()
@@ -340,7 +351,8 @@ def run(cell, config, traffic, seed, seconds, trace, t_process_start):
         "setup_s": t_ready - t_process_start, "warmup_s": t_ready - t_warm,
         "reference_s": time.perf_counter() - t_ref,
         "memory_peak_bytes": peak, "trace": reduction,
-        "config": config, "image_shape": image_shape,
+        "config": config,
+        "batch_spec": traffic_mod.batch_spec(traffic, config, image_shape),
     }
 
 

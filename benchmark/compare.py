@@ -57,7 +57,9 @@ def worst_leaf_gap(prog: np.ndarray, ref: np.ndarray):
 def masked_delta_norms(delta_prog, delta_ref, grad_ref, floor: float):
     """Per-leaf norms of both changes over the elements whose reference
     gradient is at least `floor` in magnitude."""
-    def norms(dp, dr, g):
+    # the floor is an argument: as a constant of the program it would make
+    # every seed a program of its own, compiled anew in every run
+    def norms(dp, dr, g, floor):
         out = []
         for a, b, c in zip(*map(jax.tree.leaves, (dp, dr, g))):
             keep = (jnp.abs(c) >= floor).astype(jnp.float32)
@@ -65,7 +67,7 @@ def masked_delta_norms(delta_prog, delta_ref, grad_ref, floor: float):
         return out
 
     sums = np.asarray(jax.device_get(jax.jit(norms)(
-        delta_prog, delta_ref, grad_ref)), np.float64)
+        delta_prog, delta_ref, grad_ref, jnp.float32(floor))), np.float64)
     return np.sqrt(sums[:, 0]), np.sqrt(sums[:, 1])
 
 

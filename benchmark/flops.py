@@ -4,9 +4,11 @@ The count is of the mathematics: 2 x the multiply-accumulates of every
 `dot_general` and `conv_general_dilated`, found by walking the jaxpr and
 every jaxpr nested in it. Counted on the plain reference's
 `value_and_grad`, so no remat, kernel or fusion in the program can change
-it. The zeros an input-gradient convolution puts between the rows of a
-strided convolution's output (lhs dilation) are not counted: the forward
-convolution did not multiply by them either.
+it; a reference whose plain form multiplies by zeros (experts a token is
+not routed to, masked scores) writes its count down instead
+(`train_step_flops`). The zeros an input-gradient convolution puts between
+the rows of a strided convolution's output (lhs dilation) are not counted:
+the forward convolution did not multiply by them either.
 """
 from __future__ import annotations
 
@@ -51,18 +53,23 @@ def flops_of(fn, *args) -> float:
     return jaxpr_flops(jax.make_jaxpr(fn)(*args).jaxpr)
 
 
-def train_step_flops(module, cfg, batch_shape, label_shape) -> float:
-    """FLOPs of one training step of the plain reference `module` at
-    `batch_shape`: forward, and backward to every parameter."""
-    import jax.numpy as jnp
+def train_step_flops(module, cfg, batch_spec) -> float:
+    """FLOPs of one training step of the plain reference `module` over a
+    batch of `batch_spec` (`traffic.batch_spec`): forward, and backward to
+    every parameter.
 
+    Where the module defines `step_flops(cfg, batch_spec)`, a written count
+    of the step's mathematics, that is the number: a plain reference
+    computes every held expert over every token and every masked score,
+    and its jaxpr counts them. Where it defines none, the jaxpr count."""
+    if hasattr(module, "step_flops"):
+        return float(module.step_flops(cfg, batch_spec))
     variables = jax.eval_shape(lambda: module.init(cfg, jax.random.PRNGKey(0)))
 
-    def step(params, stats, images, labels):
+    def step(params, stats, batch):
         return jax.value_and_grad(
-            lambda p: module.loss_fn(cfg, p, stats, images, labels),
-            has_aux=True)(params)
+            lambda p: module.loss_fn(cfg, p, stats, batch), has_aux=True)(
+                params)
 
     return flops_of(step, variables["params"], variables["batch_stats"],
-                    jax.ShapeDtypeStruct(batch_shape, jnp.float32),
-                    jax.ShapeDtypeStruct(label_shape, jnp.int32))
+                    batch_spec)

@@ -7,10 +7,10 @@ planes, on one clock. This file lays the one over the other. Read with
 `jax.profiler.ProfileData` alone, as `trace.py` reads the device planes.
 
 Per device plane the slice and the gaps are `trace.reduce_device`'s: from
-the start of the step module's first execution to the start of its last,
-the gaps between the merged intervals of the `XLA Ops` line. Of the host
-plane, the events whose name starts with `train/` on the thread that
-carries `train/dispatch` are kept. Every instant of every gap gets one name:
+the start of the step module's second execution in the trace to the start
+of its last, the gaps between the merged intervals of the `XLA Ops` line.
+Of the host plane, the events whose name starts with `train/` on the thread
+that carries `train/dispatch` are kept. Every instant of every gap gets one name:
 
 - the innermost open span of `train/data_wait`, `train/place`,
   `train/dispatch`, `train/fetch`, `train/log` names it; `train/step` and
@@ -24,7 +24,7 @@ carries `train/dispatch` are kept. Every instant of every gap gets one name:
 - no span open: `other`. That is also how an edge gap is counted whose span
   the session missed because it was open when the session started or
   stopped. (A benchmark run starts and stops its session inside the feed's
-  `next()`, and its slice begins at the first module's start, after that
+  `next()`, and its slice begins at the second module's start, after that
   step's `train/dispatch`: every gap of the slice has its spans.)
 
 A name says where the loop's thread was while the device idled, not what
@@ -38,11 +38,16 @@ The seven names' seconds per step add up to the slice's idle time per step
 (`host_gap_ms`) by construction; the result is the mean over the devices,
 as in `trace.reduce_trace`.
 
-The clocks are checked once per run, per device: the k-th `train/dispatch`
-must begin before the k-th execution of the step module begins, and the
-first `train/fetch` after it must end after that execution ends. A host
-plane on another clock than the device's fails this, and the reduction
-raises instead of attributing.
+The clocks are checked once per run, per device: a `train/dispatch` must
+begin before its execution of the step module begins, and the
+`train/fetch` that reads that step's report (the one whose `step` argument
+is the dispatch's: the loop keeps one step in flight, so it comes after
+the next dispatch, and for the trace's last step it may come after the
+session) must end after that execution ends. The dispatches are
+the trace's executions in order; one execution more than dispatches is
+the one the session started inside, the first. A host plane on another
+clock than the device's fails this, and the reduction raises instead of
+attributing.
 """
 from __future__ import annotations
 
@@ -61,6 +66,8 @@ from benchmark.trace import (  # noqa: E402
     clip,
     find_xplane,
     gaps_ns,
+    step_runs,
+    whole_runs,
 )
 
 HOST_PLANE = "/host:CPU"
@@ -76,61 +83,59 @@ class ClockMismatch(RuntimeError):
     one clock (or are not the same steps)."""
 
 
-def step_runs(modules):
-    """`(start, end)` of each execution of the step module — the module that
-    takes most of the device's time, as in `trace.reduce_device` — in order."""
-    by_module = defaultdict(list)
-    for name, start, dur in modules:
-        by_module[name].append((start, start + dur))
-    if not by_module:
-        return []
-    step = max(by_module, key=lambda n: sum(e - s for s, e in by_module[n]))
-    return sorted(by_module[step])
-
-
 def loop_spans(host_lines):
-    """`[(name, start, end)]` of the `train/*` events on the thread that
-    carries `train/dispatch`. `host_lines`: `{thread: [(name, start_ns,
-    duration_ns)]}`."""
+    """`[(name, start, end, step)]` of the `train/*` events on the thread
+    that carries `train/dispatch`. `host_lines`: `{thread: [(name, start_ns,
+    duration_ns)]}`, a `train/*` event with its `step` argument fourth."""
     threads = [t for t, events in host_lines.items()
-               if any(n == DISPATCH for n, _, _ in events)]
+               if any(e[0] == DISPATCH for e in events)]
     if len(threads) != 1:
         raise RuntimeError(
             f"{len(threads)} host threads carry {DISPATCH!r} (need one): the "
             "session's host plane is off, or the program has no such span")
-    return threads[0], sorted((n, s, s + d) for n, s, d in
-                              host_lines[threads[0]]
-                              if n.startswith(SPAN_PREFIX))
+    return threads[0], sorted(
+        ((e[0], e[1], e[1] + e[2], e[3]) for e in host_lines[threads[0]]
+         if e[0].startswith(SPAN_PREFIX)), key=lambda span: span[:3])
 
 
 def check_clocks(spans, runs):
     """Raise `ClockMismatch` unless every step's dispatch precedes its
-    execution and its fetch outlasts it. -> the number of steps checked."""
-    dispatches = [(s, e) for n, s, e in spans if n == DISPATCH]
-    fetches = [(s, e) for n, s, e in spans if n == FETCH]
-    if len(dispatches) != len(runs):
+    execution and the fetch of its report outlasts it. `runs`: every
+    execution of the step module in the trace. -> the number of steps
+    checked."""
+    dispatches = [(s, step) for n, s, _, step in spans if n == DISPATCH]
+    fetch_ends = defaultdict(list)
+    for n, _, end, step in spans:
+        if n == FETCH:
+            fetch_ends[step].append(end)
+    # an execution the session started inside has no dispatch in the trace
+    started_inside = len(runs) - len(dispatches)
+    if started_inside not in (0, 1):
         raise ClockMismatch(
             f"{len(dispatches)} {DISPATCH} spans for {len(runs)} executions "
             "of the step module: not the same steps")
-    for k, ((d_start, d_end), (m_start, m_end)) in enumerate(
-            zip(dispatches, runs)):
+    for k, ((d_start, step), (m_start, m_end)) in enumerate(
+            zip(dispatches, runs[started_inside:])):
         if d_start >= m_start:
             raise ClockMismatch(
                 f"step {k}: {DISPATCH} begins {(d_start - m_start) * 1e-6:.3f}"
                 " ms after its module begins on the device")
-        fetch_end = next((e for s, e in fetches if s >= d_end), None)
+        # the sampled fence is a `train/fetch` of the same step inside it
+        fetch_end = max(fetch_ends[step], default=None)
+        if fetch_end is None and k == len(dispatches) - 1:
+            break  # the session stopped with this step in flight, unread
         if fetch_end is None or fetch_end <= m_end:
             raise ClockMismatch(
-                f"step {k}: the first {FETCH} after its dispatch ends "
+                f"step {k}: the {FETCH} of its report ends "
                 + ("nowhere in the trace" if fetch_end is None else
                    f"{(m_end - fetch_end) * 1e-6:.3f} ms before its module "
                    "ends on the device"))
-    return len(runs)
+    return len(dispatches)
 
 
 def attribute(gaps, spans, runs):
     """-> `{name: ns}` over `NAMES` for idle `gaps` `[(start, end), ...]`."""
-    naming = [(s, e, n) for n, s, e in spans if n in NAMING]
+    naming = [(s, e, n) for n, s, e, *_ in spans if n in NAMING]
     starts = sorted(s for s, _ in runs)
     out = dict.fromkeys(NAMES, 0.0)
     for g_start, g_end in gaps:
@@ -154,14 +159,15 @@ def attribute(gaps, spans, runs):
 
 def reduce_device(modules, ops, spans) -> dict | None:
     """One device's table. `modules`, `ops`: `(name, start_ns, duration_ns)`
-    of its two lines; `spans`: `loop_spans(...)[1]`. None where no module
-    ran twice (as `trace.reduce_device`)."""
-    runs = step_runs(modules)
-    if len(runs) < 2:
+    of its two lines; `spans`: `loop_spans(...)[1]`. None where
+    `trace.reduce_device` gives none."""
+    _, runs = step_runs(modules)
+    whole = whole_runs(runs)
+    if len(whole) < 2:
         return None
     checked = check_clocks(spans, runs)
-    lo, hi = runs[0][0], runs[-1][0]
-    periods = len(runs) - 1
+    lo, hi = whole[0][0], whole[-1][0]
+    periods = len(whole) - 1
     busy = clip([(s, s + d) for _, s, d in ops if s + d > lo and s < hi],
                 lo, hi)
     table = attribute([(s, e) for s, e, _ in gaps_ns(busy, lo, hi)],
@@ -196,6 +202,13 @@ def reduce_planes(planes) -> dict:
             "gap_s_per_step": table}
 
 
+def _event(e):
+    """An event as the reductions take it; a loop's span with its `step`."""
+    if e.name.startswith(SPAN_PREFIX):
+        return (e.name, e.start_ns, e.duration_ns, dict(e.stats).get("step"))
+    return (e.name, e.start_ns, e.duration_ns)
+
+
 def reduce_host_spans(trace_dir: str) -> dict:
     """The reduction of the capture under `trace_dir` (or of one
     `.xplane.pb`)."""
@@ -208,8 +221,7 @@ def reduce_host_spans(trace_dir: str) -> dict:
         if plane.name == HOST_PLANE or plane.name.startswith(
                 DEVICE_PLANE_PREFIX):
             planes[plane.name] = {
-                line.name: [(e.name, e.start_ns, e.duration_ns)
-                            for e in line.events]
+                line.name: [_event(e) for e in line.events]
                 for line in plane.lines
                 if plane.name == HOST_PLANE
                 or line.name in (MODULES_LINE, OPS_LINE)}

@@ -61,8 +61,7 @@ def main(argv=None):
         config, traffic["global_batch"])
 
     def pool_of(seed):
-        return traffic_mod.make_pool(traffic, image_shape,
-                                     config["num_classes"], seed)
+        return traffic_mod.make_pool(traffic, config, image_shape, seed)
 
     module = train.reference_module(config)
     init = jax.jit(lambda k: module.init(config, k))

@@ -6,7 +6,9 @@ matmul precision, and returns what the comparison reads: each step's
 loss, the first gradient, and the parameters' change after the last step.
 The optimizer updates are written out (SGD with momentum and L2 weight
 decay as torch's; AdamW, decoupled decay, bias-corrected) and the learning
-rate schedules too. Imports nothing of the program, and not optax.
+rate schedules too; the optimizer's state is stored in the configuration's
+`optimizer_state_dtype` (float32 where it states none), the update itself
+in float32. Imports nothing of the program, and not optax.
 
 `control` computes the same in the precision below the configuration's
 (`CONTROL_BELOW`): every matmul operand is rounded to that type in the
@@ -57,22 +59,36 @@ def _round_fp8(x):
 CONTROL_BELOW = {"float32": _round_bfloat16, "bfloat16": _round_fp8}
 
 
+def _state_dtype(cfg):
+    """The type the optimizer's state is stored in between steps: the
+    configuration's `optimizer_state_dtype`, float32 where it states none."""
+    return jnp.dtype(cfg.get("optimizer_state_dtype") or "float32")
+
+
 def _opt_init(cfg, params):
-    zeros = jax.tree.map(jnp.zeros_like, params)
-    if cfg["optimizer"]["name"] == "sgd":
-        return {"trace": zeros}
-    return {"mu": zeros, "nu": jax.tree.map(jnp.zeros_like, params)}
+    names = ("trace",) if cfg["optimizer"]["name"] == "sgd" else ("mu", "nu")
+    # `zeros_like` keeps each parameter's placement, so the first step runs
+    # the program that the later ones run
+    return {name: jax.tree.map(
+        lambda p: jnp.zeros_like(p, dtype=_state_dtype(cfg)), params)
+        for name in names}
 
 
 def _opt_update(cfg, params, grads, opt, lr, count):
+    """The update in float32 whatever the state is stored in: the state is
+    upcast on the way in, the parameters move by the unrounded new state,
+    and that is rounded once on the way out."""
     o = cfg["optimizer"]
     wd = o.get("weight_decay", 0.0)
+    opt = jax.tree.map(lambda x: x.astype(jnp.float32), opt)
+    stored = lambda new: jax.tree.map(
+        lambda x: x.astype(_state_dtype(cfg)), new)
     if o["name"] == "sgd":
         trace = jax.tree.map(
             lambda g, p, t: o.get("momentum", 0.0) * t + g + wd * p,
             grads, params, opt["trace"])
         return jax.tree.map(lambda p, t: p - lr * t, params, trace), \
-            {"trace": trace}
+            stored({"trace": trace})
     if o["name"] == "adamw":
         b1, b2, eps = o.get("b1", 0.9), o.get("b2", 0.999), 1e-8
         mu = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, opt["mu"], grads)
@@ -83,15 +99,16 @@ def _opt_update(cfg, params, grads, opt, lr, count):
         new = jax.tree.map(
             lambda p, m, v: p - lr * ((m / c1) / (jnp.sqrt(v / c2) + eps)
                                       + wd * p), params, mu, nu)
-        return new, {"mu": mu, "nu": nu}
+        return new, stored({"mu": mu, "nu": nu})
     raise ValueError(f"no reference for optimizer {o['name']!r}")
 
 
 def run_steps(module, cfg, variables, batches, control=False, rows=None,
               row_blocks=1):
-    """Follow `len(batches)` steps. -> {"losses": [...], "grad": tree of the
-    first step's gradient, "delta": tree of params after the last step
-    minus params before the first}.
+    """Follow `len(batches)` steps; a batch is the feed's dict of arrays,
+    rows leading, handed to `module.loss_fn` as it is. -> {"losses": [...],
+    "grad": tree of the first step's gradient, "delta": tree of params
+    after the last step minus params before the first}.
 
     `row_blocks` > 1 accumulates the gradient over equal blocks of rows (so
     the reference fits the device); refused where rows are coupled."""
@@ -99,27 +116,27 @@ def run_steps(module, cfg, variables, batches, control=False, rows=None,
     if row_blocks > 1 and module.BATCH_COUPLED:
         raise ValueError("rows are coupled through batch statistics")
 
-    def grad_block(params, stats, images, labels):
+    def grad_block(params, stats, batch):
         (loss, new_stats), grads = jax.value_and_grad(
-            lambda p: module.loss_fn(cfg, p, stats, images, labels, q),
+            lambda p: module.loss_fn(cfg, p, stats, batch, q),
             has_aux=True)(params)
         return loss, new_stats, grads
 
     @jax.jit
-    def step(params, stats, opt, images, labels, lr, count):
+    def step(params, stats, opt, batch, lr, count):
         if rows is not None:
-            images, labels = images[:rows], labels[:rows]
+            batch = jax.tree.map(lambda x: x[:rows], batch)
         if row_blocks == 1:
-            loss, new_stats, grads = grad_block(params, stats, images, labels)
+            loss, new_stats, grads = grad_block(params, stats, batch)
         else:
             def body(acc, block):
-                l, s, g = grad_block(params, stats, *block)
+                l, s, g = grad_block(params, stats, block)
                 return jax.tree.map(lambda a, b: a + b / row_blocks,
                                     acc, (l, g)), s
             split = lambda x: x.reshape(row_blocks, -1, *x.shape[1:])
             zero = (jnp.float32(0.0), jax.tree.map(jnp.zeros_like, params))
             (loss, grads), new_stats = jax.lax.scan(
-                body, zero, (split(images), split(labels)))
+                body, zero, jax.tree.map(split, batch))
             new_stats = jax.tree.map(lambda x: x[-1], new_stats)
         new_params, new_opt = _opt_update(cfg, params, grads, opt, lr, count)
         return new_params, new_stats, new_opt, loss, grads
@@ -130,7 +147,7 @@ def run_steps(module, cfg, variables, batches, control=False, rows=None,
     with jax.default_matmul_precision("highest"):
         for count, batch in enumerate(batches):
             params, stats, opt, loss, grads = step(
-                params, stats, opt, batch["image"], batch["label"],
+                params, stats, opt, batch,
                 jnp.float32(learning_rate(cfg, count)), jnp.float32(count))
             losses.append(float(loss))
             if count == 0:

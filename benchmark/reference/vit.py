@@ -107,10 +107,10 @@ def forward(cfg, variables, images, q=lambda x: x):
     return q(x) @ q(d["kernel"]) + d["bias"], {}
 
 
-def loss_fn(cfg, params, batch_stats, images, labels, q=lambda x: x):
+def loss_fn(cfg, params, batch_stats, batch, q=lambda x: x):
     """Mean softmax cross entropy over the batch -> (loss, {})."""
     logits, new = forward(cfg, {"params": params, "batch_stats": batch_stats},
-                          images, q)
+                          batch["image"], q)
     logp = jax.nn.log_softmax(logits)
-    nll = -jnp.take_along_axis(logp, labels[:, None], axis=1)[:, 0]
+    nll = -jnp.take_along_axis(logp, batch["label"][:, None], axis=1)[:, 0]
     return jnp.mean(nll), new

@@ -18,6 +18,7 @@ import argparse  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
+import statistics  # noqa: E402
 import sys  # noqa: E402
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -147,6 +148,13 @@ def run_cell(manifest, workload, seed, seconds, trace, require_chip=True):
         device.update(busy_s=red["busy_s"], window_s=red["window_s"])
         result["breakdown"] = {"device_ops": top(red["op_s_per_step"]),
                                "idle_gaps": top(red["gap_s_per_step"])}
+        # the device's clock against the host's: the slice's wall per whole
+        # period beside the window's median step interval
+        result["slice"] = {
+            "periods": red["periods"],
+            "wall_per_period_ms": red["window_s"] / red["periods"] * 1e3,
+            "interval_median_ms": statistics.median(
+                record["intervals_s"]) * 1e3}
     result["reference_s"] = record["reference_s"]
     result["faults"] = record["faults"]
     result["compared"] = record["compared"]

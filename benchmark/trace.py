@@ -4,9 +4,12 @@ Read with `jax.profiler.ProfileData` alone. A device plane
 (`/device:TPU:<n>`) carries one line of whole-program executions
 (`XLA Modules`) and one of the operations inside them (`XLA Ops`). The
 training step is the module that takes most of the device's time; the
-slice that is measured runs from the start of its first execution to the
-start of its last, so it holds whole step periods and nothing else, on the
-device's own clock. Busy time is the union of the operations' intervals in
+slice that is measured runs from the start of its second execution in the
+trace to the start of its last, so it holds whole step periods and nothing
+else, on the device's own clock. The first execution is left out because
+a loop that keeps a step in flight starts the session inside it, and it
+enters the trace with its start cut to the session's: a period that begins
+there is short. Busy time is the union of the operations' intervals in
 that slice; everything else in it is idle.
 """
 from __future__ import annotations
@@ -64,16 +67,31 @@ def clip(intervals, lo, hi):
             if e > lo and s < hi]
 
 
-def reduce_device(modules, ops) -> dict | None:
-    """One device's reduction. `modules` and `ops` are lists of
-    `(name, start_ns, duration_ns)`. None where no module ran twice."""
+def step_runs(modules):
+    """-> (the step module's name, `(start, end)` of each of its executions
+    in order). The step module is the one that takes most of the device's
+    time. `modules`: `(name, start_ns, duration_ns)` of the modules' line."""
     by_module = defaultdict(list)
     for name, start, dur in modules:
-        by_module[name].append((start, dur))
+        by_module[name].append((start, start + dur))
     if not by_module:
-        return None
-    step_name = max(by_module, key=lambda n: sum(d for _, d in by_module[n]))
-    runs = sorted(by_module[step_name])
+        return None, []
+    step = max(by_module, key=lambda n: sum(e - s for s, e in by_module[n]))
+    return step, sorted(by_module[step])
+
+
+def whole_runs(runs):
+    """The executions whose starts bound whole step periods: all but the
+    trace's first, which a session that starts inside it cuts short."""
+    return runs[1:]
+
+
+def reduce_device(modules, ops) -> dict | None:
+    """One device's reduction. `modules` and `ops` are lists of
+    `(name, start_ns, duration_ns)`. None where the step module has fewer
+    than two executions after its first."""
+    step_name, runs = step_runs(modules)
+    runs = whole_runs(runs)
     if len(runs) < 2:
         return None
     lo, hi = runs[0][0], runs[-1][0]
@@ -93,7 +111,8 @@ def reduce_device(modules, ops) -> dict | None:
         "periods": periods,
         "window_s": (hi - lo) * 1e-9,
         "busy_s": busy * 1e-9,
-        "step_device_ms": statistics.median(d for _, d in runs[:-1]) * 1e-6,
+        "step_device_ms": statistics.median(
+            e - s for s, e in runs[:-1]) * 1e-6,
         "op_s_per_step": {n: v * 1e-9 / periods for n, v in per_op.items()},
         "gap_s_per_step": {n: v * 1e-9 / periods for n, v in per_gap.items()},
     }

@@ -4,6 +4,7 @@ The rehearsal cells (tests/benchmark/rehearsal.json) are tiny float32
 models on the 8-device virtual mesh; they are never cells of
 BENCHMARK.json and state no device number.
 """
+import hashlib
 import itertools
 import json
 import os
@@ -24,7 +25,7 @@ if ROOT not in sys.path:
 from benchmark import compare, flops, run, trace  # noqa: E402
 from benchmark import traffic as traffic_mod  # noqa: E402
 from benchmark.adapters import train as train_adapter  # noqa: E402
-from benchmark.reference import resnet, vit  # noqa: E402
+from benchmark.reference import resnet, steps, vit  # noqa: E402
 
 REHEARSAL = os.path.join(ROOT, "tests", "benchmark", "rehearsal.json")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -71,11 +72,12 @@ def test_interval_union_counts_overlap_and_nesting_once():
 
 
 def test_idle_share_of_a_hand_made_device_plane():
-    # three executions of the step, 100 ns apart; 60 ns of ops in each
-    # period (two overlapping, one nested); a short other module between
-    modules = [("step", 1000, 70), ("other", 1075, 5), ("step", 1100, 70),
-               ("step", 1200, 70)]
-    ops = []
+    # an execution of the step that the session started inside, then three
+    # whole ones, 100 ns apart; 60 ns of ops in each period (two
+    # overlapping, one nested); a short other module between
+    modules = [("step", 960, 10), ("step", 1000, 70), ("other", 1075, 5),
+               ("step", 1100, 70), ("step", 1200, 70)]
+    ops = [("%fusion.1 = f32[8] fusion(f32[8] %p)", 960, 10)]
     for start in (1000, 1100, 1200):
         ops += [("%fusion.1 = f32[8] fusion(f32[8] %p)", start, 40),
                 ("%copy.2 = f32[8] custom-call(f32[8] %q)", start + 30, 30),
@@ -88,6 +90,34 @@ def test_idle_share_of_a_hand_made_device_plane():
     assert red["op_s_per_step"]["fusion.1"] == pytest.approx(40e-9)
     assert red["gap_s_per_step"] == {"after:copy.2": pytest.approx(40e-9)}
     assert trace.reduce_device([("step", 0, 5)], ops) is None
+    assert trace.reduce_device(modules[:3], ops) is None  # no whole period
+
+
+def plane_of(first_start, first_length, whole, period=1000, length=990):
+    """A device plane whose trace begins with one execution of the step at
+    `first_start`, `first_length` long, then `whole` more, back to back but
+    for the 10 ns between two."""
+    modules = [("step", first_start, first_length)] + [
+        ("step", period * (k + 1), length) for k in range(whole)]
+    return modules, [("%fusion.1 = f32[8] fusion()", s, d)
+                     for _, s, d in modules]
+
+
+@pytest.mark.parametrize("first_start, first_length", [
+    (700, 290),  # the session started inside it: its start is the session's
+    (0, 990),    # a whole one
+])
+def test_the_slice_holds_whole_periods_only(first_start, first_length):
+    red = trace.reduce_device(*plane_of(first_start, first_length, whole=20))
+    assert red["periods"] == 19
+    assert red["window_s"] / red["periods"] == pytest.approx(1000e-9)
+    assert red["busy_s"] / red["periods"] == pytest.approx(990e-9)
+    assert red["step_device_ms"] == pytest.approx(990e-6)
+    # one period fewer, the same wall and busy time per period
+    fewer = trace.reduce_device(*plane_of(first_start, first_length, whole=19))
+    assert fewer["periods"] == 18
+    assert fewer["window_s"] / 18 == pytest.approx(red["window_s"] / 19)
+    assert fewer["gap_s_per_step"] == pytest.approx(red["gap_s_per_step"])
 
 
 # -- FLOP count --------------------------------------------------------------
@@ -113,9 +143,160 @@ def test_flops_of_resnet50_per_image():
     with open(os.path.join(ROOT, "benchmark", "configs",
                            "resnet50.json")) as f:
         config = json.load(f)
-    per_image = flops.train_step_flops(resnet, config, (2, 112, 112, 12),
-                                       (2,)) / 2
+    traffic = {"kind": "resident_pool", "global_batch": 2, "pool_batches": 1}
+    per_image = flops.train_step_flops(resnet, config, traffic_mod.batch_spec(
+        traffic, config, (112, 112, 12))) / 2
     assert per_image == pytest.approx(24.6e9, rel=0.05)
+    assert per_image == 24299077632.0  # PR 25's count, to the digit
+
+
+def test_flops_of_vit_b16_per_image():
+    with open(os.path.join(ROOT, "benchmark", "configs", "vit_b16.json")) as f:
+        config = json.load(f)
+    traffic = {"kind": "resident_pool", "global_batch": 2, "pool_batches": 1}
+    per_image = flops.train_step_flops(vit, config, traffic_mod.batch_spec(
+        traffic, config, (224, 224, 3))) / 2
+    assert per_image == 104598687744.0  # PR 25's count, to the digit
+
+
+class _Plain:
+    """A reference of one matmul, counted by its jaxpr: the product, and
+    its gradient to the weights (none to the batch)."""
+    BATCH_COUPLED = False
+
+    @staticmethod
+    def init(cfg, key):
+        return {"params": {"w": jnp.ones((cfg["dim"], cfg["dim"]))},
+                "batch_stats": {}}
+
+    @staticmethod
+    def loss_fn(cfg, params, batch_stats, batch, q=lambda x: x):
+        return jnp.sum(batch["x"] @ params["w"]), batch_stats
+
+
+class _Written(_Plain):
+    """The same with its count written down: a quarter of the jaxpr's, as
+    where only a quarter of the experts held are routed to."""
+
+    @staticmethod
+    def step_flops(cfg, batch_spec):
+        rows, dim = batch_spec["x"].shape
+        return rows * dim * dim
+
+
+def test_a_written_count_is_the_count_and_the_jaxpr_stands_in_for_none():
+    cfg = {"dim": 8}
+    spec = {"x": jax.ShapeDtypeStruct((4, 8), jnp.float32)}
+    assert flops.train_step_flops(_Plain, cfg, spec) == 2 * (2 * 4 * 8 * 8)
+    assert flops.train_step_flops(_Written, cfg, spec) == 4 * 8 * 8
+
+
+# -- the feed ----------------------------------------------------------------
+
+def test_resident_pool_is_the_arrays_of_the_first_generator():
+    """The hash is of PR 25's `make_pool` (commit 268d362) at these sizes
+    and this seed: the same bits, so every limit set on them stands."""
+    traffic = {"kind": "resident_pool", "global_batch": 4, "pool_batches": 3}
+    config = {"num_classes": 10}
+    pool = traffic_mod.make_pool(traffic, config, (8, 8, 3), 2 ** 31 + 11)
+    digest = hashlib.sha256()
+    for batch in pool:
+        assert list(batch) == ["image", "label"]
+        digest.update(batch["image"].tobytes())
+        digest.update(batch["label"].tobytes())
+    assert digest.hexdigest() == ("6ae0fe05ac6ea03f30d40de2f66bff10"
+                                  "c517e4adc9c8fdadd188e0af260a2027")
+    spec = traffic_mod.batch_spec(traffic, config, (8, 8, 3))
+    assert {k: (v.shape, v.dtype) for k, v in spec.items()} == {
+        k: (v.shape, v.dtype) for k, v in pool[0].items()}
+
+
+TOKEN_TRAFFIC = {"kind": "token_pool", "global_batch": 8, "seq_len": 12,
+                 "pool_batches": 3}
+TOKEN_CONFIG = {"vocab_size": 32, "dim": 16, "compute_dtype": "float32",
+                "optimizer": {"name": "adamw", "learning_rate": 1e-2,
+                              "weight_decay": 1e-4},
+                "schedule": None, "steps_per_epoch": 100}
+
+
+def test_token_pool_gives_ids_below_the_vocabulary_from_the_seed():
+    pool = traffic_mod.make_pool(TOKEN_TRAFFIC, TOKEN_CONFIG, None,
+                                 2 ** 31 + 5)
+    assert len(pool) == 3
+    for batch in pool:
+        assert list(batch) == ["tokens"]
+        tokens = batch["tokens"]
+        assert tokens.shape == (8, 12) and tokens.dtype == np.int32
+        assert tokens.min() >= 0 and tokens.max() < 32
+    assert len({b["tokens"].tobytes() for b in pool}) == 3  # all differ
+    assert pool[0]["tokens"].max() > 16  # the whole range, not a part
+    again = traffic_mod.make_pool(TOKEN_TRAFFIC, TOKEN_CONFIG, None,
+                                  2 ** 31 + 5)
+    other = traffic_mod.make_pool(TOKEN_TRAFFIC, TOKEN_CONFIG, None,
+                                  2 ** 31 + 6)
+    for a, b, c in zip(pool, again, other):
+        assert np.array_equal(a["tokens"], b["tokens"])
+        assert not np.array_equal(a["tokens"], c["tokens"])
+    spec = traffic_mod.batch_spec(TOKEN_TRAFFIC, TOKEN_CONFIG, None)
+    assert spec["tokens"].shape == (8, 12)
+    assert spec["tokens"].dtype == np.int32
+    with pytest.raises(ValueError, match="not a training feed"):
+        traffic_mod.make_pool({**TOKEN_TRAFFIC, "kind": "open_loop"},
+                              TOKEN_CONFIG, None, 1)
+
+
+class _ToyTokens:
+    """A token-sequence reference: embedding, one matmul, and the mean
+    loss of every position's next token."""
+    BATCH_COUPLED = False
+
+    @staticmethod
+    def init(cfg, key):
+        k1, k2 = jax.random.split(key)
+        v, d = cfg["vocab_size"], cfg["dim"]
+        return {"params": {"embed": jax.random.normal(k1, (v, d)),
+                           "out": jax.random.normal(k2, (d, v)) * d ** -0.5},
+                "batch_stats": {}}
+
+    @staticmethod
+    def loss_fn(cfg, params, batch_stats, batch, q=lambda x: x):
+        tokens = batch["tokens"]
+        x = params["embed"][tokens[:, :-1]]
+        logp = jax.nn.log_softmax(q(x) @ q(params["out"]))
+        nll = -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)
+        return jnp.mean(nll), batch_stats
+
+
+@pytest.mark.parametrize("how", ["whole", "row_blocks", "rows_halved"])
+def test_run_steps_follows_a_token_reference_through_its_batch_dict(how):
+    pool = traffic_mod.make_pool(TOKEN_TRAFFIC, TOKEN_CONFIG, None, 7)
+    variables = _ToyTokens.init(TOKEN_CONFIG, jax.random.PRNGKey(7))
+    plain = steps.run_steps(_ToyTokens, TOKEN_CONFIG, variables, pool)
+    assert len(plain["losses"]) == 3 and np.all(np.isfinite(plain["losses"]))
+    assert plain["losses"][2] < plain["losses"][0]  # it does learn
+    if how == "whole":  # the first step is the loss and gradient themselves
+        with jax.default_matmul_precision("highest"):
+            (loss, _), grad = jax.value_and_grad(
+                lambda p: _ToyTokens.loss_fn(TOKEN_CONFIG, p, {}, pool[0]),
+                has_aux=True)(variables["params"])
+        got, want = plain, {"losses": [float(loss)], "grad": grad}
+    elif how == "row_blocks":  # the mean over two equal blocks of rows
+        got = steps.run_steps(_ToyTokens, TOKEN_CONFIG, variables, pool,
+                              row_blocks=2)
+        want = plain
+    else:  # every array of the batch cut to its first rows
+        got = steps.run_steps(_ToyTokens, TOKEN_CONFIG, variables, pool,
+                              rows=4)
+        want = steps.run_steps(
+            _ToyTokens, TOKEN_CONFIG, variables,
+            [{k: v[:4] for k, v in batch.items()} for batch in pool])
+        assert got["losses"][0] != pytest.approx(plain["losses"][0], rel=1e-4)
+    assert got["losses"][:len(want["losses"])] == pytest.approx(
+        want["losses"], rel=1e-5)
+    for key in set(want) - {"losses"}:
+        for a, b in zip(jax.tree.leaves(got[key]), jax.tree.leaves(want[key])):
+            assert float(jnp.linalg.norm(a - b)) <= 1e-4 * float(
+                jnp.linalg.norm(b))
 
 
 # -- the plain references against the program's models -----------------------
@@ -149,7 +330,8 @@ def test_reference_matches_the_programs_model(tiny_models, family, module,
         lp, gp = jax.jit(jax.value_and_grad(program_loss))(
             variables["params"])
         (lr, _), gr = jax.jit(jax.value_and_grad(
-            lambda p: module.loss_fn(config, p, stats, x, y),
+            lambda p: module.loss_fn(config, p, stats,
+                                     {"image": x, "label": y}),
             has_aux=True))(variables["params"])
     assert float(lp) == pytest.approx(float(lr), rel=1e-5)
     for a, b in zip(jax.tree.leaves(gp), jax.tree.leaves(gr)):
@@ -229,6 +411,34 @@ def test_rehearsal_run_is_correct(tiny_models, cell):
     assert list(result)[-1] == "compared"
 
 
+def test_a_state_kept_in_bfloat16_is_kept_so_by_program_and_reference(
+        tiny_models, monkeypatch):
+    """`optimizer_state_dtype` in the configuration's file reaches
+    `build_trainer(opt_state_dtype=)` and the reference's own moments."""
+    from deep_vision_tpu import train_cli
+
+    kept = {"program": [], "reference": []}
+    build, update = train_cli.build_trainer, steps._opt_update
+
+    def spy_program(*args, **kw):
+        trainer = build(*args, **kw)
+        kept["program"] += jax.tree.leaves(
+            train_adapter._find(trainer.state.opt_state, "mu"))
+        return trainer
+
+    def spy_reference(*args):
+        new_params, opt = update(*args)
+        kept["reference"] += jax.tree.leaves(opt)
+        return new_params, opt
+
+    monkeypatch.setattr(train_cli, "build_trainer", spy_program)
+    monkeypatch.setattr(steps, "_opt_update", spy_reference)
+    result = rehearse("tiny_vit_bf16state_train")
+    assert result["correct"], (result["compared"], result["faults"])
+    for side, leaves in kept.items():
+        assert leaves and all(x.dtype == jnp.bfloat16 for x in leaves), side
+
+
 def _state_unchanged(impl):
     def broken(self, state, batch):
         _, metrics = impl(self, state, batch)
@@ -304,14 +514,15 @@ def test_every_number_is_held_to_its_limit():
             values, {k: v for k, v in limits.items() if k != name})[0]
 
 
-@pytest.mark.parametrize("cell", ["tiny_resnet_train", "tiny_vit_train"])
+@pytest.mark.parametrize("cell", ["tiny_resnet_train", "tiny_vit_train",
+                                  "tiny_vit_bf16state_train"])
 def test_the_lower_precision_control_fails(cell):
     """The reference computed in bfloat16, the precision below the float32
     these rehearsal configurations state, put in the program's place and
     judged at the cell's limits."""
     cell, config, traffic = run.resolve(run.load_manifest(REHEARSAL), cell)
     shape = (16, 16, 12) if config["reference"] == "resnet" else (32, 32, 3)
-    pool = traffic_mod.make_pool(traffic, shape, config["num_classes"], 5)
+    pool = traffic_mod.make_pool(traffic, config, shape, 5)
     devices = jax.devices()[:1]
     reference = train_adapter.reference_steps(config, pool, 5, devices)
     control = train_adapter.reference_steps(config, pool, 5, devices,

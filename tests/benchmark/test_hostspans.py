@@ -15,29 +15,32 @@ if ROOT not in sys.path:
 
 from benchmark import hostspans, trace  # noqa: E402
 
-# Two executions of the step module, 2000 ns apart, 1000 ns long; the first
-# has a 100 ns bubble inside. The slice is [1000, 3000): idle [1400, 1500)
-# and [2000, 3000).
-MODULES = [("jit_step", 1000, 1000), ("jit_other", 2300, 10),
-           ("jit_step", 3000, 1000)]
-OPS = [("%fusion.1 = f32[8] fusion()", 1000, 400),
+# An execution of the step module that the session started inside (cut
+# short, its dispatch before the trace), then two whole ones, 2000 ns apart,
+# 1000 ns long; the first of those has a 100 ns bubble inside. The slice is
+# [1000, 3000): idle [1400, 1500) and [2000, 3000).
+MODULES = [("jit_step", 100, 300), ("jit_step", 1000, 1000),
+           ("jit_other", 2300, 10), ("jit_step", 3000, 1000)]
+OPS = [("%fusion.1 = f32[8] fusion()", 100, 300),
+       ("%fusion.1 = f32[8] fusion()", 1000, 400),
        ("%copy-done.2 = f32[8] copy-done()", 1500, 500),
        ("%fusion.1 = f32[8] fusion()", 3000, 1000)]
-# (name, start, end) on the loop's thread, two steps
+# (name, start, end, its `step` argument) on the loop's thread: steps 7 and
+# 8 of a loop that reads each step's report before it dispatches the next
 SPANS = [
-    ("train/epoch", 400, 4400),
-    ("train/data_wait", 500, 600),
-    ("train/step", 600, 2400),
-    ("train/place", 610, 700),
-    ("train/dispatch", 700, 800),
-    ("train/fetch", 820, 2100),     # ends 100 after its module
-    ("train/log", 2150, 2400),
-    ("train/data_wait", 2450, 2550),
-    ("train/step", 2600, 4300),
-    ("train/place", 2600, 2750),
-    ("train/dispatch", 2760, 2800),
-    ("train/fetch", 2850, 4100),    # 150 before its module begins
-    ("train/log", 4150, 4300),
+    ("train/epoch", 400, 4400, None),
+    ("train/data_wait", 500, 600, 7),
+    ("train/step", 600, 2400, 7),
+    ("train/place", 610, 700, 7),
+    ("train/dispatch", 700, 800, 7),
+    ("train/fetch", 820, 2100, 7),     # ends 100 after its module
+    ("train/log", 2150, 2400, 7),
+    ("train/data_wait", 2450, 2550, 8),
+    ("train/step", 2600, 4300, 8),
+    ("train/place", 2600, 2750, 8),
+    ("train/dispatch", 2760, 2800, 8),
+    ("train/fetch", 2850, 4100, 8),    # 150 before its module begins
+    ("train/log", 4150, 4300, 8),
 ]
 EXPECTED = {  # ns of the slice's 1100 idle, by hand
     "train/fetch": 100 + 100,       # the bubble, and the tail after the end
@@ -51,7 +54,7 @@ EXPECTED = {  # ns of the slice's 1100 idle, by hand
 
 
 def host_lines(shift=0, spans=SPANS):
-    return {"python": [(n, s + shift, e - s) for n, s, e in spans]
+    return {"python": [(n, s + shift, e - s, step) for n, s, e, step in spans]
             + [("PjitFunction(step)", 705 + shift, 80)],
             "prefetch": [("data/fetch", 100, 50)]}
 
@@ -133,6 +136,77 @@ def test_other_steps_than_the_devices_trip_the_check():
     with pytest.raises(hostspans.ClockMismatch, match="not the same steps"):
         hostspans.reduce_planes({**planes(), hostspans.HOST_PLANE:
                                  host_lines(spans=fewer)})
+
+
+def capture(steps, in_flight, first=4):
+    """Planes of a hand-made capture of `steps` dispatches, 1000 ns a step.
+    `in_flight`: the loop of PR 27, which dispatches step k and then reads
+    step k-1 (the device runs back to back, the session starts inside step
+    `first - 1` and stops before the last report is read); else the loop
+    before it, which reads step k before it feeds step k+1 (the device
+    idles 400 ns a step, and every execution has its dispatch)."""
+    modules, ops, spans = [], [], []
+    if in_flight:
+        t = 1000 * (first - 1)
+        modules.append(("jit_step", t + 700, 290))  # cut to the session's
+        ops.append(("%fusion.1 = f32[8] fusion()", t + 700, 290))
+    for k in range(first, first + steps):
+        t = 1000 * k
+        length = 990 if in_flight else 600
+        modules.append(("jit_step", t, length))
+        ops.append(("%fusion.1 = f32[8] fusion()", t, length))
+        read = k - 1 if in_flight else k
+        spans += [("train/data_wait", t - 350, 20, k),
+                  ("train/place", t - 320, 50, k),
+                  ("train/dispatch", t - 260, 60, k)]
+        if in_flight:  # ends 15 after module k-1, inside module k
+            spans += [("train/fetch", t - 190, 195, read),
+                      ("train/log", t + 10, 30, read)]
+        else:          # ends 40 after module k
+            spans += [("train/fetch", t - 190, 190 + 640, read),
+                      ("train/fetch", t + 100, 400, read),  # the fence
+                      ("train/log", t + 645, 5, read)]
+    return {hostspans.HOST_PLANE: {"python": spans},
+            f"{trace.DEVICE_PLANE_PREFIX}0": {trace.MODULES_LINE: modules,
+                                              trace.OPS_LINE: ops}}
+
+
+@pytest.mark.parametrize("in_flight", [True, False])
+def test_the_check_pairs_a_dispatch_with_the_fetch_of_its_own_step(in_flight):
+    red = hostspans.reduce_planes(capture(5, in_flight))
+    # whole periods only: the trace's first execution bounds none
+    assert red["steps_checked"] == 5
+    assert red["periods"] == (4 if in_flight else 3)
+    idle = sum(red["gap_s_per_step"].values())
+    assert idle == pytest.approx(10e-9 if in_flight else 400e-9)
+    fewer = hostspans.reduce_planes(capture(4, in_flight))
+    assert fewer["periods"] == red["periods"] - 1
+    assert fewer["gap_s_per_step"] == pytest.approx(red["gap_s_per_step"])
+
+
+@pytest.mark.parametrize("in_flight, shift, says", [
+    (True, -20, "before its module ends"),   # fetch of k ends inside module k
+    (True, 300, "after its module begins"),
+    (False, -50, "before its module ends"),
+])
+def test_a_shifted_host_plane_trips_the_check_in_either_loop(in_flight, shift,
+                                                             says):
+    planes_ = capture(5, in_flight)
+    planes_[hostspans.HOST_PLANE] = {"python": [
+        (n, s + shift, d, step)
+        for n, s, d, step in planes_[hostspans.HOST_PLANE]["python"]]}
+    with pytest.raises(hostspans.ClockMismatch, match=says):
+        hostspans.reduce_planes(planes_)
+
+
+def test_a_report_read_for_another_step_trips_the_check():
+    planes_ = capture(5, in_flight=True)
+    planes_[hostspans.HOST_PLANE] = {"python": [
+        (n, s, d, step + 1 if n == "train/fetch" else step)
+        for n, s, d, step in planes_[hostspans.HOST_PLANE]["python"]]}
+    # the fetch that says step k now ends when module k-1 does
+    with pytest.raises(hostspans.ClockMismatch, match="before its module"):
+        hostspans.reduce_planes(planes_)
 
 
 def test_a_trace_without_the_loops_spans_is_refused():
