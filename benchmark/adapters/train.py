@@ -97,22 +97,25 @@ def build_trainer(config, global_batch):
 def seed_state(trainer, variables):
     """Put the benchmark's seeded variables into the trainer, at step 0
     with a fresh optimizer state. Refuses a tree the program does not
-    have: leaf names and shapes are the program's."""
+    have: leaf names and shapes are the program's. The state the trainer
+    held is freed before the new optimizer state is made, so the device
+    never holds two."""
     import jax
     import jax.numpy as jnp
 
-    have = jax.tree.map(lambda x: (x.shape, str(x.dtype)),
-                        {"params": trainer.state.params,
-                         "batch_stats": trainer.state.batch_stats})
+    state = trainer.state
+    held = {"params": state.params, "batch_stats": state.batch_stats}
+    have = jax.tree.map(lambda x: (x.shape, str(x.dtype)), held)
     made = jax.tree.map(lambda x: (x.shape, str(x.dtype)), variables)
     if have != made:
         raise ValueError("the reference's variable tree is not the "
                          "program's: " + _first_difference(have, made))
-    state = trainer.state.replace(
+    for leaf in jax.tree.leaves((held, state.opt_state)):
+        leaf.delete()
+    trainer.state = trainer._place_state(state.replace(
         step=jnp.zeros((), jnp.int32), params=variables["params"],
         batch_stats=variables["batch_stats"],
-        opt_state=jax.jit(trainer._tx.init)(variables["params"]))
-    trainer.state = trainer._place_state(state)
+        opt_state=jax.jit(trainer._tx.init)(variables["params"])))
 
 
 def _first_difference(a, b):
@@ -144,23 +147,28 @@ def _find(state, name):
 def first_gradient_norms(config, opt_state, params0):
     """Per-leaf norms of the first gradient as the optimizer got it, worked
     out from its state after one step (upcast first, where the state is
-    stored below float32)."""
+    stored below float32) in one program that keeps no gradient tree.
+    `params0`, the parameters before that step, is read under SGD alone."""
     import jax
+    import jax.numpy as jnp
 
     o = config["optimizer"]
     if o["name"] == "sgd":  # trace_1 = g_1 + weight_decay * p_0
         wd = o.get("weight_decay", 0.0)
-        grad = jax.jit(lambda t, p: jax.tree.map(
-            lambda a, b: a.astype("float32") - wd * b, t, p))(
-                _find(opt_state, "trace"), params0)
+        first = lambda t, p: t.astype("float32") - wd * p
+        trace = _find(opt_state, "trace")
+        trees = (trace, jax.device_put(
+            params0, jax.tree.map(lambda t: t.sharding, trace)))
     elif o["name"] == "adamw":  # mu_1 = (1 - b1) * g_1
         b1 = o.get("b1", 0.9)
-        grad = jax.jit(lambda m: jax.tree.map(
-            lambda a: a.astype("float32") / (1 - b1), m))(
-                _find(opt_state, "mu"))
+        first = lambda m: m.astype("float32") / (1 - b1)
+        trees = (_find(opt_state, "mu"),)
     else:
         raise ValueError(f"no first gradient for optimizer {o['name']!r}")
-    return compare.leaf_norms(grad)
+    sums = jax.device_get(jax.jit(lambda *trees: [
+        jnp.sum(jnp.square(first(*leaves)))
+        for leaves in zip(*map(jax.tree.leaves, trees))])(*trees))
+    return np.sqrt(np.asarray(sums, np.float64))
 
 
 def _fit(trainer, batches):
@@ -173,16 +181,15 @@ def _fit(trainer, batches):
 
 def first_steps(trainer, journal, config, pool, variables):
     """Drive the trainer through its first steps with the window's own call
-    and feed. -> what the comparison reads of the program.
+    and feed. -> what the comparison reads of the program, on the host.
+    Consumes `variables`: they become the trainer's state, which the steps
+    donate; the caller keeps no name for them.
 
     The first gradient is read from the optimizer's state after exactly
     one step: a `fit` of one batch, closed, so that a loop which runs or
     fetches ahead cannot put the reading before or after that step."""
     import jax
 
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    everywhere = NamedSharding(trainer.mesh, PartitionSpec())
     params0 = jax.device_get(variables["params"])
     seed_state(trainer, variables)
     del variables
@@ -190,11 +197,12 @@ def first_steps(trainer, journal, config, pool, variables):
     batches = [pool[i % len(pool)] for i in range(COMPARED_STEPS)]
     _fit(trainer, batches[:1])
     got = {"grad_norms": first_gradient_norms(
-        config, trainer.state.opt_state, jax.device_put(params0, everywhere))}
+        config, trainer.state.opt_state, params0)}
     _fit(trainer, batches[1:])
-    delta = jax.jit(lambda a, b: jax.tree.map(lambda x, y: x - y, a, b))(
-        trainer.state.params, jax.device_put(params0, everywhere))
-    got["delta"] = jax.device_get(delta)  # kept on the host for the window
+    # on the host, where it waits out the window: float32 as the device
+    # subtracts, but that the host keeps subnormals
+    got["delta"] = jax.tree.map(lambda a, b: a - b,
+                                jax.device_get(trainer.state.params), params0)
     got["losses"] = [f["metrics"]["loss"]
                      for _, f in journal.steps[n_before:]][:COMPARED_STEPS]
     return got
@@ -210,9 +218,10 @@ def reference_steps(config, pool, seed, devices, control=False, rows=None):
 
     module = reference_module(config)
     mesh = Mesh(np.asarray(devices), ("rows",))
+    # set-up's own program makes them; placing them gives the source up
     variables = jax.device_put(
         jax.jit(lambda k: module.init(config, k))(seed_key(seed)),
-        NamedSharding(mesh, P()))
+        NamedSharding(mesh, P()), donate=True)
     batches = [jax.device_put(b, NamedSharding(mesh, P("rows")))
                for b in pool[:COMPARED_STEPS]]
     return steps.run_steps(
@@ -300,10 +309,10 @@ def run(cell, config, traffic, seed, seconds, trace, t_process_start):
     t_built = time.perf_counter()
     pool = traffic_mod.make_pool(traffic, config, image_shape, seed)
     module = reference_module(config)
-    variables = jax.jit(lambda k: module.init(config, k))(seed_key(seed))
+    # in a list, so that no name here outlives their hand-over
+    variables = [jax.jit(lambda k: module.init(config, k))(seed_key(seed))]
     t_warm = time.perf_counter()
-    program = first_steps(trainer, journal, config, pool, variables)
-    del variables
+    program = first_steps(trainer, journal, config, pool, variables.pop())
     t_ready = time.perf_counter()
     print(f"set-up: {t_import - t_process_start:.1f} s to the adapter, "
           f"{t_built - t_import:.1f} s build_trainer, "
@@ -350,7 +359,9 @@ def run(cell, config, traffic, seed, seconds, trace, t_process_start):
         "global_batch": traffic["global_batch"], "chips": cell["chips"],
         "setup_s": t_ready - t_process_start, "warmup_s": t_ready - t_warm,
         "reference_s": time.perf_counter() - t_ref,
-        "memory_peak_bytes": peak, "trace": reduction,
+        "memory_peak_bytes": peak,
+        "memory_peak_bytes_after": memory_peak_bytes(devices),
+        "trace": reduction,
         "config": config,
         "batch_spec": traffic_mod.batch_spec(traffic, config, image_shape),
     }

@@ -15,6 +15,12 @@ chip at a cell's own size, many seeds in one process:
 Each reading is passed through `compare.judge` at the cell's own limits,
 as a run's is, and its row says whether it came out `correct`. Not run by
 the benchmark's own runs.
+
+Memory: the programs' readings are all taken first and wait on the host,
+one float32 tree of the parameters (the change after three steps) a seed,
+and the reference's gradient and change wait there too while the control's
+and the fault's references have the device. So a cell whose parameters
+fill gigabytes is read a few seeds a call.
 """
 from __future__ import annotations
 
@@ -80,7 +86,9 @@ def main(argv=None):
 
     for n, seed in enumerate(seeds):
         pool = pool_of(seed)
-        reference = train.reference_steps(config, pool, seed, devices)
+        # to the host: the control's and the fault's references come next
+        reference = jax.device_get(
+            train.reference_steps(config, pool, seed, devices))
         row = rows[seed]
         row["reference_losses"] = reference["losses"]
         row["program"] = judged(program.pop(seed), reference)

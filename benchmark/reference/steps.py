@@ -18,10 +18,12 @@ left out, the mean over the rest).
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def learning_rate(cfg, count: int) -> float:
@@ -103,15 +105,33 @@ def _opt_update(cfg, params, grads, opt, lr, count):
     raise ValueError(f"no reference for optimizer {o['name']!r}")
 
 
+def _host_copy(tree):
+    """The tree on the host, in memory of its own: the CPU backend's
+    `device_get` is a view of the device's buffer, which a donation could
+    then neither take nor overwrite."""
+    return jax.tree.map(np.array, jax.device_get(tree))
+
+
 def run_steps(module, cfg, variables, batches, control=False, rows=None,
               row_blocks=1):
     """Follow `len(batches)` steps; a batch is the feed's dict of arrays,
     rows leading, handed to `module.loss_fn` as it is. -> {"losses": [...],
     "grad": tree of the first step's gradient, "delta": tree of params
-    after the last step minus params before the first}.
+    after the last step minus params before the first}, both on the device.
+
+    Consumes `variables`: every step donates its state, so the leaves
+    handed in are deleted, and a caller that follows the steps twice makes
+    them twice. What is on the device is then the training state (the
+    parameters and the optimizer's state as stored) and, inside a step,
+    one float32 tree of gradients and the activations of `loss_fn`: the
+    first parameters and the first gradient wait on the host, and come back
+    once the optimizer's state is freed.
 
     `row_blocks` > 1 accumulates the gradient over equal blocks of rows (so
-    the reference fits the device); refused where rows are coupled."""
+    the reference's activations fit the device) at the cost of one more
+    float32 tree, the accumulator; refused where rows are coupled. A
+    configuration that fills the device runs whole (`row_blocks` 1) and
+    blocks inside its own `loss_fn`."""
     q = CONTROL_BELOW[cfg["compute_dtype"]] if control else (lambda x: x)
     if row_blocks > 1 and module.BATCH_COUPLED:
         raise ValueError("rows are coupled through batch statistics")
@@ -122,7 +142,7 @@ def run_steps(module, cfg, variables, batches, control=False, rows=None,
             has_aux=True)(params)
         return loss, new_stats, grads
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
     def step(params, stats, opt, batch, lr, count):
         if rows is not None:
             batch = jax.tree.map(lambda x: x[:rows], batch)
@@ -142,7 +162,8 @@ def run_steps(module, cfg, variables, batches, control=False, rows=None,
         return new_params, new_stats, new_opt, loss, grads
 
     params, stats = variables["params"], variables["batch_stats"]
-    first, opt = params, _opt_init(cfg, params)
+    placed = jax.tree.map(lambda x: x.sharding, params)
+    first, opt = _host_copy(params), _opt_init(cfg, params)
     losses, first_grad = [], None
     with jax.default_matmul_precision("highest"):
         for count, batch in enumerate(batches):
@@ -151,6 +172,10 @@ def run_steps(module, cfg, variables, batches, control=False, rows=None,
                 jnp.float32(learning_rate(cfg, count)), jnp.float32(count))
             losses.append(float(loss))
             if count == 0:
-                first_grad = grads
-    return {"losses": losses, "grad": first_grad,
-            "delta": jax.tree.map(lambda a, b: a - b, params, first)}
+                first_grad = _host_copy(grads)
+            del grads  # or it lies beside the next step's
+    del opt
+    delta = jax.jit(lambda a, b: jax.tree.map(lambda x, y: x - y, a, b),
+                    donate_argnums=0)(params, jax.device_put(first, placed))
+    return {"losses": losses, "grad": jax.device_put(first_grad, placed),
+            "delta": delta}

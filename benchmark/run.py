@@ -138,7 +138,10 @@ def run_cell(manifest, workload, seed, seconds, trace, require_chip=True):
     group = "per_layer" if trace else "end_to_end"
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
-              "memory_peak_bytes": record["memory_peak_bytes"]}
+              "memory_peak_bytes": record["memory_peak_bytes"],
+              # the same reading once the reference has run and been
+              # compared: what the comparison cost beside the program
+              "memory_peak_bytes_after": record["memory_peak_bytes_after"]}
     result = {"correct": bool(record["correct"]),
               "attempted": record["attempted"], "failed": record["failed"],
               "metrics": read_metrics(manifest, group, record),

@@ -267,28 +267,32 @@ class _ToyTokens:
         return jnp.mean(nll), batch_stats
 
 
+def toy_variables():
+    """Made anew for every call of `run_steps`, which consumes them."""
+    return _ToyTokens.init(TOKEN_CONFIG, jax.random.PRNGKey(7))
+
+
 @pytest.mark.parametrize("how", ["whole", "row_blocks", "rows_halved"])
 def test_run_steps_follows_a_token_reference_through_its_batch_dict(how):
     pool = traffic_mod.make_pool(TOKEN_TRAFFIC, TOKEN_CONFIG, None, 7)
-    variables = _ToyTokens.init(TOKEN_CONFIG, jax.random.PRNGKey(7))
-    plain = steps.run_steps(_ToyTokens, TOKEN_CONFIG, variables, pool)
+    plain = steps.run_steps(_ToyTokens, TOKEN_CONFIG, toy_variables(), pool)
     assert len(plain["losses"]) == 3 and np.all(np.isfinite(plain["losses"]))
     assert plain["losses"][2] < plain["losses"][0]  # it does learn
     if how == "whole":  # the first step is the loss and gradient themselves
         with jax.default_matmul_precision("highest"):
             (loss, _), grad = jax.value_and_grad(
                 lambda p: _ToyTokens.loss_fn(TOKEN_CONFIG, p, {}, pool[0]),
-                has_aux=True)(variables["params"])
+                has_aux=True)(toy_variables()["params"])
         got, want = plain, {"losses": [float(loss)], "grad": grad}
     elif how == "row_blocks":  # the mean over two equal blocks of rows
-        got = steps.run_steps(_ToyTokens, TOKEN_CONFIG, variables, pool,
+        got = steps.run_steps(_ToyTokens, TOKEN_CONFIG, toy_variables(), pool,
                               row_blocks=2)
         want = plain
     else:  # every array of the batch cut to its first rows
-        got = steps.run_steps(_ToyTokens, TOKEN_CONFIG, variables, pool,
+        got = steps.run_steps(_ToyTokens, TOKEN_CONFIG, toy_variables(), pool,
                               rows=4)
         want = steps.run_steps(
-            _ToyTokens, TOKEN_CONFIG, variables,
+            _ToyTokens, TOKEN_CONFIG, toy_variables(),
             [{k: v[:4] for k, v in batch.items()} for batch in pool])
         assert got["losses"][0] != pytest.approx(plain["losses"][0], rel=1e-4)
     assert got["losses"][:len(want["losses"])] == pytest.approx(
@@ -297,6 +301,171 @@ def test_run_steps_follows_a_token_reference_through_its_batch_dict(how):
         for a, b in zip(jax.tree.leaves(got[key]), jax.tree.leaves(want[key])):
             assert float(jnp.linalg.norm(a - b)) <= 1e-4 * float(
                 jnp.linalg.norm(b))
+
+
+# -- the budget: the training state and one float32 tree of the parameters ---
+
+def parents_run_steps(module, cfg, variables, batches, control=False,
+                      rows=None, row_blocks=1):
+    """`steps.run_steps` as PR 28 had it, kept as the oracle of the loop
+    that consumes its state: it donates nothing, and keeps the first
+    parameters and the first gradient on the device through every step.
+    The optimizer's arithmetic is `steps`' own."""
+    q = steps.CONTROL_BELOW[cfg["compute_dtype"]] if control else (
+        lambda x: x)
+
+    def grad_block(params, stats, batch):
+        (loss, new_stats), grads = jax.value_and_grad(
+            lambda p: module.loss_fn(cfg, p, stats, batch, q),
+            has_aux=True)(params)
+        return loss, new_stats, grads
+
+    @jax.jit
+    def step(params, stats, opt, batch, lr, count):
+        if rows is not None:
+            batch = jax.tree.map(lambda x: x[:rows], batch)
+        if row_blocks == 1:
+            loss, new_stats, grads = grad_block(params, stats, batch)
+        else:
+            def body(acc, block):
+                l, s, g = grad_block(params, stats, block)
+                return jax.tree.map(lambda a, b: a + b / row_blocks,
+                                    acc, (l, g)), s
+            split = lambda x: x.reshape(row_blocks, -1, *x.shape[1:])
+            zero = (jnp.float32(0.0), jax.tree.map(jnp.zeros_like, params))
+            (loss, grads), new_stats = jax.lax.scan(
+                body, zero, jax.tree.map(split, batch))
+            new_stats = jax.tree.map(lambda x: x[-1], new_stats)
+        new_params, new_opt = steps._opt_update(cfg, params, grads, opt, lr,
+                                                count)
+        return new_params, new_stats, new_opt, loss, grads
+
+    params, stats = variables["params"], variables["batch_stats"]
+    first, opt = params, steps._opt_init(cfg, params)
+    losses, first_grad = [], None
+    with jax.default_matmul_precision("highest"):
+        for count, batch in enumerate(batches):
+            params, stats, opt, loss, grads = step(
+                params, stats, opt, batch,
+                jnp.float32(steps.learning_rate(cfg, count)),
+                jnp.float32(count))
+            losses.append(float(loss))
+            if count == 0:
+                first_grad = grads
+    return {"losses": losses, "grad": first_grad,
+            "delta": jax.tree.map(lambda a, b: a - b, params, first)}
+
+
+BF16_STATE = {**TOKEN_CONFIG, "optimizer_state_dtype": "bfloat16"}
+SGD = {**TOKEN_CONFIG, "optimizer": {"name": "sgd", "learning_rate": 0.1,
+                                     "momentum": 0.9, "weight_decay": 1e-4}}
+
+
+@pytest.mark.parametrize("cfg", [TOKEN_CONFIG, BF16_STATE, SGD],
+                         ids=["adamw", "adamw_bf16_state", "sgd"])
+@pytest.mark.parametrize("how", [{}, {"row_blocks": 2}, {"rows": 4},
+                                 {"control": True}],
+                         ids=["whole", "row_blocks", "rows_halved", "control"])
+def test_the_loop_that_consumes_its_state_reads_what_the_parents_read(cfg,
+                                                                      how):
+    pool = traffic_mod.make_pool(TOKEN_TRAFFIC, cfg, None, 7)
+    want = parents_run_steps(_ToyTokens, cfg, toy_variables(), pool, **how)
+    handed = toy_variables()
+    got = steps.run_steps(_ToyTokens, cfg, handed, pool, **how)
+    assert got["losses"] == want["losses"]  # to the bit, all of it
+    for key in ("grad", "delta"):
+        assert jax.tree.structure(got[key]) == jax.tree.structure(want[key])
+        for a, b in zip(jax.tree.leaves(got[key]), jax.tree.leaves(want[key])):
+            assert isinstance(a, jax.Array) and a.dtype == b.dtype
+            assert np.array_equal(np.asarray(a), np.asarray(b)), (key, how)
+    assert all(x.is_deleted() for x in jax.tree.leaves(handed))
+
+
+def tree_bytes(tree):
+    return sum(x.nbytes for x in jax.tree.leaves(tree))
+
+
+def alive_now():
+    return {id(x) for x in jax.live_arrays()}
+
+
+def alive_since(before):
+    return [x for x in jax.live_arrays() if id(x) not in before]
+
+
+class _Probed:
+    """A feed that reads, as each batch is asked for, the bytes of the
+    arrays then alive that were not alive when it was made."""
+
+    def __init__(self, batches):
+        self.batches, self.readings = batches, []
+        self.before = alive_now()
+
+    def __iter__(self):
+        for batch in self.batches:
+            self.readings.append(tree_bytes(alive_since(self.before)))
+            yield batch
+
+
+@pytest.mark.parametrize("cfg", [TOKEN_CONFIG, BF16_STATE],
+                         ids=["float32_state", "bfloat16_state"])
+def test_between_steps_the_device_holds_the_training_state_and_no_more(cfg):
+    """Before steps 2 and 3 the loop keeps the parameters, the optimizer's
+    state as stored and the batches; the parent's kept the first
+    parameters and the first gradient beside them, two trees more."""
+    pool = [jax.device_put(b) for b in
+            traffic_mod.make_pool(TOKEN_TRAFFIC, cfg, None, 7)]
+    params = toy_variables()["params"]
+    state = tree_bytes(params) + tree_bytes(steps._opt_init(cfg, params))
+    assert state == tree_bytes(params) * (
+        2 if "optimizer_state_dtype" in cfg else 3)
+    del params
+    readings = {}
+    for name, loop in (("change", steps.run_steps),
+                       ("parent", parents_run_steps)):
+        feed = _Probed(pool)
+        out = loop(_ToyTokens, cfg, toy_variables(), feed)
+        readings[name] = feed.readings[1:]
+        del out
+    assert all(r <= state + 1024 for r in readings["change"]), readings
+    assert all(r >= state + 2 * tree_bytes(toy_variables()["params"])
+               for r in readings["parent"]), readings
+
+
+class _AdamState(tuple):
+    _fields = ("count", "mu", "nu")
+    mu = property(lambda self: self[1])
+
+
+class _TraceState(tuple):
+    _fields = ("trace",)
+    trace = property(lambda self: self[0])
+
+
+@pytest.mark.parametrize("name,stored", [
+    ("sgd", "float32"), ("adamw", "float32"), ("adamw", "bfloat16")])
+def test_first_gradient_norms_are_the_norms_of_the_tree_never_made(name,
+                                                                   stored):
+    """Against `compare.leaf_norms` of the float32 gradient tree that the
+    parent's reading made on the device only to take its norms."""
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    shapes = {"a": (64, 48), "b": {"bias": (48,), "kernel": (3, 3, 8, 16)}}
+    draw = lambda key, scale: jax.tree.map(
+        lambda s: scale * jax.random.normal(key, s), shapes,
+        is_leaf=lambda s: isinstance(s, tuple))
+    params0 = jax.device_get(draw(keys[0], 1.0))
+    moment = jax.tree.map(lambda x: x.astype(stored), draw(keys[1], 1e-2))
+    config = {"optimizer": {"name": name, "weight_decay": 1e-2, "b1": 0.8}}
+    if name == "sgd":
+        state = (_TraceState((moment,)),)
+        tree = jax.tree.map(lambda t, p: t.astype("float32") - 1e-2 * p,
+                            moment, params0)
+    else:
+        state = (_AdamState((0, moment, None)), ())
+        tree = jax.tree.map(lambda m: m.astype("float32") / (1 - 0.8), moment)
+    got = train_adapter.first_gradient_norms(config, state, params0)
+    assert got.dtype == np.float64 and got.shape == (3,)
+    assert got == pytest.approx(compare.leaf_norms(tree), rel=1e-6)
 
 
 # -- the plain references against the program's models -----------------------
@@ -409,6 +578,9 @@ def test_rehearsal_run_is_correct(tiny_models, cell):
                                       "setup_s"}
     assert result["attempted"] > 0 and result["failed"] == 0
     assert list(result)[-1] == "compared"
+    # both peak readings, before the reference and after the comparison
+    device = result["device"]
+    assert device["memory_peak_bytes_after"] >= device["memory_peak_bytes"]
 
 
 def test_a_state_kept_in_bfloat16_is_kept_so_by_program_and_reference(
@@ -437,6 +609,42 @@ def test_a_state_kept_in_bfloat16_is_kept_so_by_program_and_reference(
     assert result["correct"], (result["compared"], result["faults"])
     for side, leaves in kept.items():
         assert leaves and all(x.dtype == jnp.bfloat16 for x in leaves), side
+
+
+@pytest.mark.parametrize("config_name,shape", [
+    ("tiny_vit_bf16state", (32, 32, 3)), ("tiny_resnet", (16, 16, 12))])
+def test_first_steps_leave_no_spare_parameter_tree_on_the_device(
+        tiny_models, config_name, shape):
+    """After `first_steps` the readings are on the host and every new array
+    of a parameter's shape lies in the memory of the trainer's own state:
+    no copy of the seeded variables, of `params0` or of a gradient is left
+    (on the CPU a fetched leaf stays alive as a view of its own buffer)."""
+    config = rehearsal_config(config_name)
+    with open(os.path.join(ROOT, "tests", "benchmark", "traffic",
+                           "b16_pool4.json")) as f:
+        traffic = json.load(f)
+    trainer, journal, _ = train_adapter.build_trainer(
+        config, traffic["global_batch"])
+    pool = traffic_mod.make_pool(traffic, config, shape, 5)
+    module = train_adapter.reference_module(config)
+    before = alive_now()
+    handed = [module.init(config, train_adapter.seed_key(5))]
+    shapes = [x.shape for x in jax.tree.leaves(handed[0]["params"])]
+    got = train_adapter.first_steps(trainer, journal, config, pool,
+                                    handed.pop())
+    try:
+        assert all(isinstance(x, np.ndarray)
+                   for x in jax.tree.leaves(got["delta"]))
+        assert [x.shape for x in jax.tree.leaves(got["delta"])] == shapes
+        assert got["grad_norms"].shape == (len(shapes),)
+        alive = [x for x in alive_since(before) if x.shape in shapes]
+        memory = lambda x: {s.data.unsafe_buffer_pointer()
+                            for s in x.addressable_shards}
+        own = set().union(*map(memory, jax.tree.leaves(trainer.state)))
+        assert len(alive) >= len(shapes)
+        assert all(memory(x) <= own for x in alive)
+    finally:
+        trainer.close()
 
 
 def _state_unchanged(impl):
