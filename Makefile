@@ -213,10 +213,10 @@ host-smoke:
 data-smoke:
 	JAX_PLATFORMS=cpu python tools/data_smoke.py --workdir artifacts/data_smoke
 
-# perf smoke: the CPU-provable proxies behind the MFU attack — fused
-# Pallas kernels (bn_act, nms) match their lax references in interpret
-# mode, a multistep=4 Trainer superstep is step-for-step equivalent to 4
-# single dispatches with 4x fewer step events and ZERO recompiles after
+# perf smoke: the CPU-provable proxies behind the MFU attack — the Pallas
+# NMS kernel matches its lax reference in interpret mode, a multistep=4
+# Trainer superstep is step-for-step equivalent to 4 single dispatches
+# with 4x fewer step events and ZERO recompiles after
 # warmup, the depth-2 device prefetcher never starves a slower consumer,
 # and check_journal --strict accepts the extended step/bench fields
 # (tools/perf_smoke.py)

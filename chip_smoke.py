@@ -16,7 +16,7 @@ ResNet-50, on every local device:
            window closed by block_until_ready.
   loader   8 record-reading worker processes started beside the live TPU
            client (they pin themselves to the CPU backend).
-  kernels  the three Pallas kernels, compiled (interpret=False), against
+  kernels  the two Pallas kernels, compiled (interpret=False), against
            their in-repo references at the shapes production uses.
   serve    Transport -> admission -> queue -> Engine with resnet50 over HTTP,
            the Engine warmed from the AOT executable store a first one filled.
@@ -59,46 +59,6 @@ def _check(ok, what: str) -> None:
 
 
 # -- train -------------------------------------------------------------------
-
-def _count_bn_routes(model, sample):
-    """(ConvBN sites asking for the fused tail, pallas_call sites traced):
-    the difference took bn_act's lax route (a channel count that does not
-    tile the 128 lanes). Counted from one abstract forward trace."""
-    import flax.linen as nn
-    import jax
-
-    from deep_vision_tpu.nn.layers import BatchNorm
-
-    asked = 0
-
-    def intercept(next_fn, args, kwargs, context):
-        nonlocal asked
-        if (isinstance(context.module, BatchNorm)
-                and context.method_name == "__call__"
-                and (context.module.act is not None
-                     or kwargs.get("residual") is not None)):
-            asked += 1
-        return next_fn(*args, **kwargs)
-
-    variables = jax.eval_shape(
-        lambda x: model.init(jax.random.PRNGKey(0), x, train=False), sample)
-
-    def forward(v, x):
-        return model.apply(v, x, train=True, mutable=["batch_stats"])
-
-    with nn.intercept_methods(intercept):
-        jaxpr = jax.make_jaxpr(forward)(variables, sample)
-
-    def count(j):
-        n = 0
-        for eqn in j.eqns:
-            n += eqn.primitive.name == "pallas_call"
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                n += count(sub)
-        return n
-
-    return asked, count(jaxpr.jaxpr)
-
 
 def phase_train(devices) -> dict:
     import jax
@@ -172,12 +132,8 @@ def phase_train(devices) -> dict:
                                              placed.data).compile()
     text = compiled.as_text()
     n_custom = text.count("tpu_custom_call")
-    _check(n_custom > 0, "no tpu_custom_call in the compiled train step: "
-                         "the fused BN kernel is not in the program")
-    asked, fused = _count_bn_routes(
-        trainer.model, jax.ShapeDtypeStruct(
-            (PER_CHIP_BATCH, *train_cli.model_input_shape(cfg)), np.float32))
-    _check(fused > 0, "no ConvBN site traced a pallas_call")
+    _check(n_custom == 0, f"{n_custom} tpu_custom_call in the compiled "
+                          "train step: ResNet's step is XLA's own fusions")
 
     inventory = costmodel.collective_inventory(text)
     by_kind: dict = {}
@@ -190,7 +146,7 @@ def phase_train(devices) -> dict:
         ar = by_kind.get("all-reduce", {"bytes": 0})["bytes"]
         _check(0.95 * grad_bytes <= ar <= 1.10 * grad_bytes,
                f"all-reduce bytes {ar} vs gradient tree {grad_bytes}")
-        # the kernel runs per shard: nothing may gather an activation
+        # BN's statistics reduce per shard: nothing may gather an activation
         big = [c for c in inventory if c["kind"] == "all-gather"
                and c["bytes"] >= 1 << 20]
         _check(not big, f"activation-sized all-gathers: {big[:3]}")
@@ -233,7 +189,6 @@ def phase_train(devices) -> dict:
         "memory_stats_device0": {k: v for k, v in stats[0].items()
                                  if isinstance(v, int)},
         "tpu_custom_calls": n_custom,
-        "convbn_fused_sites": {"pallas": fused, "lax": asked - fused},
         "collectives": by_kind, "grad_tree_bytes": grad_bytes,
     }
     _say(f"train: {json.dumps(out)}")
@@ -333,49 +288,12 @@ def phase_kernels() -> dict:
     import numpy as np
 
     from deep_vision_tpu.ops import nms as lax_nms
-    from deep_vision_tpu.ops.pallas import bn_act
     from deep_vision_tpu.ops.pallas import flash_attention as _  # noqa: F401
     from deep_vision_tpu.ops.pallas.nms import pallas_nms
 
     fa = sys.modules["deep_vision_tpu.ops.pallas.flash_attention"]
-    out: dict = {"bn_act_ms": {}, "flash_ms": {}, "nms_ms": {}}
+    out: dict = {"flash_ms": {}, "nms_ms": {}}
     key = jax.random.PRNGKey(0)
-
-    # bn_act, fwd+bwd, the five ResNet-50 stage shapes, batch 128. The
-    # cotangent is an explicit input already in the io dtype, so both sides
-    # differentiate the same function of the same numbers.
-    def bn_fwd_bwd(impl):
-        def run(x, a, b, r, g):
-            y, vjp = jax.vjp(
-                lambda x, a, b, r: impl(x, a, b, residual=r, act="relu"),
-                x, a, b, r)
-            return y, vjp(g)
-        return jax.jit(run)
-
-    kernel = bn_fwd_bwd(lambda *a, **k: bn_act.fused_scale_bias_act(
-        *a, interpret=False, **k))
-    reference = bn_fwd_bwd(bn_act.reference_scale_bias_act)
-    for hw, c in ((56, 64), (56, 256), (28, 512), (14, 1024), (7, 2048)):
-        for dtype in (jnp.float32, jnp.bfloat16):
-            ks = jax.random.split(jax.random.fold_in(key, hw * c), 5)
-            shape = (PER_CHIP_BATCH, hw, hw, c)
-            x = jax.random.normal(ks[0], shape, dtype)
-            r = jax.random.normal(ks[1], shape, dtype)
-            a = 1.0 + 0.1 * jax.random.normal(ks[2], (c,), jnp.float32)
-            b = 0.1 * jax.random.normal(ks[3], (c,), jnp.float32)
-            g = jax.random.normal(ks[4], shape, dtype)
-            (y, grads), secs = _timed(kernel, x, a, b, r, g)
-            ref_y, ref_grads = reference(x, a, b, r, g)
-            tag = f"{hw}x{hw}x{c}/{jnp.dtype(dtype).name}"
-            # one rounding of the io dtype apart, elementwise; the sums
-            # behind dscale/dbias (400k-6M f32 terms) only reorder
-            tol = 1e-5 if dtype == jnp.float32 else 1e-2
-            _close(y, ref_y, tol, f"bn_act {tag} y")
-            for name, got, want in zip(("dx", "dscale", "dbias", "dres"),
-                                       grads, ref_grads):
-                _close(got, want, tol if name in ("dx", "dres") else 1e-3,
-                       f"bn_act {tag} {name}", max_outliers=1e-5)
-            out["bn_act_ms"][tag] = round(secs * 1e3, 3)
 
     # flash attention, fwd+bwd, 12 heads x 64, bf16
     def attn_fwd_bwd(impl):

@@ -4,9 +4,9 @@ Before this module, 14 knobs were scattered across 12 files, each with
 its own parse idiom: DVT_NMS_IMPL raised on a typo (the convention worth
 keeping — a triage knob that silently no-ops defeats its purpose),
 DVT_LOCKSMITH_HOLD_MS fed `float()` raw (garbage = unhandled
-ValueError deep in `arm_from_env`), DVT_TELEMETRY warned, and
-DVT_PALLAS_FUSED treated ANY value — including the empty string — as
-truthy unless it happened to be "0"/"false"/"off". This module is the
+ValueError deep in `arm_from_env`), DVT_TELEMETRY warned, and a kernel
+switch treated ANY value — including the empty string — as truthy
+unless it happened to be "0"/"false"/"off". This module is the
 single source of truth the DV203 lint rule enforces: every `DVT_*` read
 in the tree must go through a typed helper here, and every name a
 helper is given must be declared in `KNOBS`.
@@ -132,10 +132,6 @@ KNOBS: Dict[str, Knob] = {k.name: k for k in (
        "Force the NMS selection backend (ops/nms.py); unset = auto "
        "(pallas when the backend compiles Pallas, lax elsewhere).",
        choices=("lax", "pallas")),
-    _k("DVT_PALLAS_FUSED", "flag", None,
-       "Force the fused Pallas scale/bias/act path (ops/pallas/"
-       "bn_act.py) on (1) or off (0); unset = on only when the backend "
-       "compiles Pallas."),
     _k("DVT_PREFLIGHT_BUDGET_S", "float", 60.0,
        "Per-probe time budget (seconds) for tools/preflight.py backend "
        "checks; raise it for a slow cold start."),

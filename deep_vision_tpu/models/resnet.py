@@ -30,9 +30,9 @@ class BasicBlock(nn.Module):
     def __call__(self, x, train: bool = True):
         residual = x
         y = ConvBN(self.features, (3, 3), strides=self.strides, dtype=self.dtype)(x, train)
-        # tail: BN + skip-add + ReLU fold into one pass (nn/layers.py
-        # ConvBN residual arg -> ops/pallas/bn_act.py on TPU). Constructed
-        # before the projection so flax auto-names (ConvBN_1 here, ConvBN_2
+        # tail: BN + skip-add + ReLU fold into one expression (ConvBN's
+        # residual arg -> nn/layers.scale_bias_act). Constructed before
+        # the projection so flax auto-names (ConvBN_1 here, ConvBN_2
         # for the projection) — and with them every checkpoint — keep the
         # exact pre-fusion variable-tree paths.
         tail = ConvBN(self.features, (3, 3), act=nn.relu, dtype=self.dtype)
@@ -56,9 +56,9 @@ class BottleneckBlock(nn.Module):
         # zero-init the last BN scale so each block starts as identity
         # (standard TPU ResNet recipe; improves large-batch training)
         y = nn.Conv(self.features * 4, (1, 1), use_bias=False, dtype=self.dtype)(y)
-        # the block tail — BN apply + skip-add + ReLU — is ONE fused pass on
-        # TPU (ops/pallas/bn_act.py; act/residual args on nn/layers.py
-        # BatchNorm). Constructed before the projection ConvBN so flax
+        # the block tail — BN apply + skip-add + ReLU — is one expression
+        # (nn/layers.scale_bias_act, through BatchNorm's act/residual
+        # args). Constructed before the projection ConvBN so flax
         # auto-names (BatchNorm_0, ConvBN_2) keep the pre-fusion
         # variable-tree paths and checkpoints stay interchangeable.
         bn = FusedBatchNorm(

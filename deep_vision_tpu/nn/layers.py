@@ -17,6 +17,7 @@ NHWC, TPU-native:
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional, Sequence, Tuple
 
 import flax.linen as nn
@@ -72,6 +73,91 @@ class LocalResponseNorm(nn.Module):
         return x / jnp.power(self.k + self.alpha * window, self.beta)
 
 
+def _scale_bias_act(x, scale, bias, residual, act: Optional[str]):
+    y = x.astype(jnp.float32) * scale.astype(jnp.float32) + bias.astype(
+        jnp.float32)
+    if residual is not None:
+        y = y + residual.astype(jnp.float32)
+    if act == "relu":
+        y = jnp.maximum(y, 0.0)
+    return y.astype(x.dtype)
+
+
+def _scale_bias_act_bwd(act, res, g):
+    """-> (dx, dscale, dbias, dresidual) from the saved (x, scale, bias, y)."""
+    x, scale, bias, y = res
+    gf = g.astype(jnp.float32)
+    if act == "relu":
+        gf = jnp.where(y > 0, gf, 0.0)
+    axes = tuple(range(x.ndim - 1))
+    dx = (gf * scale.astype(jnp.float32)).astype(x.dtype)
+    dscale = jnp.sum(gf * x.astype(jnp.float32), axis=axes)
+    dbias = jnp.sum(gf, axis=axes)
+    return (dx, dscale.astype(scale.dtype), dbias.astype(bias.dtype),
+            gf.astype(x.dtype))
+
+
+# one custom_vjp per arity, so `residual=None` never ships a zeros tensor
+# through HBM just to satisfy a uniform signature
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _tail3(x, scale, bias, act):
+    return _scale_bias_act(x, scale, bias, None, act)
+
+
+def _tail3_fwd(x, scale, bias, act):
+    y = _scale_bias_act(x, scale, bias, None, act)
+    return y, (x, scale, bias, y)
+
+
+_tail3.defvjp(_tail3_fwd,
+              lambda act, res, g: _scale_bias_act_bwd(act, res, g)[:3])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _tail4(x, scale, bias, residual, act):
+    return _scale_bias_act(x, scale, bias, residual, act)
+
+
+def _tail4_fwd(x, scale, bias, residual, act):
+    y = _scale_bias_act(x, scale, bias, residual, act)
+    return y, (x, scale, bias, y)
+
+
+_tail4.defvjp(_tail4_fwd, _scale_bias_act_bwd)
+
+
+def scale_bias_act(x, scale, bias, residual=None,
+                   act: Optional[str] = "relu"):
+    """y = act(x * scale + bias [+ residual]): BatchNorm's tail.
+
+    x: (..., C) in its io dtype; scale/bias: (C,), the folded BN apply
+    (scale = gamma * rsqrt(var + eps), bias = beta - mean * scale);
+    residual: x's shape or None; act: 'relu' or None. The arithmetic is
+    float32 whatever the io dtype, so bf16 activations lose nothing to the
+    folding, and it is plain jax.numpy, so XLA fuses it into its
+    neighbours in the convolutions' own layouts.
+
+    The backward is written out (custom_vjp) and not left to autodiff: it
+    masks on the saved io-dtype `y` and reduces dscale/dbias in one pass
+    over (g, x, y), where autodiff of the float32 expression keeps float32
+    tensors of the activation's size alive across the step (PERF.md §6,
+    PR 30). At y == 0 the ReLU's slope is 0.
+    """
+    if act not in ("relu", None):
+        raise ValueError(f"unsupported act {act!r}")
+    c = x.shape[-1]
+    if scale.shape != (c,) or bias.shape != (c,):
+        raise ValueError(
+            f"scale/bias must be ({c},), got {scale.shape}/{bias.shape}")
+    if residual is None:
+        return _tail3(x, scale, bias, act)
+    if residual.shape != x.shape:
+        raise ValueError(
+            f"residual shape {residual.shape} != x shape {x.shape}")
+    return _tail4(x, scale, bias, residual, act)
+
+
 class BatchNorm(nn.Module):
     """BatchNorm that never materializes the activation tensor in float32.
 
@@ -98,11 +184,8 @@ class BatchNorm(nn.Module):
     bias_init: Callable = nn.initializers.zeros
     dtype: Optional[jnp.dtype] = None  # output/compute dtype; None = x.dtype
     # act='relu' (and/or a `residual` call arg) folds the activation and the
-    # skip-add into the normalize. With the Pallas fusion enabled
-    # (ops/pallas/bn_act.fusion_enabled: TPU default, DVT_PALLAS_FUSED
-    # forces) the whole tail runs as ONE kernel pass — the big tensor
-    # crosses HBM once instead of once per op; disabled, the math is the
-    # exact pre-kernel sequence so existing numerics never drift.
+    # skip-add into the normalize: `scale_bias_act` above, one expression
+    # with a written-out backward, the same on every platform.
     act: Optional[str] = None
 
     @nn.compact
@@ -138,23 +221,10 @@ class BatchNorm(nn.Module):
         inv = scale * jax.lax.rsqrt(var + self.epsilon)
         dt = self.dtype or x.dtype
         if self.act is not None or residual is not None:
-            from deep_vision_tpu.ops.pallas import bn_act as _bn_act
-
-            if _bn_act.fusion_enabled():
-                # folded apply (x*a + b) is safe here: the kernel computes
-                # in f32 internally, so the bf16-cancellation concern below
-                # does not apply inside it
-                y = _bn_act.fused_scale_bias_act(
-                    x, inv, bias - mean * inv, residual=residual,
-                    act=self.act)
-                return y.astype(dt)
-            y = (x.astype(jnp.float32) - mean) * inv + bias
-            if residual is not None:
-                y = y + residual.astype(jnp.float32)
-            if self.act == "relu":
-                y = jnp.maximum(y, 0.0)
-            elif self.act is not None:
-                raise ValueError(f"unsupported act {self.act!r}")
+            # the folded apply (x*a + b) is safe here: the tail computes in
+            # f32, so the bf16-cancellation concern below does not apply
+            y = scale_bias_act(x, inv, bias - mean * inv, residual=residual,
+                               act=self.act)
             return y.astype(dt)
         # normalize in f32 *inside the fusion*: per-element upcast costs no
         # HBM traffic (XLA fuses the converts), and subtracting the mean
@@ -199,8 +269,7 @@ class ConvBN(nn.Module):
         )(x)
         if self.use_bn:
             # ReLU (and a skip tensor, when the caller passes one) fold into
-            # the BN apply — one fused pass on TPU (ops/pallas/bn_act.py),
-            # the identical unfused sequence elsewhere
+            # the BN apply (`scale_bias_act`)
             fuse_relu = self.act is nn.relu
             x = FusedBatchNorm(
                 use_running_average=not train,

@@ -4,8 +4,8 @@ A `pallas_call` is an opaque custom call to XLA's SPMD partitioner, and the
 TPU lowering refuses one outright in a program that spans more than one
 device ("Mosaic kernels cannot be automatically partitioned. Please wrap
 the call in a shard_map."). Every kernel in this package is independent
-along its leading (batch) dimension — bn_act is row-wise, flash attention
-and NMS are per-image — so the wrap is exact: each device runs the kernel
+along its leading (batch) dimension — flash attention and NMS are
+per-image — so the wrap is exact: each device runs the kernel
 on the rows it already holds and nothing moves.
 
 The mesh comes from JAX's own context (`jax.set_mesh`, which the trainers
@@ -34,12 +34,6 @@ def _context_mesh():
             f"Pallas kernels partition over the {DATA_AXIS!r} mesh axis; "
             f"the context mesh has {mesh.axis_names}")
     return mesh
-
-
-def data_shards() -> int:
-    """How many ways the context mesh splits a batch (1 with no mesh)."""
-    mesh = _context_mesh()
-    return 1 if mesh is None else mesh.shape[DATA_AXIS]
 
 
 def over_data_axis(kernel: Callable, batched: Sequence[bool]) -> Callable:

@@ -447,12 +447,18 @@ class Rendezvous:
         ref = self._reference_member(members)
         if ref is None:
             return {}
+        ref_score = self._compat_score(ref, members)
         out = {}
         for h, r in members.items():
             ok, detail = versions_compatible(r, ref)
             if ok:
                 out[h] = r
-            elif not os.path.exists(self._refusal_path(h)):
+            elif (ref_score > self._compat_score(r, members)
+                  and not os.path.exists(self._refusal_path(h))):
+                # a marker only on a STRICT majority: a reference that won
+                # the earliest-joiner tiebreak leaves the member out of
+                # this pass's world, but a marker would end the grace
+                # window `_check_admission` gives that very tie
                 _atomic_write(self._refusal_path(h), {
                     "host": h, "kind": REFUSAL_VERSION_SKEW,
                     "detail": detail,

@@ -279,8 +279,8 @@ def bench_position(bench: dict, analytic: dict) -> dict:
     # per-layer placement from the analytic shape model: no achieved rate
     # per layer (the profile has no per-op split on this backend), but the
     # intensity says which kernels even CAN go fast — the low-intensity
-    # rows are the fusion targets (ops/pallas/bn_act.py), the high ones
-    # the MXU-occupancy targets
+    # rows are the fusion targets (fewer HBM bytes), the high ones the
+    # MXU-occupancy targets
     for layer in analytic.get("top_layers", []):
         if layer.get("gb"):
             rows.append(row(layer["layer"], None,

@@ -468,9 +468,9 @@ class TestKnobs:
         monkeypatch.setenv("DVT_NMS_IMPL", "LAX")  # no normalization
         with pytest.raises(knobs.KnobError, match="DVT_NMS_IMPL"):
             knobs.get_choice("DVT_NMS_IMPL")
-        monkeypatch.setenv("DVT_PALLAS_FUSED", "maybe")
-        with pytest.raises(knobs.KnobError, match="DVT_PALLAS_FUSED"):
-            knobs.get_flag("DVT_PALLAS_FUSED")
+        monkeypatch.setenv("DVT_LOCKSMITH", "maybe")
+        with pytest.raises(knobs.KnobError, match="DVT_LOCKSMITH"):
+            knobs.get_flag("DVT_LOCKSMITH")
 
     def test_unregistered_and_wrong_kind_raise(self):
         with pytest.raises(knobs.KnobError, match="not a registered"):

@@ -1,7 +1,8 @@
-"""Perf layer: fused Pallas kernels, scan-multistep Trainer, device
-prefetch, bf16 optimizer state, roofline bench anchoring.
+"""Perf layer: the Pallas NMS kernel, scan-multistep Trainer, device
+prefetch, bf16 optimizer state, roofline bench anchoring. (BatchNorm's
+fused tail is plain jax.numpy: tests/test_bn_tail.py.)
 
-Kernel tests run the REAL Pallas kernels under interpret=True (the same
+Kernel tests run the REAL Pallas kernel under interpret=True (the same
 code path the TPU compiles), against pure-lax references. Multistep tests
 prove the one-dispatch-per-K-steps contract the on-TPU bench banks on.
 """
@@ -12,93 +13,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-from deep_vision_tpu.ops.pallas.bn_act import (
-    fused_scale_bias_act,
-    reference_scale_bias_act,
-)
-
-
-def _xab(c, shape=(2, 4, 4), seed=0, dtype=np.float32):
-    rng = np.random.RandomState(seed)
-    x = jnp.asarray(rng.randn(*shape, c).astype(dtype))
-    a = jnp.asarray((rng.rand(c) + 0.5).astype(np.float32))
-    b = jnp.asarray(rng.randn(c).astype(np.float32))
-    return x, a, b
-
-
-# -- fused scale-bias-act kernel --------------------------------------------
-
-@pytest.mark.parametrize("c", [64, 128, 256])  # 64: lane-tiled, others direct
-@pytest.mark.parametrize("act", ["relu", None])
-def test_bn_act_forward_parity(c, act):
-    x, a, b = _xab(c)
-    got = fused_scale_bias_act(x, a, b, act=act, interpret=True)
-    want = reference_scale_bias_act(x, a, b, act=act)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-6, atol=1e-6)
-
-
-def test_bn_act_residual_parity():
-    x, a, b = _xab(128)
-    r = jnp.asarray(np.random.RandomState(1).randn(*x.shape).astype(np.float32))
-    got = fused_scale_bias_act(x, a, b, residual=r, act="relu",
-                               interpret=True)
-    want = reference_scale_bias_act(x, a, b, residual=r, act="relu")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-6, atol=1e-6)
-
-
-def test_bn_act_grads_match_reference():
-    x, a, b = _xab(128, shape=(2, 4, 4), seed=2)
-    r = jnp.asarray(np.random.RandomState(3).randn(*x.shape).astype(np.float32))
-
-    def f(fn):
-        return lambda x, a, b, r: jnp.sum(
-            fn(x, a, b, residual=r, act="relu") ** 2)
-
-    g1 = jax.grad(f(lambda *args, **kw: fused_scale_bias_act(
-        *args, interpret=True, **kw)), argnums=(0, 1, 2, 3))(x, a, b, r)
-    g2 = jax.grad(f(reference_scale_bias_act), argnums=(0, 1, 2, 3))(x, a, b, r)
-    for u, v, name in zip(g1, g2, ("x", "scale", "bias", "residual")):
-        np.testing.assert_allclose(np.asarray(u), np.asarray(v),
-                                   rtol=2e-5, atol=2e-5, err_msg=name)
-
-
-def test_bn_act_bf16_io_keeps_dtype():
-    x, a, b = _xab(128, dtype=np.float32)
-    x = x.astype(jnp.bfloat16)
-    got = fused_scale_bias_act(x, a, b, act="relu", interpret=True)
-    assert got.dtype == jnp.bfloat16
-    want = reference_scale_bias_act(x, a, b, act="relu")
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=2e-2, atol=2e-2)
-
-
-def test_bn_act_awkward_channels_fall_back():
-    # 96 neither divides nor is divided by 128: lax fallback, same contract
-    x, a, b = _xab(96)
-    got = fused_scale_bias_act(x, a, b, act="relu", interpret=True)
-    want = reference_scale_bias_act(x, a, b, act="relu")
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_fused_flag_resnet_block_forward_close(monkeypatch):
-    """A real BottleneckBlock forward with the fusion forced on must match
-    the unfused default path (tolerance: one fused-vs-sequential rounding)."""
-    from deep_vision_tpu.models import get_model
-
-    m = get_model("resnet50", num_classes=8)
-    x = jnp.asarray(np.random.RandomState(0).rand(2, 32, 32, 3)
-                    .astype(np.float32))
-    v = m.init(jax.random.PRNGKey(0), x, train=False)
-    monkeypatch.setenv("DVT_PALLAS_FUSED", "0")
-    want = m.apply(v, x, train=False)
-    monkeypatch.setenv("DVT_PALLAS_FUSED", "1")
-    got = m.apply(v, x, train=False)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-5)
 
 
 # -- pallas NMS -------------------------------------------------------------

@@ -1,0 +1,87 @@
+"""tools/step_bytes.py: the count of a step's HBM bytes by kind of op, on a
+small written-out program (the real ones compile for a minute or two and
+are read in PERF.md §5)."""
+import importlib.util
+import os
+
+import pytest
+
+_spec = importlib.util.spec_from_file_location(
+    "step_bytes", os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "step_bytes.py"))
+step_bytes = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(step_bytes)
+
+HLO = """HloModule jit_step, is_scheduled=true
+
+%fused_conv (p0: bf16[8,4,4,128], p1: f32[1,1,64,64]) -> bf16[8,4,4,128] {
+  %p0 = bf16[8,4,4,128]{3,0,2,1} parameter(0)
+  %p1 = f32[1,1,64,64]{3,2,1,0} parameter(1)
+  ROOT %convolution.1 = bf16[8,4,4,128]{3,0,2,1} convolution(%p0, %p1), window={size=1x1}
+}
+
+%fused_sum (p0: bf16[8,4,4,128]) -> f32[64] {
+  %p0 = bf16[8,4,4,128]{3,0,2,1} parameter(0)
+  ROOT %reduce.1 = f32[64]{0} reduce(%p0), dimensions={0,1,2}
+}
+
+%fused_relu (p0: bf16[8,4,4,128]) -> bf16[8,4,4,128] {
+  %p0 = bf16[8,4,4,128]{3,0,2,1} parameter(0)
+  ROOT %maximum.1 = bf16[8,4,4,128]{3,0,2,1} maximum(%p0, %p0)
+}
+
+%fused_sgd (p0: f32[1,1,64,64], p1: f32[1,1,64,64]) -> f32[1,1,64,64] {
+  %p0 = f32[1,1,64,64]{3,2,1,0} parameter(0)
+  %p1 = f32[1,1,64,64]{3,2,1,0} parameter(1)
+  ROOT %subtract.1 = f32[1,1,64,64]{3,2,1,0} subtract(%p0, %p1)
+}
+
+ENTRY %main.1 (x: bf16[8,4,4,128], w: f32[1,1,64,64]) -> (bf16[8,4,4,128], f32[1,1,64,64]) {
+  %x = bf16[8,4,4,128]{3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %w = f32[1,1,64,64]{3,2,1,0:T(8,128)} parameter(1)
+  %copy.1 = bf16[8,4,4,128]{3,0,2,1:T(8,128)(2,1)} copy(%x)
+  %fusion.1 = bf16[8,4,4,128]{3,0,2,1:T(8,128)(2,1)} fusion(%copy.1, %w), kind=kOutput, calls=%fused_conv
+  %copy-start.1 = (bf16[8,4,4,128]{3,0,2,1:T(8,128)(2,1)S(1)}, bf16[8,4,4,128]{3,0,2,1:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%fusion.1)
+  %copy-done.1 = bf16[8,4,4,128]{3,0,2,1:T(8,128)(2,1)S(1)} copy-done(%copy-start.1)
+  %fusion.2 = f32[64]{0:T(128)} fusion(%copy-done.1), kind=kLoop, calls=%fused_sum
+  %bn_tail.3 = bf16[128,128]{1,0:T(8,128)(2,1)} custom-call(%fusion.1), custom_call_target="tpu_custom_call"
+  %custom-call.4 = bf16[8,4,4,128]{3,0,2,1:T(8,128)(2,1)} custom-call(%fusion.1), custom_call_target="ConcatBitcast"
+  %fusion.3 = bf16[8,4,4,128]{3,0,2,1:T(8,128)(2,1)} fusion(%fusion.1), kind=kLoop, calls=%fused_relu
+  %fusion.4 = f32[1,1,64,64]{3,2,1,0:T(8,128)} fusion(%w, %w), kind=kLoop, calls=%fused_sgd
+  %all-reduce.5 = f32[64]{0:T(128)} all-reduce(%fusion.2), replica_groups={}
+  ROOT %tuple.1 = (bf16[8,4,4,128]{3,0,2,1:T(8,128)(2,1)}, f32[1,1,64,64]{3,2,1,0:T(8,128)}) tuple(%fusion.3, %fusion.4)
+}
+"""
+ACT = 8 * 4 * 4 * 128 * 2  # the activation, bf16
+W = 64 * 64 * 4
+
+
+@pytest.mark.parametrize("name, kind, moved", [
+    ("copy.1", "copy", 2 * ACT),
+    ("fusion.1", "convolution fusion", 2 * ACT + W),
+    ("copy-start.1", "async copy/slice", ACT + 4),  # once, and its context
+    ("fusion.2", "reduction fusion", ACT + 64 * 4),
+    ("bn_tail.3", "custom call", 2 * ACT),
+    ("fusion.3", "elementwise fusion", 2 * ACT),
+    ("fusion.4", "optimizer", 3 * W),
+    ("all-reduce.5", "all-reduce", 2 * 64 * 4),
+])
+def test_each_instruction_is_charged_its_operands_and_result(
+        name, kind, moved):
+    assert step_bytes.classify(HLO, {W})[name] == (kind, moved)
+
+
+def test_what_moves_nothing_is_left_out_and_traced_time_joins_by_name():
+    got = step_bytes.classify(HLO, {W})
+    for free in ("x", "w", "tuple.1", "copy-done.1", "custom-call.4"):
+        assert free not in got
+    seconds = {"fusion.1": 2e-3, "copy-done.1": 1e-3, "copy-start.1": 1e-5,
+               "fusion.999": 5e-4}
+    table = step_bytes.table(got, seconds)
+    assert table["kinds"]["convolution fusion"] == {
+        "ops": 1, "gb": (2 * ACT + W) / 1e9, "ms": 2.0}
+    assert table["kinds"]["async copy/slice"]["ms"] == pytest.approx(1.01)
+    assert table["unmatched_ms"] == pytest.approx(0.5)  # another program's
+    assert "reshape/transpose" not in table["kinds"]
+    assert table["largest"][0] == [
+        "fusion.1", "convolution fusion", (2 * ACT + W) / 1e6, 2.0]

@@ -7,16 +7,22 @@ fail here. The second half goes further where libtpu is installed: a
 compile-only v5e topology runs the real Mosaic and XLA:TPU compilers, so
 running out of VMEM fails here too. Only execution (numbers, times) is left
 to chip_smoke.py. Shapes are the production ones chip_smoke.py runs.
+
+BatchNorm's tail was such a kernel until PR 30 and is plain jax.numpy now
+(tests/test_bn_tail.py); the last test here holds what that bought: a
+ResNet block compiled for the v5e has no custom call and no layout copy
+of an activation.
 """
+import math
 import os
 import subprocess
 import sys
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import pytest
 
-from deep_vision_tpu.ops.pallas.bn_act import fused_scale_bias_act
 from deep_vision_tpu.ops.pallas.flash_attention import flash_attention
 from deep_vision_tpu.ops.pallas.nms import pallas_nms
 
@@ -29,22 +35,6 @@ def lower_for_tpu(fn, *specs):
         lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text  # the kernel is in the program, compiled
     return text
-
-
-def _bn_fwd_bwd(x, a, b, r):
-    def loss(x, a, b, r):
-        y = fused_scale_bias_act(x, a, b, residual=r, interpret=False)
-        return jnp.sum(y.astype(jnp.float32))
-    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(x, a, b, r)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("hw,c", [(56, 64), (56, 256), (28, 512),
-                                  (14, 1024), (7, 2048)])
-def test_bn_act_lowers_at_resnet50_stage_shapes(hw, c, dtype):
-    x = S((128, hw, hw, c), dtype)
-    p = S((c,), jnp.float32)
-    lower_for_tpu(_bn_fwd_bwd, x, p, p, x)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -69,13 +59,6 @@ def test_nms_lowers_at_yolo_scale(batch):
         S((batch, 10647, 4), jnp.float32), S((batch, 10647), jnp.float32))
 
 
-def _bn_no_residual(x, a, b):
-    def loss(x, a, b):
-        y = fused_scale_bias_act(x, a, b, interpret=False)
-        return jnp.sum(y.astype(jnp.float32))
-    return jax.value_and_grad(loss, argnums=(0, 1, 2))(x, a, b)
-
-
 def _flash_fwd_bwd(q, k, v):
     def loss(q, k, v):
         return jnp.sum(flash_attention(q, k, v, interpret=False)
@@ -83,14 +66,10 @@ def _flash_fwd_bwd(q, k, v):
     return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
-_X = S((128, 56, 56, 64), jnp.bfloat16)
-_P = S((64,), jnp.float32)
 _Q = S((2, 1024, 12, 64), jnp.bfloat16)
 
 
 @pytest.mark.parametrize("name, fn, specs", [
-    ("bn_act_fwd", _bn_no_residual, (_X, _P, _P)),
-    ("bn_act_res_fwd", _bn_fwd_bwd, (_X, _P, _P, _X)),
     ("flash_fwd", _flash_fwd_bwd, (_Q, _Q, _Q)),
     ("flash_bwd_dq", _flash_fwd_bwd, (_Q, _Q, _Q)),
     ("flash_bwd_dkv", _flash_fwd_bwd, (_Q, _Q, _Q)),
@@ -112,14 +91,12 @@ def test_kernel_in_a_multi_device_program_needs_the_mesh_context(mesh8):
     kernel runs per data-axis shard instead (ops/pallas/partition.py)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    rows = NamedSharding(mesh8, P("data"))
-    rep = NamedSharding(mesh8, P())
-    x = S((128, 7, 7, 2048), jnp.float32, sharding=rows)
-    p = S((2048,), jnp.float32, sharding=rep)
+    q = S((8, 1024, 12, 64), jnp.bfloat16,
+          sharding=NamedSharding(mesh8, P("data")))
     with pytest.raises(NotImplementedError, match="shard_map"):
-        lower_for_tpu(_bn_fwd_bwd, x, p, p, x)
+        lower_for_tpu(_flash_fwd_bwd, q, q, q)
     with jax.set_mesh(mesh8):
-        text = lower_for_tpu(_bn_fwd_bwd, x, p, p, x)
+        text = lower_for_tpu(_flash_fwd_bwd, q, q, q)
     assert "all-gather" not in text and "all_gather" not in text
 
 
@@ -156,46 +133,6 @@ def _sharded(mesh, *arrays):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     return [jax.device_put(a, NamedSharding(mesh, P("data"))) for a in arrays]
-
-
-def test_bn_act_per_shard_matches_reference(mesh8):
-    import numpy as np
-
-    from deep_vision_tpu.ops.pallas.bn_act import reference_scale_bias_act
-
-    rng = np.random.RandomState(0)
-    x, r = _sharded(mesh8, *(rng.randn(16, 4, 4, 128).astype(np.float32)
-                             for _ in range(2)))
-    a = jnp.asarray(rng.rand(128).astype(np.float32) + 0.5)
-    b = jnp.asarray(rng.randn(128).astype(np.float32))
-
-    def loss(impl):
-        return lambda x, a, b, r: jnp.sum(
-            impl(x, a, b, residual=r, act="relu") ** 2)
-
-    fused = jax.jit(jax.value_and_grad(loss(
-        lambda *args, **kw: fused_scale_bias_act(*args, interpret=True, **kw)),
-        argnums=(0, 1, 2, 3)))
-    with jax.set_mesh(mesh8):
-        assert "shard_map" in str(jax.make_jaxpr(fused)(x, a, b, r))
-        got = fused(x, a, b, r)
-    want = jax.value_and_grad(loss(reference_scale_bias_act),
-                              argnums=(0, 1, 2, 3))(x, a, b, r)
-    for u, v in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_allclose(np.asarray(u), np.asarray(v),
-                                   rtol=2e-5, atol=2e-5)
-
-
-def test_bn_act_takes_the_lax_route_when_a_shard_does_not_tile(mesh8):
-    # 8 rows of 64 channels over 8 shards: one shard is 64 elements, half a
-    # lane row — the shape rule sends it to the reference, never a gather
-    x = jnp.ones((8, 1, 1, 64), jnp.float32)
-    p = jnp.ones((64,), jnp.float32)
-    with jax.set_mesh(mesh8):
-        jaxpr = str(jax.make_jaxpr(lambda x, a, b: fused_scale_bias_act(
-            x, a, b, interpret=True))(x, p, p))
-    assert "pallas_call" not in jaxpr and "shard_map" not in jaxpr
 
 
 def test_flash_and_nms_per_shard_match_their_references(mesh8):
@@ -262,31 +199,6 @@ def compile_for_v5e(device, fn, *specs):
     return jax.jit(fn).lower(*specs).compile()
 
 
-@pytest.mark.parametrize("hw,c,dtype", [(56, 64, jnp.bfloat16),
-                                        (56, 256, jnp.float32),
-                                        (7, 2048, jnp.float32),
-                                        (7, 2048, jnp.bfloat16)])
-def test_bn_act_blocks_fit_vmem(v5e, hw, c, dtype):
-    """Block rows come from the element budget, not a fixed 256: the widest
-    f32 stage and the narrowest bf16 one both fit the scoped-VMEM default
-    (a 1 Mi-element block does not — the compiler says so here)."""
-    x = S((128, hw, hw, c), dtype)
-    p = S((c,), jnp.float32)
-    compile_for_v5e(v5e, _bn_fwd_bwd, x, p, p, x)
-
-
-def test_bn_act_block_over_budget_is_refused_by_the_compiler(
-        v5e, monkeypatch):
-    from deep_vision_tpu.ops.pallas import bn_act
-
-    monkeypatch.setattr(bn_act, "_BLOCK_ELEMS", 4 * 1024 * 1024)
-    x = S((128, 7, 7, 2048), jnp.float32)
-    p = S((2048,), jnp.float32)
-    with pytest.raises(Exception, match="vmem"):
-        # a fresh function: jit would hand back the cached lowering
-        compile_for_v5e(v5e, lambda *args: _bn_fwd_bwd(*args), x, p, p, x)
-
-
 def test_nms_and_flash_compile_for_v5e(v5e):
     compile_for_v5e(
         v5e, lambda boxes, scores: pallas_nms(boxes, scores, 100, 0.5, 0.5,
@@ -300,3 +212,47 @@ def test_nms_and_flash_compile_for_v5e(v5e):
 
     q = S((1, 4096, 12, 64), jnp.bfloat16)
     compile_for_v5e(v5e, fwd_bwd, q, q, q)
+
+
+@pytest.mark.parametrize("hw, c", [(56, 256), (7, 2048)])
+def test_resnet_blocks_compile_to_xla_fusions_alone(v5e, hw, c):
+    """A convolution and two identity bottlenecks, forward and backward,
+    bf16, batch 128: every activation has a convolution before and after
+    it, as in the model. BatchNorm's tail is XLA's to fuse in the
+    convolutions' layouts: no `tpu_custom_call`, and no `copy`, `reshape`
+    or `transpose` of its own that writes a tensor of the activation's
+    size (the Pallas tail cost two or three per site, PERF.md §5)."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from deep_vision_tpu.models.resnet import BottleneckBlock
+
+    class Net(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            x = nn.Conv(c, (1, 1), dtype=jnp.bfloat16)(x)
+            for _ in range(2):
+                x = BottleneckBlock(c // 4, dtype=jnp.bfloat16)(x, True)
+            return x
+
+    x = S((128, hw, hw, 16), jnp.bfloat16)
+    variables = jax.eval_shape(Net().init, jax.random.PRNGKey(0), x)
+
+    def fwd_bwd(variables, x):
+        def loss(params):
+            y, _ = Net().apply({**variables, "params": params}, x,
+                               mutable=["batch_stats"])
+            return jnp.sum(y.astype(jnp.float32))
+        return jax.value_and_grad(loss)(variables["params"])
+
+    here = SingleDeviceSharding(v5e)
+    specs = jax.tree.map(lambda s: S(s.shape, s.dtype, sharding=here),
+                         (variables, x))
+    text = jax.jit(fwd_bwd).lower(*specs).compile().as_text()
+    assert "tpu_custom_call" not in text
+    entry = text[text.index("\nENTRY "):]
+    moved = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* (?:copy|reshape|transpose)\(", entry)
+        if math.prod(map(int, m.group(1).split(","))) >= 128 * hw * hw * c]
+    assert not moved, moved

@@ -9,11 +9,10 @@ resilience/. The on-TPU acceptance for this arc is a bench delta
 (vs_baseline >= 1.0 wall, mfu_device_pct >= 40); these are the proxies
 that must hold on ANY backend before that bench is even worth running:
 
-  1. fused kernels   ops/pallas/bn_act.py (scale-bias+ReLU+residual) and
-                     ops/pallas/nms.py run under interpret=True and must
-                     match their pure-lax references — values AND grads
-                     for bn_act, exact index/score agreement for NMS
-                     through the full class-aware non_maximum_suppression.
+  1. fused kernels   ops/pallas/nms.py runs under interpret=True and must
+                     match its pure-lax reference: exact index/score
+                     agreement through the full class-aware
+                     non_maximum_suppression.
   2. multistep       a Trainer(multistep=4) superstep over 4 stacked
                      batches must land within float-ulp of 4 single-step
                      dispatches (same params, same per-microstep losses),
@@ -56,40 +55,12 @@ class Failures:
 
 
 def phase1_fused_kernels(f: Failures):
-    import jax
     import jax.numpy as jnp
     import numpy as np
 
     from deep_vision_tpu.ops.nms import non_maximum_suppression
-    from deep_vision_tpu.ops.pallas.bn_act import (
-        fused_scale_bias_act,
-        reference_scale_bias_act,
-    )
 
     rng = np.random.RandomState(0)
-    x = jnp.asarray(rng.randn(2, 8, 8, 128).astype(np.float32))
-    res = jnp.asarray(rng.randn(2, 8, 8, 128).astype(np.float32))
-    a = jnp.asarray(rng.rand(128).astype(np.float32) + 0.5)
-    b = jnp.asarray(rng.randn(128).astype(np.float32))
-    got = fused_scale_bias_act(x, a, b, residual=res, act="relu",
-                               interpret=True)
-    want = reference_scale_bias_act(x, a, b, residual=res, act="relu")
-    f.check(np.allclose(np.asarray(got), np.asarray(want), atol=1e-6),
-            "bn_act: fused fwd matches lax reference")
-
-    def loss_f(fn):
-        return lambda *args: jnp.sum(
-            fn(args[0], args[1], args[2], residual=args[3], act="relu") ** 2)
-
-    g1 = jax.grad(loss_f(fused_scale_bias_act), argnums=(0, 1, 2, 3))(
-        x, a, b, res)
-    g2 = jax.grad(loss_f(reference_scale_bias_act), argnums=(0, 1, 2, 3))(
-        x, a, b, res)
-    ok = all(np.allclose(np.asarray(u), np.asarray(v), atol=2e-5)
-             for u, v in zip(g1, g2))
-    f.check(ok, "bn_act: custom-vjp grads match lax reference (x, scale, "
-                "bias, residual)")
-
     xy = rng.rand(2, 300, 2).astype(np.float32) * 0.8
     wh = rng.rand(2, 300, 2).astype(np.float32) * 0.2 + 0.02
     boxes = jnp.asarray(np.concatenate([xy, xy + wh], -1))

@@ -1,0 +1,281 @@
+#!/usr/bin/env python3
+"""Bytes a training step moves through HBM, by kind of op, with no chip.
+
+    JAX_PLATFORMS=cpu python tools/step_bytes.py --workload resnet50_train_b128
+    ... --ops chiprun_out/<dir>/ops.json   # + traced ms by kind, from a chip
+
+The step program of a benchmark cell (`BENCHMARK.json`), built the way the
+cell builds it (`benchmark/adapters/train.build_trainer`, the Trainer's own
+`_train_step_impl`, state donated), is compiled for a described v5e
+(`jax.experimental.topologies`, PERF.md §5's recipe): the program and its
+op names are the chip's. Every instruction of the optimized entry
+computation is then charged its operands' bytes plus its result's, and
+summed by kind. A step bound by HBM bandwidth takes that sum over 819 GB/s
+(PERF.md §5); `artifacts/roofline_r05.json` reckons 14.5 GB as ResNet-50's
+floor at batch 128. Nothing runs: no time comes from here. `--ops` takes a
+chip's traced table {op name: seconds a step} (`--dump-ops`, on the chip,
+writes one) and lays its milliseconds beside the bytes, by the same kinds.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import math
+import os
+import re
+import sys
+import time
+from collections import defaultdict
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+KINDS = ("convolution fusion", "reduction fusion", "elementwise fusion",
+         "copy", "reshape/transpose", "custom call", "select-and-scatter",
+         "optimizer", "all-reduce", "async copy/slice", "other")
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+                "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+                "u64": 8}
+_SHAPE = re.compile(r"\b(" + "|".join(_DTYPE_BYTES) + r")\[([\d,]*)\]")
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$")
+# no time and no traffic of their own
+_FREE = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+         "after-all", "partition-id", "replica-id", "iota"}
+
+
+def shapes_bytes(text: str) -> list:
+    """Bytes of each array shape written in `text`, in order."""
+    return [_DTYPE_BYTES[d] * math.prod(int(n) for n in dims.split(",") if n)
+            for d, dims in _SHAPE.findall(text)]
+
+
+def computations(hlo: str) -> dict:
+    """{computation name: its instruction lines}; the entry's under
+    "ENTRY"."""
+    out, name = {}, None
+    for line in hlo.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            head = line.split()
+            name = "ENTRY" if head[0] == "ENTRY" else head[0].lstrip("%")
+            out[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+def _opcodes(lines) -> set:
+    return {m.group(3) for m in map(_INSTR.match, lines) if m}
+
+
+def classify(hlo: str, param_bytes: set = frozenset()) -> dict:
+    """{instruction of the entry computation: (kind, bytes)}. A fusion is a
+    convolution fusion if a convolution is inside, else a reduction fusion
+    if a reduce is, else elementwise; one whose every big operand and
+    result has a parameter's size (`param_bytes`) is the optimizer's."""
+    comps = computations(hlo)
+    sizes, out = {}, {}
+    for line in comps["ENTRY"]:
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, result, opcode, rest = m.groups()
+        parts = shapes_bytes(result)
+        sizes[name] = sum(parts)
+        if opcode in _FREE:
+            continue
+        operands = re.findall(r"%([\w.\-]+)", rest.split("), ", 1)[0])
+        moved = sizes[name] + sum(sizes.get(o, 0) for o in operands)
+        if opcode.endswith("-done"):
+            continue  # charged at its -start
+        if opcode in ("copy-start", "slice-start"):
+            # to or from the chip's near memory (`S(1)` in a layout): the
+            # destination crosses HBM once. The start's result repeats its
+            # operand beside the destination.
+            moved = sizes[name] - sum(sizes.get(o, 0) for o in operands)
+            kind = "async copy/slice"
+        elif opcode.endswith("-start"):
+            kind = "all-reduce"
+        elif opcode == "fusion":
+            called = re.search(r"calls=%([\w.\-]+)", rest)
+            inside = _opcodes(comps.get(called.group(1), [])) if called else ()
+            big = [sizes.get(o, 0) for o in operands] + parts
+            if "convolution" in inside:
+                kind = "convolution fusion"
+            elif param_bytes and all(
+                    b in param_bytes or b <= 4096 for b in big):
+                kind = "optimizer"
+            elif "reduce" in inside or "reduce-window" in inside:
+                kind = "reduction fusion"
+            else:
+                kind = "elementwise fusion"
+        elif opcode == "copy":
+            kind = "copy"
+        elif opcode in ("reshape", "transpose"):
+            kind = "reshape/transpose"
+        elif opcode == "custom-call":
+            if "tpu_custom_call" not in rest:
+                continue  # ConcatBitcast and the like: no pass over HBM
+            kind = "custom call"
+        elif opcode == "select-and-scatter":
+            kind = "select-and-scatter"
+        elif opcode in ("all-reduce", "all-gather", "reduce-scatter"):
+            kind = "all-reduce"
+        else:
+            kind = "other"
+        out[name] = (kind, moved)
+    return out
+
+
+def table(classified: dict, op_seconds: dict | None = None,
+          largest: int = 12) -> dict:
+    """-> {"kinds": {kind: {"ops", "gb", "ms"}}, "largest": the ops that
+    move most, as [name, kind, MB, ms], "unmatched_ms": the traced time of
+    ops this program does not have (another program was traced)}."""
+    rows = {k: {"ops": 0, "gb": 0.0, "ms": 0.0} for k in KINDS}
+    for kind, moved in classified.values():
+        rows[kind]["ops"] += 1
+        rows[kind]["gb"] += moved / 1e9
+    unmatched, op_ms = 0.0, defaultdict(float)
+    for name, seconds in (op_seconds or {}).items():
+        # an async pair's time is its -done's wait; the -start is an issue
+        base = re.sub(r"-done(\.\d+)?$", r"-start\1", name)
+        if base in classified:
+            op_ms[base] += seconds * 1e3
+            rows[classified[base][0]]["ms"] += seconds * 1e3
+        else:
+            unmatched += seconds * 1e3
+    top = sorted(classified, key=lambda n: -classified[n][1])[:largest]
+    return {"kinds": {k: v for k, v in rows.items() if v["ops"]},
+            "largest": [[n, classified[n][0], classified[n][1] / 1e6,
+                         op_ms.get(n, 0.0)] for n in top],
+            "unmatched_ms": unmatched}
+
+
+def _described_mesh(like):
+    """The described v5e devices, laid out as the mesh `like`."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    devices = np.array(topo.devices[:like.size]).reshape(like.devices.shape)
+    return Mesh(devices, like.axis_names)
+
+
+def _cell(workload: str, manifest_path: str):
+    """-> (cell, config, traffic, the configuration's adapter module)."""
+    from benchmark import run as bench
+
+    manifest = bench.load_manifest(manifest_path)
+    cell, config, traffic = bench.resolve(manifest, workload)
+    adapter = bench.load_py(bench.find_file(manifest, "adapters",
+                                            config["kind"] + ".py"))
+    return cell, config, traffic, adapter
+
+
+def compile_step(workload: str, manifest_path: str):
+    """-> (the compiled step of the cell, the byte sizes of its parameter
+    leaves)."""
+    cell, config, traffic, adapter = _cell(workload, manifest_path)
+    if cell["chips"] > 1:
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={cell['chips']}")
+    import jax
+    import numpy as np
+    from jax.sharding import NamedSharding, SingleDeviceSharding
+
+    from deep_vision_tpu.core import backend
+
+    trainer, _, image_shape = adapter.build_trainer(
+        config, traffic["global_batch"])
+    n = traffic["global_batch"]
+    batch = trainer._place_one({
+        "image": np.zeros((n, *image_shape), np.float32),
+        "label": np.zeros((n,), np.int32)}).data
+    mesh = _described_mesh(trainer.mesh)
+
+    def described(x):
+        spec = getattr(x.sharding, "spec", None)
+        where = (NamedSharding(mesh, spec) if spec is not None
+                 else SingleDeviceSharding(mesh.devices.flat[0]))
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=where)
+
+    state, batch = jax.tree.map(described, (trainer.state, batch))
+    # built here on the CPU; traced as on the chip, where a kernel compiles
+    backend.current_platform = lambda: "tpu"
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(trainer._train_step_impl, donate_argnums=0).lower(
+            state, batch).compile()
+    leaves = {x.size * x.dtype.itemsize
+              for x in jax.tree.leaves(trainer.state.params)}
+    return compiled, leaves
+
+
+def dump_ops(workload, manifest_path, seed, seconds, out):
+    """On the chip: one traced run of the cell, as `benchmark/run.py
+    --trace 1` makes it, and the whole op table {name: seconds a step}
+    (the result line keeps ten) written to `out`."""
+    import jax
+
+    from deep_vision_tpu.core import excache
+
+    excache.place_compile_cache()  # the cell's own cache, as run.py sets it
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cell, config, traffic, adapter = _cell(workload, manifest_path)
+    record = adapter.run(cell, config, traffic, seed, seconds, True,
+                         time.time())
+    red = record["trace"]
+    os.makedirs(os.path.dirname(os.path.abspath(out)), exist_ok=True)
+    with open(out, "w") as f:
+        json.dump({"workload": workload, "seed": seed,
+                   "correct": bool(record["correct"]),
+                   "step_device_ms": red["step_device_ms"],
+                   "periods": red["periods"],
+                   "op_s_per_step": red["op_s_per_step"]}, f)
+    print(f"wrote {len(red['op_s_per_step'])} ops to {out}")
+
+
+def main(argv=None):
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--workload", required=True)
+    parser.add_argument("--manifest",
+                        default=os.path.join(ROOT, "BENCHMARK.json"))
+    parser.add_argument("--ops", help="a chip's {op: seconds a step} table")
+    parser.add_argument("--dump-ops", metavar="OUT",
+                        help="on the chip: trace the cell, write its table")
+    parser.add_argument("--seed", type=int, default=0)
+    parser.add_argument("--seconds", type=float, default=20.0)
+    args = parser.parse_args(argv)
+    if args.dump_ops:
+        return dump_ops(args.workload, args.manifest, args.seed,
+                        args.seconds, args.dump_ops)
+    t0 = time.time()
+    compiled, leaves = compile_step(args.workload, args.manifest)
+    text = compiled.as_text()
+    ops = None
+    if args.ops:
+        with open(args.ops) as f:
+            ops = json.load(f)["op_s_per_step"]
+    result = table(classify(text, leaves), ops)
+    mem = compiled.memory_analysis()
+    cost = compiled.cost_analysis()
+    result.update(
+        workload=args.workload, compile_s=round(time.time() - t0, 1),
+        tpu_custom_calls=text.count('custom_call_target="tpu_custom_call"'),
+        temp_gb=mem.temp_size_in_bytes / 1e9,
+        cost_analysis_gb=(cost[0] if isinstance(cost, list) else cost).get(
+            "bytes accessed", 0.0) / 1e9,
+        sync_gb=sum(v["gb"] for k, v in result["kinds"].items()
+                    if k not in ("async copy/slice", "all-reduce")))
+    print(json.dumps(result, indent=1))
+
+
+if __name__ == "__main__":
+    main()
