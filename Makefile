@@ -7,7 +7,7 @@
 #   make resume MODEL=resnet50           # resume from latest checkpoint
 #   make train-fg MODEL=lenet5 ARGS=--fake-data
 #   make tb                              # tensorboard on ./runs
-#   make test / make bench / make dryrun
+#   make test / make dryrun
 
 TIME := $(shell date "+%Y-%m-%dT%H-%M-%S")
 MODEL ?= resnet50
@@ -56,7 +56,7 @@ lint-baseline:
 # obs-smoke and chaos-smoke — the telemetry artifacts must validate and
 # the resilience contracts must hold before the tests count
 verify: SHELL := /bin/bash
-verify: lint preflight perf-smoke obs-smoke chaos-smoke data-smoke host-smoke serve-smoke fleet-smoke fleetnet-smoke cache-smoke shard-smoke perf-gate live-smoke
+verify: lint preflight obs-smoke chaos-smoke data-smoke host-smoke serve-smoke fleet-smoke fleetnet-smoke cache-smoke shard-smoke perf-gate live-smoke
 	set -o pipefail; rm -f /tmp/_t1.log; timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=$${PIPESTATUS[0]}; echo DOTS_PASSED=$$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$$' /tmp/_t1.log | tr -cd . | wc -c); exit $$rc
 
 # environment preflight: backend liveness + libtpu/client version
@@ -213,35 +213,6 @@ host-smoke:
 data-smoke:
 	JAX_PLATFORMS=cpu python tools/data_smoke.py --workdir artifacts/data_smoke
 
-# perf smoke: the CPU-provable proxies behind the MFU attack — the Pallas
-# NMS kernel matches its lax reference in interpret mode, a multistep=4
-# Trainer superstep is step-for-step equivalent to 4 single dispatches
-# with 4x fewer step events and ZERO recompiles after
-# warmup, the depth-2 device prefetcher never starves a slower consumer,
-# and check_journal --strict accepts the extended step/bench fields
-# (tools/perf_smoke.py)
-perf-smoke:
-	JAX_PLATFORMS=cpu python tools/perf_smoke.py --workdir artifacts/perf_smoke
-
-bench:
-	python bench.py
-
-# roofline anchored to the latest bench numbers: where the measured step
-# and each analytic layer sit vs the 197 TF/s / 819 GB/s pins and the
-# 30%-MFU baseline (deep_vision_tpu/tools/roofline.py --bench-json)
-# BENCH_JSON: a bench.py result line saved from a chip run (no default:
-# the old BENCH_r0N records are gone and nothing measured replaces them yet)
-roofline:
-	@test -n "$(BENCH_JSON)" || { echo "roofline: set BENCH_JSON=<bench.py result from a chip run>"; exit 2; }
-	python -m deep_vision_tpu.tools.roofline --analytic \
-	  --bench-json $(BENCH_JSON) --out artifacts/roofline_bench.json
-
-# perf-evidence suite: every README perf claim regenerates from these
-bench-evidence:
-	python tools/batch_sweep.py artifacts/batch_scaling_r04.json
-	python tools/bench_ablate.py
-	python tools/bench_models.py
-
 demo:
 	python -m deep_vision_tpu.tools.convergence_run --model yolov3 \
 	  --holdout --render-dir examples/output
@@ -269,4 +240,4 @@ ps:
 native:
 	$(MAKE) -C native
 
-.PHONY: train resume train-fg test lint lint-baseline verify preflight obs-smoke chaos-smoke data-smoke host-smoke serve-smoke fleet-smoke fleetnet-smoke cache-smoke shard-smoke perf-gate live-smoke perf-smoke bench bench-evidence roofline demo demo-gan demo-real dryrun tb ps native
+.PHONY: train resume train-fg test lint lint-baseline verify preflight obs-smoke chaos-smoke data-smoke host-smoke serve-smoke fleet-smoke fleetnet-smoke cache-smoke shard-smoke perf-gate live-smoke demo demo-gan demo-real dryrun tb ps native

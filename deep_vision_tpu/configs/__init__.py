@@ -128,7 +128,8 @@ register_config(ExperimentConfig(
 for _name, _model, _mkw in (
     ("resnet34", "resnet34", {}),
     # flagship: space-to-depth stem (math-equal to conv7, ~3% faster on TPU;
-    # models/resnet.py SpaceToDepthStem) — the config bench.py reproduces
+    # models/resnet.py SpaceToDepthStem) — the program of the benchmark's
+    # resnet50 cells
     ("resnet50", "resnet50", {"stem": "s2d"}),
     ("resnet152", "resnet152", {}), ("resnet50v2", "resnet50v2", {}),
 ):

@@ -29,9 +29,6 @@ from typing import Dict
 __all__ = [
     "BackendProfile",
     "BACKENDS",
-    "DevicePeaks",
-    "DEVICE_PEAKS",
-    "device_peaks",
     "current_platform",
     "get_backend",
     "is_tpu",
@@ -61,33 +58,6 @@ BACKENDS: Dict[str, BackendProfile] = {
     "cpu": BackendProfile(name="cpu", pallas_compiled=False,
                           nms_impl="lax"),
 }
-
-
-@dataclasses.dataclass(frozen=True)
-class DevicePeaks:
-    """Published per-chip peaks a utilisation or roofline share divides by."""
-
-    bf16_flops: float  # FLOP/s
-    hbm_bytes_per_s: float
-
-
-#: the ONE peaks table, keyed by jax's `device_kind`. Source: Google Cloud
-#: documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM per chip (jax
-#: reports a v5e chip as "TPU v5 lite"). A device that is not here is an
-#: error, never a default: a share of the wrong peak is a wrong number.
-DEVICE_PEAKS: Dict[str, DevicePeaks] = {
-    "TPU v5 lite": DevicePeaks(bf16_flops=197e12, hbm_bytes_per_s=819e9),
-}
-
-
-def device_peaks(device_kind: str) -> DevicePeaks:
-    try:
-        return DEVICE_PEAKS[device_kind]
-    except KeyError:
-        raise ValueError(
-            f"no published peaks for device_kind {device_kind!r} (known: "
-            f"{sorted(DEVICE_PEAKS)}); add a sourced row to "
-            "core/backend.py DEVICE_PEAKS") from None
 
 
 def current_platform() -> str:

@@ -111,7 +111,7 @@ def env_fingerprint(mesh_shape=None) -> dict:
 def place_compile_cache() -> str:
     """Decide where JAX's persistent compilation cache lives and return
     that directory. Called first thing by every entry point (train_cli,
-    the serving replicas, tools/infer, bench.py, chip_smoke.py), before
+    the serving replicas, tools/infer, chip_smoke.py), before
     anything compiles.
 
     ``JAX_COMPILATION_CACHE_DIR`` set: JAX already reads it — nothing is

@@ -11,7 +11,7 @@ Event types (full schema in obs/README.md):
   health        health monitor findings (obs/health.py: non_finite,
                 loss_spike, divergence, hang with thread stacks)
   profile       profiler trace start/stop
-  bench         one benchmark measurement (tools/bench_*.py)
+  bench         one named measurement (tools/shard_smoke.py scaling rows)
   retry         one retried/abandoned I/O attempt (resilience/retry.py)
   fault         an injected fault fired (resilience/faults.py)
   data_skip     a bad record skipped under the bad-record budget

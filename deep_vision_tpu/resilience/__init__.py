@@ -7,15 +7,15 @@ clean lab run):
 - `retry`:  `RetryPolicy` — exponential backoff + jitter, deadline,
   retryable-exception classification; decorator / driver / attempt-loop
   forms; typed `retry` journal events and metrics counters. Shared by
-  bench.py's rebuild-replay loop, the checkpoint sidecar writer, and
+  the Trainer's rebuild-replay loop, the checkpoint sidecar writer, and
   shard opens in the tolerant record reader.
 - `elastic`: the accelerator-layer arc — backend-failure classification
   (connection loss / hung-backend timeout / libtpu version skew),
   `BackendSupervisor` rebuild-replay choreography with typed
   `backend_lost`/`backend_recovered` journal events, cross-mesh
   checkpoint sharding metadata (restore a run saved on N devices onto
-  M), and the threaded `backend_alive` liveness probe shared by bench
-  and `tools/preflight.py`.
+  M), and the threaded `backend_alive` liveness probe
+  `tools/preflight.py` runs.
 - `rendezvous`: the multi-HOST half of the elastic arc — file-backed
   generation-numbered membership (heartbeat leases, deadline-bounded
   barriers/consensus, join-time version handshake), `HostSupervisor`

@@ -12,15 +12,13 @@ inputs*:
   `connection_lost` / `timeout` (retryable: rebuild the client and
   replay), `version_skew` (NOT retryable: a skew does not heal mid-run —
   fail fast, that is `tools/preflight.py`'s job to catch before minutes
-  are burned), `unknown` (a program bug wearing a RuntimeError; only
-  callers replaying pure computation, like bench.py, opt into retrying
-  it).
-- `BackendSupervisor`: the rebuild-replay choreography bench.py
-  prototyped, lifted into one reusable object: a single `RetryPolicy` holds the backoff jitter RNG (the
-  `_ACTIVE_POLICY` module-global shim this replaces could silently
-  re-seed and re-draw the same "jittered" delay), failures journal typed
-  `backend_lost` events and recoveries `backend_recovered`, with flight
-  recorder breadcrumbs on both. The Trainer and bench.py both drive it.
+  are burned), `unknown` (a program bug wearing a RuntimeError: never
+  retried, it propagates).
+- `BackendSupervisor`: the rebuild-replay choreography in one object: a
+  single `RetryPolicy` holds the backoff jitter RNG (one draw per
+  backoff), failures journal typed `backend_lost` events and recoveries
+  `backend_recovered`, with flight recorder breadcrumbs on both. The
+  Trainer drives it.
 - cross-mesh sharding metadata (`sharding_meta` / `replace_on_mesh`):
   serializable leaf-level PartitionSpecs saved in the checkpoint sidecar
   so a run checkpointed on N hosts/devices restores onto M — specs are
@@ -28,7 +26,7 @@ inputs*:
   cannot honor (axis absent, or dim no longer divisible) per dimension.
 - `backend_alive`: the one budgeted liveness probe, threaded so that a
   backend that blocks without raising is still seen (by the join
-  timeout), shared by bench.py and the preflight.
+  timeout); `tools/preflight.py` runs it.
 
 jax-free at import (the resilience/ contract — spawned data workers
 import this package): jax is imported inside the functions that need it.
@@ -115,16 +113,17 @@ def classify_backend_error(exc) -> str:
     return KIND_UNKNOWN
 
 
-def backend_alive(budget_s: float, probe=None, with_kind: bool = False):
-    """(ok, error) — does a trivial device op complete within `budget_s`?
+def backend_alive(budget_s: float, probe=None):
+    """(ok, error, kind) — does a trivial device op complete within
+    `budget_s`?
 
     The op runs in a worker thread: a backend that blocks without raising
     cannot be seen by a try/except — a join timeout can. The orphaned
     daemon thread stays blocked; callers report and return, so it never
     wedges teardown.
 
-    `with_kind=True` returns (ok, error, kind) with the failure classified
-    from the EXCEPTION OBJECT the probe raised (a hang is `timeout`) —
+    `kind` classifies the failure from the EXCEPTION OBJECT the probe
+    raised (a hang is `timeout`; None when healthy) —
     re-classifying the formatted message would lose the exception-type
     gate and let a probe bug mentioning 'timeout' impersonate a hung
     backend.
@@ -150,15 +149,13 @@ def backend_alive(budget_s: float, probe=None, with_kind: bool = False):
     if t.is_alive():
         err = (f"backend liveness probe still blocked after "
                f"{budget_s:.0f}s (backend hung?)")
-        return (False, err, KIND_TIMEOUT) if with_kind else (False, err)
+        return False, err, KIND_TIMEOUT
     if "exc" in out:
         e = out["exc"]
         err = (f"backend liveness probe failed: "
                f"{type(e).__name__}: {e}")
-        if with_kind:
-            return False, err, classify_backend_error(e)
-        return False, err
-    return (True, None, None) if with_kind else (True, None)
+        return False, err, classify_backend_error(e)
+    return True, None, None
 
 
 # -- the rebuild-replay supervisor --------------------------------------------
@@ -166,19 +163,17 @@ def backend_alive(budget_s: float, probe=None, with_kind: bool = False):
 class BackendSupervisor:
     """Backend-loss detection + rebuild-replay bookkeeping, in one place.
 
-    One supervisor serves one recovery surface (a bench session, a
-    Trainer.fit): it owns the `RetryPolicy` whose jitter RNG advances one
-    draw per backoff, journals typed `backend_lost` / `backend_recovered`
-    events, bumps `backend_lost_total{kind=}` /
+    One supervisor serves one recovery surface (a Trainer.fit): it owns
+    the `RetryPolicy` whose jitter RNG advances one draw per backoff,
+    journals typed `backend_lost` / `backend_recovered` events, bumps `backend_lost_total{kind=}` /
     `backend_recoveries_total`, and leaves flight-recorder breadcrumbs so
     a degraded-result postmortem shows the recovery attempts that led
     there.
 
     The caller keeps its own control flow (what "rebuild" and "replay"
-    mean is caller-specific — bench rebuilds the jitted step and replays
-    the timed windows; the Trainer re-jits, restores the last checkpoint,
-    and replays the epoch); the supervisor decides *whether* another
-    attempt is worth it and paces it:
+    mean is caller-specific — the Trainer re-jits, restores the last
+    checkpoint, and replays the epoch); the supervisor decides *whether*
+    another attempt is worth it and paces it:
 
         retrying = sup.on_failure(attempt, exc, step=...)
         if not retrying:
@@ -187,18 +182,14 @@ class BackendSupervisor:
         ... rebuild + replay ...
         sup.on_recovered(attempt, step=...)
 
-    `retry_unclassified=True` (bench) retries `unknown` failures too — a
-    bench window is a replayable pure computation, so any Exception is
-    worth one more attempt. The Trainer keeps the default False: an
-    unknown exception there is a program bug and must propagate.
-    `version_skew` is never retried: it cannot heal mid-run, and burning
-    the retry budget on it is exactly the minutes `tools/preflight.py`
-    exists to save.
+    An `unknown` failure is never retried: it is a program bug and must
+    propagate. `version_skew` is never retried either: it cannot heal
+    mid-run, and burning the retry budget on it is exactly the minutes
+    `tools/preflight.py` exists to save.
     """
 
     def __init__(self, max_retries: int = 5, policy: Optional[RetryPolicy] = None,
                  journal=None, registry=None, name: str = "backend",
-                 retry_unclassified: bool = False,
                  clear_caches_after: int = 2):
         # max_attempts counts the first try too: max_retries retries on top
         self.policy = policy or RetryPolicy(
@@ -214,7 +205,6 @@ class BackendSupervisor:
             # rows the policy emits per attempt
             self.policy.journal = self.journal
         self._registry = registry
-        self.retry_unclassified = bool(retry_unclassified)
         self.clear_caches_after = int(clear_caches_after)
 
     # -- decisions ---------------------------------------------------------
@@ -228,9 +218,9 @@ class BackendSupervisor:
         if isinstance(exc, (KeyboardInterrupt, SystemExit)):
             return False
         kind = self.classify(exc)
-        if kind == KIND_VERSION_SKEW:
-            return False  # will not heal; fail fast (preflight's domain)
-        if kind not in RETRYABLE_KINDS and not self.retry_unclassified:
+        if kind not in RETRYABLE_KINDS:
+            # version_skew will not heal (preflight's domain); unknown is
+            # a program bug
             return False
         return self.policy.should_retry(attempt, exc)
 

@@ -1,14 +1,12 @@
 """Shared retry policy: exponential backoff + jitter, deadline, typed events.
 
 Production checkpoint/data systems treat storage and transport as
-unreliable by design (Check-N-Run, NSDI '22; Varuna, EuroSys '22); until
-this module the repo's only retry logic was a bespoke loop inside
-bench.py (grown after a run lost its perf number to ONE transient
-transport error). `RetryPolicy` is the one implementation every I/O
-boundary shares — bench's rebuild-replay loop, the checkpoint sidecar
-writer, and shard opens in the tolerant record reader all consult it —
-so backoff behavior, exception classification, and the `retry` journal
-event schema cannot drift between callers.
+unreliable by design (Check-N-Run, NSDI '22; Varuna, EuroSys '22).
+`RetryPolicy` is the one implementation every I/O boundary shares — the
+Trainer's backend rebuild-replay loop (`elastic.BackendSupervisor`), the
+checkpoint sidecar writer, and shard opens in the tolerant record reader
+all consult it — so backoff behavior, exception classification, and the
+`retry` journal event schema cannot drift between callers.
 
 Three usage shapes:
 

@@ -119,7 +119,7 @@ def check_backend(budget_s: float = DEFAULT_BUDGET_S,
     libtpu client/terminal skew raises FAILED_PRECONDITION on the first
     dispatch and lands here as `version_skew` seconds into the run
     instead of minutes."""
-    ok, err, kind = backend_alive(budget_s, probe=probe, with_kind=True)
+    ok, err, kind = backend_alive(budget_s, probe=probe)
     if not ok:
         return CheckResult("backend", False, err, kind=kind)
     try:

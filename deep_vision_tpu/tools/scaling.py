@@ -8,10 +8,9 @@ sub-meshes of the available devices, reporting throughput, per-device
 examples/s, and the efficiency fraction vs the 1-device baseline (1.0 =
 linear scaling; the gap is the collective/dispatch cost).
 
-Shared by `bench.py --multichip` (the journal/bench-JSON emitter), the
-`__graft_entry__.dryrun_multichip`
-scaling section, and `make shard-smoke` — one measurement, three
-consumers, so the numbers are comparable.
+Shared by the `__graft_entry__.dryrun_multichip` scaling section and
+`make shard-smoke` (phase D, which journals the rows as a `bench` event)
+— one measurement, two consumers, so the numbers are comparable.
 
 On a real multi-chip slice the rows are the scaling story; on a forced
 virtual-CPU mesh (every "device" is the same host core) efficiency
@@ -131,7 +130,7 @@ def measure_scaling(
     """
     import jax
 
-    # degenerate knobs (BENCH_MULTICHIP_STEPS=0, warmup=0) would leave
+    # degenerate knobs (steps=0, warmup=0) would leave
     # `loss` unbound or divide by a zero baseline — clamp, don't crash
     steps = max(1, int(steps))
     warmup = max(1, int(warmup))

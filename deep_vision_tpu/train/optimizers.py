@@ -102,12 +102,13 @@ def cast_optimizer_state(
     The SGD+momentum update reads and rewrites a full params-sized trace
     every step; at f32 that is 2x params bytes of pure HBM traffic per
     step on top of the weights themselves. Storing the trace in bf16
-    halves it (the roofline's `params` rows in tools/roofline.py price
-    this directly). The update itself still runs in `compute_dtype`: state
-    is upcast entering the wrapped transform and the new state rounded
-    back on the way out — one rounding per step, the same error model as
-    bf16 gradient accumulation. Float leaves only; step counters and other
-    integer state pass through untouched.
+    halves it (PERF.md §5's reckoning prices it: momentum read and write
+    are 2 of the 6 passes over the parameters). The update itself still
+    runs in `compute_dtype`: state is upcast entering the wrapped
+    transform and the new state rounded back on the way out — one rounding
+    per step, the same error model as bf16 gradient accumulation. Float
+    leaves only; step counters and other integer state pass through
+    untouched.
     """
 
     def init(params):

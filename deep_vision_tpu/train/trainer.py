@@ -365,8 +365,7 @@ class Trainer:
         self._checkify = checkify_errors
         # -- scan-multistep: K optimizer steps per dispatch ----------------
         # One lax.scan over a (K, B, ...) stacked batch amortizes the
-        # per-dispatch host turnaround K-fold (bench.py measured the
-        # mechanism; this is the first-class Trainer mode). The scan body
+        # per-dispatch host turnaround K-fold. The scan body
         # IS `_train_step_impl`, so per-microstep RNG (fold_in on the
         # advancing state.step), metrics, and the skip_step NaN-guard all
         # apply per microstep; the epoch tail (fewer than K batches left)
@@ -1041,9 +1040,9 @@ class Trainer:
                             lost = self.hosts.confirm_loss(e)
                             if lost is not None:
                                 self._handle_host_loss(lost)
-                        # backend-loss detection + rebuild-replay (the
-                        # choreography bench.py prototyped, lifted here):
-                        # only failures the supervisor classifies as a
+                        # backend-loss detection + rebuild-replay
+                        # (resilience/elastic.BackendSupervisor): only
+                        # failures the supervisor classifies as a
                         # lost backend are retried — program bugs, NaN
                         # aborts, and version skew propagate unchanged
                         attempt += 1

@@ -113,6 +113,19 @@ class TestClassification:
         assert set(BACKEND_LOST_KINDS) == cj.BACKEND_LOST_KINDS
 
 
+def test_backend_alive_detects_block_error_and_health():
+    import time
+
+    # the probe the preflight runs: a blocked backend is caught by the
+    # join timeout, an erroring one by its exception
+    ok, err, kind = elastic.backend_alive(0.2, probe=lambda: time.sleep(60))
+    assert not ok and "blocked" in err and kind == "timeout"
+    ok, err, kind = elastic.backend_alive(5.0, probe=lambda: 1 / 0)
+    assert not ok and "ZeroDivisionError" in err and kind == "unknown"
+    ok, err, kind = elastic.backend_alive(5.0, probe=lambda: 1.0)
+    assert ok and err is None and kind is None
+
+
 # -- supervisor ---------------------------------------------------------------
 
 class TestBackendSupervisor:
@@ -123,18 +136,13 @@ class TestBackendSupervisor:
         assert not sup.should_retry(3, e)  # budget: 2 retries + first try
 
     def test_version_skew_never_retried(self):
-        sup = BackendSupervisor(policy=_no_sleep_policy(),
-                                retry_unclassified=True)
+        sup = BackendSupervisor(policy=_no_sleep_policy())
         assert not sup.should_retry(1, RuntimeError(_R01_SKEW))
 
-    def test_unknown_gated_by_retry_unclassified(self):
+    def test_unknown_failure_never_retried(self):
         bug = RuntimeError("a plain bug")
         assert not BackendSupervisor(
             policy=_no_sleep_policy()).should_retry(1, bug)
-        # bench's stance: a window is a replayable pure computation
-        assert BackendSupervisor(
-            policy=_no_sleep_policy(),
-            retry_unclassified=True).should_retry(1, bug)
 
     def test_journals_typed_events(self):
         j = _Journal()

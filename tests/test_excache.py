@@ -579,17 +579,6 @@ def test_obs_report_cold_path_section(tmp_path):
     assert "executable cache" not in render(summary2)
 
 
-def test_bench_cold_start_fields():
-    import bench
-
-    fields = bench._cold_start_fields()
-    assert "warmup_compile_ms" in fields
-    assert "cold_start_ms" in fields
-    assert fields["warmup_compile_ms"] > 0
-    # the whole point: warming from cache beats the compiler
-    assert fields["cold_start_ms"] < fields["warmup_compile_ms"]
-
-
 def test_preflight_check_excache(tmp_path):
     from deep_vision_tpu.tools.preflight import check_excache
 

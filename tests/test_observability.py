@@ -544,15 +544,3 @@ def test_obs_report_flags_crash(tmp_path):
     j._atexit()
     s = summarize_run(read_journal(path))
     assert s["status"].startswith("CRASHED")
-
-
-def test_bench_models_emits_journal_schema(tmp_path):
-    from tools.bench_models import main as bench_main
-
-    out = str(tmp_path / "bench.json")
-    assert bench_main(["--out", out, "--skip-yolo", "--skip-flash"]) == 0
-    events = read_journal(str(tmp_path / "bench.journal.jsonl"))
-    kinds = [e["event"] for e in events]
-    assert kinds[0] == "run_manifest" and kinds[-1] == "exit"
-    assert events[0]["kind"] == "bench"
-    assert events[0]["config"]["tool"] == "bench_models"

@@ -1,10 +1,10 @@
 """Perf layer: the Pallas NMS kernel, scan-multistep Trainer, device
-prefetch, bf16 optimizer state, roofline bench anchoring. (BatchNorm's
-fused tail is plain jax.numpy: tests/test_bn_tail.py.)
+prefetch, bf16 optimizer state. (BatchNorm's fused tail is plain
+jax.numpy: tests/test_bn_tail.py.)
 
 Kernel tests run the REAL Pallas kernel under interpret=True (the same
 code path the TPU compiles), against pure-lax references. Multistep tests
-prove the one-dispatch-per-K-steps contract the on-TPU bench banks on.
+prove the one-dispatch-per-K-steps contract.
 """
 import json
 import time
@@ -207,6 +207,21 @@ def test_multistep_fit_tail_and_journal(mesh8, tmp_path):
     assert len(t.logger.history["loss"]) == 1  # one epoch summary
 
 
+def test_multistep_second_epoch_compiles_nothing(mesh8):
+    """The superstep executable is compiled once: a second epoch over the
+    same shapes adds no backend compile."""
+    from deep_vision_tpu.obs.stepclock import recompile_count
+
+    batches = _mk_batches(8, seed=1)
+    t = _lenet_trainer(mesh8, multistep=4)
+    t.fit(lambda: iter(batches), epochs=1, handle_preemption=False)
+    before = recompile_count()
+    t.fit(lambda: iter(batches), epochs=2, start_epoch=1,
+          handle_preemption=False)
+    assert recompile_count() == before
+    assert int(t.state.step) == 16  # 4 dispatches of 4 steps
+
+
 def test_multistep_partial_batch_inside_full_group(mesh8):
     """A short final batch landing INSIDE a full K-group must be padded to
     the group's common size and masked, not crash np.stack."""
@@ -328,84 +343,3 @@ def test_bf16_opt_state_adam_moments():
         state.inner_state)
     assert dtypes == {"bfloat16"}
     assert updates["w"].dtype == jnp.float32  # updates stay full precision
-
-
-# -- roofline bench anchoring ----------------------------------------------
-
-def test_roofline_bench_position(tmp_path):
-    from deep_vision_tpu.tools.roofline import (
-        analytic_traffic, bench_position, load_bench_json, render_roofline)
-
-    bench = {"metric": "resnet50_train_images_per_sec_per_chip",
-             "value": 2477.9, "vs_baseline": 0.949, "batch_per_chip": 256,
-             "multistep": 1, "model_flops_per_image": 24.05,
-             "hbm_gbytes_per_step_per_chip": 77.86,
-             "hbm_gbytes_per_sec_per_chip": 753.6,
-             "device_images_per_sec_per_chip": 2615.3,
-             "mfu_wall_pct": 30.2, "mfu_device_pct": 31.9}
-    p = tmp_path / "bench.json"
-    p.write_text(json.dumps({"parsed": bench}))  # driver wrapper form
-    assert load_bench_json(str(p))["value"] == 2477.9
-    pos = bench_position(bench, analytic_traffic(256))
-    rows = {r["name"]: r for r in pos["rows"]}
-    wall = rows["train_step (wall)"]
-    # 2477.9 img/s * 24.05 GF = 59.6 TF/s achieved
-    assert wall["achieved_tflops"] == pytest.approx(59.6, abs=0.1)
-    assert wall["bound"] == "memory"  # intensity 79 f/B < ridge 240
-    assert 0 < wall["pct_of_roofline"] <= 100
-    assert wall["vs_30pct_mfu_baseline"] == pytest.approx(1.01, abs=0.02)
-    # layers carry intensity-only placement
-    assert any(r["name"].startswith("s") for r in pos["rows"])
-    assert "30%-MFU baseline" in render_roofline(pos)
-
-
-def test_roofline_rejects_non_bench_json(tmp_path):
-    from deep_vision_tpu.tools.roofline import load_bench_json
-
-    p = tmp_path / "x.json"
-    p.write_text(json.dumps({"rows": []}))
-    with pytest.raises(ValueError, match="not a bench result"):
-        load_bench_json(str(p))
-
-
-# -- bench result fields ----------------------------------------------------
-
-def test_bench_stub_carries_multistep():
-    import argparse
-
-    import bench
-
-    stub = bench.train_result_stub(
-        argparse.Namespace(batch=128, multistep=4))
-    assert stub["multistep"] == 4
-    assert stub["batch_per_chip"] == 128
-
-
-def test_bench_emit_journals_every_path(monkeypatch):
-    """_emit (the one funnel for train/sweep/data lines) must write the
-    bench journal event exactly once."""
-    import bench
-
-    class Spy:
-        def __init__(self):
-            self.events, self.closed = [], False
-
-        def bench(self, name, result):
-            self.events.append((name, result))
-
-        def close(self):
-            self.closed = True
-
-    spy = Spy()
-    monkeypatch.setattr(bench, "_JOURNAL", spy)
-    monkeypatch.setattr(bench, "_EMITTED", None)
-    assert bench._emit({"metric": "dispatch_sweep", "rows": []})
-    assert not bench._emit({"metric": "late_duplicate"})  # latched
-    assert len(spy.events) == 1
-    name, result = spy.events[0]
-    assert name == "dispatch_sweep"
-    assert result["metric"] == "dispatch_sweep" and result["rows"] == []
-    # every emitted line carries the perf-ledger environment fingerprint
-    # (tools/perf_gate.py keys baselines on it)
-    assert result["env"]["jax"] and result["env_key"]
-    assert spy.closed

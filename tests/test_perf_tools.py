@@ -1,69 +1,9 @@
-"""Host-side units of the round-4 perf/evidence tooling.
-
-The chip-facing halves of these tools are exercised by their committed
-artifacts; these tests pin the pure-python parts (HLO parsing, metric
-conventions, procedural dataset generators) that everything downstream
-trusts.
+"""Host-side units of the evidence tooling: the aux-metric naming
+convention and the procedural dataset generators of
+`tools/convergence_run.py`. (HLO byte counting: tests/test_step_bytes.py.)
 """
 import numpy as np
 import pytest
-
-from tools.hbm_breakdown import breakdown, parse_entry, shape_bytes
-
-
-HLO = """\
-HloModule jit_train_step
-
-%fused_computation.1 {
-  %p = bf16[8,8]{1,0} parameter(0)
-  ROOT %a = bf16[8,8]{1,0} add(%p, %p)
-}
-
-ENTRY %main (p0: bf16[256,56,56,64], p1: f32[64]) -> bf16[256,56,56,64] {
-  %p0 = bf16[256,56,56,64]{3,2,1,0:T(8,128)(2,1)} parameter(0)
-  %p1 = f32[64]{0:T(256)} parameter(1)
-  %copy.1 = bf16[256,56,56,64]{0,3,2,1:T(8,128)(2,1)} copy(%p0)
-  %fusion.1 = bf16[256,56,56,64]{0,3,2,1:T(8,128)(2,1)} fusion(%copy.1, %p1), kind=kLoop, calls=%fused_computation.1
-  ROOT %tuple.1 = (bf16[256,56,56,64]{0,3,2,1}) tuple(%fusion.1)
-}
-"""
-
-
-def test_shape_bytes():
-    assert shape_bytes("bf16[256,56,56,64]{3,2,1,0}") == 256 * 56 * 56 * 64 * 2
-    assert shape_bytes("f32[64]{0}") == 256
-    # tuple shapes sum their elements
-    assert shape_bytes("(f32[2,2], bf16[4])") == 16 + 8
-    assert shape_bytes("pred[8]") == 8
-    assert shape_bytes("token[]") == 0
-
-
-def test_parse_entry_only_entry_instructions():
-    rows = list(parse_entry(HLO))
-    names = [r[0] for r in rows]
-    # instructions inside %fused_computation.1 must NOT appear
-    assert "a" not in names and "p" not in names
-    assert {"p0", "p1", "copy.1", "fusion.1", "tuple.1"} <= set(names)
-    by_name = {r[0]: r for r in rows}
-    assert by_name["fusion.1"][2] == "fusion"
-    assert by_name["fusion.1"][3] == ["copy.1", "p1"]
-
-
-def test_breakdown_accounting():
-    big = 256 * 56 * 56 * 64 * 2  # one bf16 feature map
-    art = breakdown(HLO)
-    # copy: in big + out big; fusion: in (big + 256) + out big; parameters
-    # and the tuple are plumbing with no traffic of their own
-    est = art["total_estimated_gb"] * 1e3  # MB (artifact rounds to 10 MB)
-    want = (2 * big + (big + 256 + big)) / 1e6
-    assert est == pytest.approx(want, abs=10.0)
-    rows = {r["name"]: r for r in art["top_instructions"]}
-    assert rows["copy.1"]["total_mb"] == pytest.approx(2 * big / 1e6,
-                                                       rel=1e-3)
-    assert rows["fusion.1"]["in_mb"] == pytest.approx((big + 256) / 1e6,
-                                                      rel=1e-3)
-    classes = {c["class"] for c in art["by_class"]}
-    assert "copy/layout" in classes
 
 
 def test_aux_metric_prefix_convention():
@@ -139,48 +79,6 @@ def test_gratings_difficulty_knob():
     # 32-class variant factors 8 orientations x 4 freqs and stays in range
     imgs32, labels32 = procedural_gratings(8, classes=32, size=32)
     assert labels32.max() < 32
-
-
-def test_roofline_analytic_model_matches_known_resnet50_figures():
-    """The shape-math traffic/FLOP model must reproduce the published
-    ResNet-50 numbers: ~8.2 GFLOP forward per image (so ~24.6 train at the
-    3x convention) and a total parameter count near 25.6M."""
-    from deep_vision_tpu.tools.roofline import (
-        analytic_traffic,
-        resnet50_conv_shapes,
-    )
-
-    a = analytic_traffic(128)
-    per_img_gflop = a["train_tflops_per_step"] * 1e3 / 128
-    assert 22.0 < per_img_gflop < 27.0, per_img_gflop
-    params = sum(L["k"] * L["k"] * L["cin"] * L["cout"]
-                 for L in resnet50_conv_shapes())
-    assert 23e6 < params < 28e6, params  # conv+head (BN scales excluded)
-    # the bound is a LOWER bound: far under the cost_analysis overcount
-    # (~40 GB at b128) and strictly positive floors
-    assert 5.0 < a["total_gb"] < 40.0
-    assert a["min_step_ms_if_memory_bound"] > 0
-    assert a["min_step_ms_if_compute_bound"] > 0
-    # the per-layer itemization accounts for the whole total (not just the
-    # top-10 excerpt that top_layers shows)
-    assert abs(a["itemized_total_gb"] - a["total_gb"]) < 0.05
-    assert sum(r["gb"] for r in a["top_layers"]) > 0.3 * a["total_gb"]
-
-
-def test_roofline_verdict_paths():
-    from deep_vision_tpu.tools.roofline import analytic_traffic, verdict
-
-    a = analytic_traffic(128)
-    assert "analytic-only" in verdict(a, None)
-    # memory-bound path: device time equal to the memory floor
-    v = verdict(a, {"device_step_ms": a["min_step_ms_if_memory_bound"],
-                    "dma_gb_per_step": a["total_gb"]})
-    assert "memory-bound" in v
-    # not-bound path: device time far above both floors, low traffic
-    v = verdict(a, {"device_step_ms": 10
-                    * a["min_step_ms_if_memory_bound"],
-                    "dma_gb_per_step": a["total_gb"]})
-    assert "NOT memory-bound" in v
 
 
 def test_gratings_nonfactoring_class_count_stays_in_freq_range():

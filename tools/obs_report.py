@@ -385,8 +385,9 @@ def summarize_sharding(events: List[dict]) -> Optional[dict]:
     `sharding_resolved` event's coverage ledger (matched/unmatched,
     sharded vs replicated float leaves, the mesh it resolved on) with
     the top rule hit counts, plus scaling-efficiency rows when the
-    journal carries a MULTICHIP bench event (`bench.py --multichip` /
-    tools/scaling.py rows, recognized by their data+efficiency keys).
+    journal carries a MULTICHIP bench event (tools/scaling.py rows as
+    `tools/shard_smoke.py` journals them, recognized by their
+    data+efficiency keys).
     None when the journal has neither — every existing report renders
     byte-unchanged."""
     resolved = [e for e in events if e.get("event") == "sharding_resolved"]
@@ -826,10 +827,11 @@ def render(summary: dict) -> str:
             if q.get("tolerance") is not None:
                 detail += f" (tolerance {q['tolerance']})"
             rows.append((f"  int8 {q['model']}", f"{verdict}: {detail}"))
-    # declarative sharding (parallel/shardmap.py sharding_resolved +
-    # bench.py --multichip): which table resolved, how many leaves each
-    # rule claimed, what actually sharded, and the scaling-efficiency
-    # curve — the "is the parallelism real and what does it buy" answers
+    # declarative sharding (parallel/shardmap.py sharding_resolved + the
+    # scaling rows of tools/shard_smoke.py): which table resolved, how
+    # many leaves each rule claimed, what actually sharded, and the
+    # scaling-efficiency curve — the "is the parallelism real and what
+    # does it buy" answers
     sharding = summary.get("sharding")
     if sharding:
         for t in sharding.get("tables", []):

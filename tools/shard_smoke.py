@@ -27,8 +27,8 @@ verify` prerequisite), on a forced 8-device virtual-CPU mesh
                 debuggable from the message; and a table missing its
                 catch-all must refuse at construction.
   D. scaling    tools/scaling.py measures throughput at data={1,2,4,8}
-                sub-meshes (the `bench.py --multichip` measurement) and
-                the rows land as a typed `bench` event, each carrying
+                sub-meshes and the rows land as a typed `bench` event,
+                each carrying
                 the compiled step's predicted comm bytes next to the
                 measured step-time delta vs the 1-device baseline.
   E. artifacts  journals pass `check_journal --strict`

@@ -11,8 +11,8 @@ cell builds it (`benchmark/adapters/train.build_trainer`, the Trainer's own
 op names are the chip's. Every instruction of the optimized entry
 computation is then charged its operands' bytes plus its result's, and
 summed by kind. A step bound by HBM bandwidth takes that sum over 819 GB/s
-(PERF.md §5); `artifacts/roofline_r05.json` reckons 14.5 GB as ResNet-50's
-floor at batch 128. Nothing runs: no time comes from here. `--ops` takes a
+(PERF.md §5, which also reckons 14.5 GB as ResNet-50's floor at batch 128).
+Nothing runs: no time comes from here. `--ops` takes a
 chip's traced table {op name: seconds a step} (`--dump-ops`, on the chip,
 writes one) and lays its milliseconds beside the bytes, by the same kinds.
 """
