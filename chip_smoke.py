@@ -16,7 +16,7 @@ ResNet-50, on every local device:
            window closed by block_until_ready.
   loader   8 record-reading worker processes started beside the live TPU
            client (they pin themselves to the CPU backend).
-  kernels  the two Pallas kernels, compiled (interpret=False), against
+  kernels  the Pallas kernels, compiled (interpret=False), against
            their in-repo references at the shapes production uses.
   serve    Transport -> admission -> queue -> Engine with resnet50 over HTTP,
            the Engine warmed from the AOT executable store a first one filled.
@@ -318,6 +318,38 @@ def phase_kernels() -> dict:
             for name, got, want in zip(("dq", "dk", "dv"), grads, ref_grads):
                 _close(got, want, 3e-2, f"flash {tag} {name}")
             out["flash_ms"][tag] = round(secs * 1e3, 3)
+
+    # the single-block kernel alone at ViT-B/16's shape (batch 128, 196
+    # tokens, 12 heads x 64), fwd+bwd through the qkv-in / o-out interface,
+    # beside XLA's dense expression on the same operands: a kernel fast
+    # alone and slow in the step is PERF.md's finding to repeat or refute.
+    # float32 io rides along at batch 16: `train.py`'s default dtype.
+    def dense_qkv(qkv):
+        b, t, _ = qkv.shape
+        q, k, v = (qkv.reshape(b, t, 3, 12, 64)[:, :, i] for i in range(3))
+        s = jnp.einsum("bthd,bshd->bhts", q, k) * 64 ** -0.5
+        p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
+        return jnp.einsum("bhts,bshd->bthd", p, v).reshape(b, t, 768)
+
+    def qkv_fwd_bwd(impl):
+        def run(qkv, g):
+            out, vjp = jax.vjp(impl, qkv)
+            return out, vjp(g)[0]
+        return jax.jit(run)
+
+    out["fused_ms"] = {}
+    for dtype, b, tol in ((jnp.bfloat16, 128, 3e-2), (jnp.float32, 16, 2e-2)):
+        ks = jax.random.split(jax.random.fold_in(key, 196 + b), 2)
+        qkv = jax.random.normal(ks[0], (b, 196, 2304), dtype)
+        g = jax.random.normal(ks[1], (b, 196, 768), dtype)
+        (o, dqkv), secs = _timed(qkv_fwd_bwd(lambda x: fa.fused_attention(
+            x, 12, interpret=False)), qkv, g)
+        (ref_o, ref_dqkv), ref_secs = _timed(qkv_fwd_bwd(dense_qkv), qkv, g)
+        tag = f"B{b}/T196/{jnp.dtype(dtype).name}"
+        _close(o, ref_o, tol, f"fused {tag} out")
+        _close(dqkv, ref_dqkv, tol, f"fused {tag} d(qkv)")
+        out["fused_ms"][tag] = round(secs * 1e3, 3)
+        out["fused_ms"][tag + "/xla_dense"] = round(ref_secs * 1e3, 3)
 
     # NMS at YOLOv3-416 scale: 10647 candidates, 100 detections. Boxes on
     # a 1/64 grid make every area, intersection and union exact in f32, so

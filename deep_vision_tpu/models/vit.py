@@ -9,10 +9,11 @@ follows ViT (Dosovitskiy 2020) with the TPU-friendly choices:
 - token global-average pooling instead of a class token (keeps the sequence
   length a power-of-two-ish multiple of 8/128 tiling at common resolutions
   and sidesteps concat-of-one ragged shapes);
-- attention auto-routes to the fused Pallas flash kernel
-  (`ops/pallas/flash_attention.py`) when the sequence is long enough to
-  matter and runs the exact dense einsum otherwise — high-res inputs get
-  O(T) memory, 224px inputs get zero kernel-launch overhead;
+- attention routes by shape (`attention_path`): a sequence that fits one
+  block (224px: 196 tokens) runs the single-block Pallas kernel in the
+  projections' own layouts, a long one the streaming flash kernel with
+  O(T) memory (`ops/pallas/flash_attention.py`), anything else and every
+  platform without compiled Pallas the exact dense einsum;
 - pre-norm blocks, GELU MLP, bf16-friendly: LayerNorm statistics in f32,
   params f32, activations in the module dtype.
 
@@ -36,14 +37,36 @@ import jax.numpy as jnp
 
 from deep_vision_tpu.core.backend import get_backend
 from deep_vision_tpu.models import register_model
+from deep_vision_tpu.obs.registry import get_registry
 # the flash routing floor lives with the kernel (shared by this backbone
 # and parallel/ring_attention.py); re-exported here for the historical
 # import path (tests, train_cli)
 from deep_vision_tpu.ops.pallas.flash_attention import (  # noqa: F401
     FLASH_MIN_TOKENS,
+    flash_attention,
     flash_min_tokens,
+    fused_attention,
+    fused_attention_fits,
 )
+from deep_vision_tpu.ops.pallas.partition import batch_parallel_only
 from deep_vision_tpu.parallel.moe import load_balancing_loss
+
+
+def attention_path(t: int, num_heads: int, dim: int) -> str:
+    """Which attention a site of T tokens runs, from its shape alone: the
+    whole sequence in one block -> "fused" (unless the context mesh splits
+    the heads over a second axis: XLA partitions the dense expression by
+    heads, a kernel would gather them); long and a multiple of the
+    streaming kernel's 512 x 1024 blocks (t % 128 alone would admit the
+    1280- and 1536-token inputs it rejects) -> "streaming"; anything else,
+    and every platform without compiled Pallas -> "dense"."""
+    if not get_backend().pallas_compiled:
+        return "dense"
+    if fused_attention_fits(t, num_heads, dim):
+        return "fused" if batch_parallel_only() else "dense"
+    if t >= flash_min_tokens() and t % 1024 == 0:
+        return "streaming"
+    return "dense"
 
 
 class Attention(nn.Module):
@@ -56,27 +79,25 @@ class Attention(nn.Module):
         h = self.num_heads
         assert d % h == 0, f"dim {d} not divisible by {h} heads"
         qkv = nn.DenseGeneral((3, h, d // h), dtype=self.dtype,
-                              name="qkv")(x)
-        q, k, v = (qkv[:, :, i] for i in range(3))  # (B, T, H, Dh)
-        # t must divide the kernel's block_q=512 AND block_k=1024 grid
-        # (flash_attention.py asserts it), so the guard is t % 1024 == 0 —
-        # t % 128 alone would admit 1280/1536-token inputs the kernel rejects
-        use_flash = (
-            get_backend().pallas_compiled
-            and t >= flash_min_tokens()
-            and t % 1024 == 0
-        )
-        if use_flash:
-            from deep_vision_tpu.ops.pallas.flash_attention import (
-                flash_attention,
-            )
-
-            o = flash_attention(q, k, v)
+                              name="qkv")(x)  # (B, T, 3, H, Dh)
+        path = attention_path(t, h, d)
+        # the choice is made while tracing, so that is where it is counted
+        get_registry().counter(
+            "attention_sites_total", "Attention sites traced, by the path "
+            "their shape chose", labels={"path": path}).inc()
+        if path == "fused":
+            o = fused_attention(qkv.reshape(b, t, 3 * d), h)
+            o = o.reshape(b, t, h, d // h)
         else:
-            scale = (d // h) ** -0.5
-            s = jnp.einsum("bthd,bshd->bhts", q, k) * scale
-            p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
-            o = jnp.einsum("bhts,bshd->bthd", p, v)
+            q, k, v = (qkv[:, :, i] for i in range(3))  # (B, T, H, Dh)
+            if path == "streaming":
+                o = flash_attention(q, k, v)
+            else:
+                scale = (d // h) ** -0.5
+                s = jnp.einsum("bthd,bshd->bhts", q, k) * scale
+                p = jax.nn.softmax(s.astype(jnp.float32),
+                                   axis=-1).astype(q.dtype)
+                o = jnp.einsum("bhts,bshd->bthd", p, v)
         return nn.DenseGeneral(d, axis=(-2, -1), dtype=self.dtype,
                                name="out")(o)
 

@@ -361,6 +361,335 @@ def _flash_backward_shard(q, k, v, out, lse, g, delta_shift=None, *,
     return unshape(dq, t), unshape(dk, tk), unshape(dv, tk)
 
 
+# -- a sequence that fits one block ------------------------------------------
+#
+# The streaming kernels above keep running (max, sum) state because a key
+# range does not fit VMEM. At ViT's 196 tokens it does: the scores of one
+# image and head are 196 x 196. `fused_attention` runs such a sequence with
+# one grid step an image, all heads: no running state, no (T, T) tensor in
+# HBM, and operands in the layouts the neighbouring projections write and
+# read: in, the qkv projection's output (B, T, 3*H*Dh), heads picked out in
+# the kernel; out, (B, T, H*Dh), which the out projection contracts over.
+# The backward saves only the float32 log-sum-exp (B, H, T) and recomputes P.
+#
+# Shaped by the v5e's schedule (bundle dumps, PERF.md §6 PR 32):
+# - Scores are held KEY-major, S^T (keys on sublanes, queries on lanes): a
+#   reduction over keys is then elementwise over vregs (a lane reduction
+#   keeps an XLU busy for cycles), and the row statistics are lane-dense
+#   (1, T) vectors, which is the (B, H, T) layout of the saved log-sum-exp.
+# - Heads sit `128 // Dh` to a 128-lane slab. A head is picked out by ROWS
+#   of the slab's transpose (whole vregs: `_head_rows`), never by a lane
+#   select on packed bf16; the matmul contracts over all 128 lanes, which
+#   costs the MXU what a 64-deep contraction costs it.
+# - Each phase goes MXU -> VMEM -> VPU -> VMEM -> MXU in one basic block:
+#   loads issue three a bundle and stores one, and a score tile (52 vregs
+#   a head) does not fit the register file, so it is stored once and
+#   streamed back rather than spilled.
+#
+# FUSED_MAX_TOKENS, from the backward kernel's VMEM at T tokens (Tp = T
+# rounded up to 128), H heads of Dh, D = H*Dh, 2-byte operands:
+#   score scratch, per head: S^T and dP^T float32, P^T and dS^T bf16
+#                                            12 * H * Tp * Tp bytes
+#   zero-padded copies of qkv and dO         8 * D * Tp bytes
+#   pipelined blocks, two buffers each: qkv, d(qkv) (3D) and dO (D)
+#                                            28 * D * T bytes
+# At T = 256, H = 16, Dh = 64 (ViT-L): 12.6 + 2.1 + 7.3 = 22 MB, inside the
+# 32 MiB these kernels ask of the v5e's 128 MiB; at T = 384 the score
+# scratch alone is 28 MB. So 256: ViT at 224 px (196 tokens) and every
+# shorter sequence; 384 px (576 tokens) and up go dense or streaming.
+FUSED_MAX_TOKENS = 256
+_FUSED_VMEM_BYTES = 32 * 2 ** 20
+_LOG2E = 1.4426950408889634
+_NN = (((1,), (0,)), ((), ()))
+
+
+def fused_attention_fits(t: int, num_heads: int, dim: int) -> bool:
+    """Can `fused_attention` take T tokens of `num_heads` heads over `dim`
+    features? The sequence within one block, whole heads to a 128-lane
+    slab."""
+    return (t <= FUSED_MAX_TOKENS and dim % num_heads == 0
+            and dim % 128 == 0 and 128 % (dim // num_heads) == 0)
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _stage(dst, src, t):
+    """dst[:t] = src[0] and zero rows below: the padding lives in VMEM,
+    never in HBM. The zeros go first, from an aligned row."""
+    rows = dst.shape[0]
+    lo = (t // 16) * 16
+    if lo < rows:
+        dst[lo:, :] = jnp.zeros((rows - lo, dst.shape[1]), dst.dtype)
+    dst[0:t, :] = src[0]
+
+
+def _head_rows(xt, i, dh):
+    """A transposed slab (128, n) with every row but head i's zeroed."""
+    parts = [jnp.zeros((i * dh, xt.shape[1]), xt.dtype),
+             xt[i * dh:(i + 1) * dh],
+             jnp.zeros((128 - (i + 1) * dh, xt.shape[1]), xt.dtype)]
+    return jnp.concatenate([x for x in parts if x.shape[0]], axis=0)
+
+
+def _take_head_lanes(new, old, i, dh):
+    """`old` with head i's lanes of its 128-lane slab taken from `new`."""
+    if old is None:
+        return new
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    return jnp.where((lane >= i * dh) & (lane < (i + 1) * dh), new, old)
+
+
+def _per_image(*dims):
+    """A block of one image's (*dims) out of (B, *dims), a grid step each."""
+    return pl.BlockSpec((1, *dims), lambda i: (i, 0, 0))
+
+
+def _mask_padded_keys(s, t):
+    """S^T (key rows, queries): rows of padded keys to NEG_INF, before the
+    max. Only the last vregs of rows hold any."""
+    lo = (t // 8) * 8
+    if t == s.shape[0]:
+        return s
+    real = lo + jax.lax.broadcasted_iota(
+        jnp.int32, (s.shape[0] - lo, 1), 0) < t
+    tail = jnp.where(real, s[lo:], NEG_INF)
+    return tail if lo == 0 else jnp.concatenate([s[:lo], tail], axis=0)
+
+
+def _fused_fwd_kernel(qkv_ref, o_ref, *rest, heads: int, dh: int, t: int,
+                      scale: float, need_lse: bool):
+    if need_lse:
+        lse_ref, pad_scr, s_scr, e_scr, inv_scr = rest
+    else:
+        pad_scr, s_scr, e_scr, inv_scr = rest
+    d = heads * dh
+    g = 128 // dh
+    tp, tr = pad_scr.shape[0], s_scr.shape[1]
+    _stage(pad_scr, qkv_ref, t)
+    if tr < tp:  # key rows the P V contraction reads and the softmax skips
+        e_scr[:, tr:, :] = jnp.zeros((heads, tp - tr, tp), e_scr.dtype)
+    # 1: S^T = K Q^T of every head, MXU -> VMEM
+    for j in range(d // 128):
+        qt = pad_scr[:, j * 128:(j + 1) * 128].T
+        kp = pad_scr[0:tr, d + j * 128:d + (j + 1) * 128]
+        for i in range(g):
+            s = jax.lax.dot_general(kp, _head_rows(qt, i, dh), _NN,
+                                    preferred_element_type=jnp.float32)
+            s_scr[j * g + i] = _mask_padded_keys(s, t)
+    # 2: float32 softmax over the keys, 128 queries of a head at a time
+    for h in range(heads):
+        for c in range(tp // 128):
+            cols = slice(c * 128, (c + 1) * 128)
+            s = s_scr[h, :, cols]
+            m = jnp.max(s, axis=0, keepdims=True)
+            e = jnp.exp2((s - m) * (scale * _LOG2E))
+            l = jnp.sum(e, axis=0, keepdims=True)
+            e_scr[h, 0:tr, cols] = e.astype(e_scr.dtype)
+            inv_scr[h, 0:1, cols] = 1.0 / l
+            if need_lse:
+                inv_scr[h, 1:2, cols] = m * scale + jnp.log(l)
+    if need_lse:
+        for h in range(heads):
+            lse_ref[0, h:h + 1, :] = inv_scr[h, 1:2, 0:t]
+    # 3: O^T = V^T E^T / l, transposed into the out projection's layout
+    for j in range(d // 128):
+        vt = pad_scr[:, 2 * d + j * 128:2 * d + (j + 1) * 128].T
+        o_t = []
+        for i in range(g):
+            h = j * g + i
+            o_t.append(jax.lax.dot_general(
+                vt[i * dh:(i + 1) * dh], e_scr[h], _NN,
+                preferred_element_type=jnp.float32) * inv_scr[h, 0:1, :])
+        o = jnp.concatenate(o_t, axis=0).T
+        o_ref[0, :, j * 128:(j + 1) * 128] = o[0:t].astype(o_ref.dtype)
+
+
+# jitted: a model traces the same kernel once a block, forward and backward,
+# and the unrolled bodies take 0.4 s to trace and 0.4 s to lower a pair
+# (9.5 s of every ViT-B/16 start); an inner jit traces and lowers each once.
+@functools.partial(jax.jit, static_argnames=("heads", "scale", "interpret",
+                                             "need_lse"))
+def _fused_forward_shard(qkv, *, heads: int, scale: float, interpret: bool,
+                         need_lse: bool):
+    """-> (o (B, T, D), lse (B, H, T) float32 or None)."""
+    b, t, d3 = qkv.shape
+    d = d3 // 3
+    tp, tr = _round_up(t, 128), _round_up(t, 16)
+    out_shape = [jax.ShapeDtypeStruct((b, t, d), qkv.dtype)]
+    out_specs = [_per_image(t, d)]
+    if need_lse:
+        out_shape.append(jax.ShapeDtypeStruct((b, heads, t), jnp.float32))
+        out_specs.append(_per_image(heads, t))
+    res = pl.pallas_call(
+        functools.partial(_fused_fwd_kernel, heads=heads, dh=d // heads, t=t,
+                          scale=scale, need_lse=need_lse),
+        out_shape=out_shape,
+        grid=(b,),
+        in_specs=[_per_image(t, d3)],
+        out_specs=out_specs,
+        scratch_shapes=[
+            pltpu.VMEM((tp, d3), qkv.dtype),            # qkv, zero-padded
+            pltpu.VMEM((heads, tr, tp), jnp.float32),   # S^T
+            pltpu.VMEM((heads, tp, tp), qkv.dtype),     # exp(S^T - max)
+            pltpu.VMEM((heads, 8, tp), jnp.float32),    # 1 / sum; lse
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_FUSED_VMEM_BYTES),
+        interpret=interpret,
+        name="attn_fused_fwd",
+    )(qkv)
+    return (res[0], res[1]) if need_lse else (res[0], None)
+
+
+def _fused_bwd_kernel(qkv_ref, do_ref, lse_ref, dqkv_ref, pad_scr, do_scr,
+                      lse_scr, s_scr, dp_scr, p_scr, ds_scr, *, heads: int,
+                      dh: int, t: int, scale: float):
+    d = heads * dh
+    g = 128 // dh
+    tp, tr = pad_scr.shape[0], s_scr.shape[1]
+    _stage(pad_scr, qkv_ref, t)
+    _stage(do_scr, do_ref, t)
+    # a padded query's row of P is exp2(0 - 0) = 1 and finite; its dO is 0
+    lse_scr[...] = jnp.zeros(lse_scr.shape, jnp.float32)
+    for h in range(heads):
+        lse_scr[h, 0:1, 0:t] = lse_ref[0, h:h + 1, :] * _LOG2E
+    if tr < tp:  # key rows the dQ contraction reads and phase 2 skips
+        ds_scr[:, tr:, :] = jnp.zeros((heads, tp - tr, tp), ds_scr.dtype)
+    # 1: S^T = K Q^T and dP^T = V dO^T of every head, MXU -> VMEM
+    for j in range(d // 128):
+        sl = slice(j * 128, (j + 1) * 128)
+        kp = pad_scr[0:tr, d + j * 128:d + (j + 1) * 128]
+        vp = pad_scr[0:tr, 2 * d + j * 128:2 * d + (j + 1) * 128]
+        qt = pad_scr[:, sl].T
+        dot = do_scr[:, sl].T
+        for i in range(g):
+            h = j * g + i
+            s = jax.lax.dot_general(kp, _head_rows(qt, i, dh), _NN,
+                                    preferred_element_type=jnp.float32)
+            s_scr[h] = _mask_padded_keys(s, t)
+            dp_scr[h] = jax.lax.dot_general(
+                vp, _head_rows(dot, i, dh), _NN,
+                preferred_element_type=jnp.float32)
+    # 2: P^T from the saved log-sum-exp, and dS^T = P^T (dP^T - sum_k P dP)
+    for h in range(heads):
+        for c in range(tp // 128):
+            cols = slice(c * 128, (c + 1) * 128)
+            dp = dp_scr[h, :, cols]
+            p = jnp.exp2(s_scr[h, :, cols] * (scale * _LOG2E)
+                         - lse_scr[h, 0:1, cols])
+            delta = jnp.sum(p * dp, axis=0, keepdims=True)
+            p_scr[h, :, cols] = p.astype(p_scr.dtype)
+            ds_scr[h, 0:tr, cols] = (p * (dp - delta)).astype(ds_scr.dtype)
+    # 3: dV = P^T dO, dK = dS^T Q, dQ^T = K^T dS^T, into d(qkv)'s layout
+    for j in range(d // 128):
+        sl = slice(j * 128, (j + 1) * 128)
+        qp = pad_scr[:, sl]
+        dop = do_scr[:, sl]
+        kt = pad_scr[:, d + j * 128:d + (j + 1) * 128].T
+        dq_t, dk, dv = [], None, None
+        for i in range(g):
+            h = j * g + i
+            dv = _take_head_lanes(jax.lax.dot_general(
+                p_scr[h], dop, _NN, preferred_element_type=jnp.float32),
+                dv, i, dh)
+            dk = _take_head_lanes(jax.lax.dot_general(
+                ds_scr[h, 0:tr], qp, _NN,
+                preferred_element_type=jnp.float32), dk, i, dh)
+            dq_t.append(jax.lax.dot_general(
+                kt[i * dh:(i + 1) * dh], ds_scr[h], _NN,
+                preferred_element_type=jnp.float32))
+        dq = jnp.concatenate(dq_t, axis=0).T
+        dqkv_ref[0, :, sl] = (dq[0:t] * scale).astype(dqkv_ref.dtype)
+        dqkv_ref[0, :, d + j * 128:d + (j + 1) * 128] = (
+            dk[0:t] * scale).astype(dqkv_ref.dtype)
+        dqkv_ref[0, :, 2 * d + j * 128:2 * d + (j + 1) * 128] = dv[0:t].astype(
+            dqkv_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "scale", "interpret"))
+def _fused_backward_shard(qkv, lse, g, *, heads: int, scale: float,
+                          interpret: bool):
+    b, t, d3 = qkv.shape
+    d = d3 // 3
+    tp, tr = _round_up(t, 128), _round_up(t, 16)
+    return pl.pallas_call(
+        functools.partial(_fused_bwd_kernel, heads=heads, dh=d // heads, t=t,
+                          scale=scale),
+        out_shape=jax.ShapeDtypeStruct((b, t, d3), qkv.dtype),
+        grid=(b,),
+        in_specs=[_per_image(t, d3), _per_image(t, d),
+                  _per_image(heads, t)],
+        out_specs=_per_image(t, d3),
+        scratch_shapes=[
+            pltpu.VMEM((tp, d3), qkv.dtype),            # qkv, zero-padded
+            pltpu.VMEM((tp, d), qkv.dtype),             # dO, zero-padded
+            pltpu.VMEM((heads, 8, tp), jnp.float32),    # lse * log2(e)
+            pltpu.VMEM((heads, tr, tp), jnp.float32),   # S^T
+            pltpu.VMEM((heads, tr, tp), jnp.float32),   # dP^T
+            pltpu.VMEM((heads, tr, tp), qkv.dtype),     # P^T
+            pltpu.VMEM((heads, tp, tp), qkv.dtype),     # dS^T
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_FUSED_VMEM_BYTES),
+        interpret=interpret,
+        name="attn_fused_bwd",
+    )(qkv, g, lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
+def _fused(qkv, heads, scale, interpret):
+    # primal (inference) path: no log-sum-exp is computed or written
+    return over_data_axis(functools.partial(
+        _fused_forward_shard, heads=heads, scale=scale, interpret=interpret,
+        need_lse=False), (True,))(qkv)[0]
+
+
+def _fused_fwd(qkv, heads, scale, interpret):
+    o, lse = over_data_axis(functools.partial(
+        _fused_forward_shard, heads=heads, scale=scale, interpret=interpret,
+        need_lse=True), (True,))(qkv)
+    return o, (qkv, lse)
+
+
+def _fused_bwd(heads, scale, interpret, res, g):
+    qkv, lse = res
+    return (over_data_axis(functools.partial(
+        _fused_backward_shard, heads=heads, scale=scale,
+        interpret=interpret), (True, True, True))(qkv, lse, g),)
+
+
+_fused.defvjp(_fused_fwd, _fused_bwd)
+
+
+def fused_attention(qkv, num_heads: int, *, scale: Optional[float] = None,
+                    interpret: Optional[bool] = None):
+    """Self-attention of a sequence that fits one block (see
+    `fused_attention_fits`), fused, in the projections' layouts.
+
+    qkv: (B, T, 3*H*Dh), the last dimension ordered [q | k | v][head][Dh]
+    as `DenseGeneral((3, H, Dh))` writes it. Returns (B, T, H*Dh). The
+    arithmetic is the dense expression's: bf16 (the io dtype's) operands
+    into the MXU with float32 accumulation, softmax in float32 on the
+    float32 scores, probabilities in the io dtype for P V. Differentiable;
+    the backward recomputes P from the saved float32 log-sum-exp.
+    """
+    b, t, d3 = qkv.shape
+    if d3 % 3 or not fused_attention_fits(t, num_heads, d3 // 3):
+        raise ValueError(
+            f"fused_attention takes qkv of (B, T <= {FUSED_MAX_TOKENS}, "
+            f"3*H*Dh) with H*Dh a multiple of 128 and Dh dividing 128; got "
+            f"{qkv.shape} with {num_heads} heads")
+    if scale is None:
+        scale = (d3 // 3 // num_heads) ** -0.5
+    if interpret is None:
+        interpret = dvt_backend.pallas_interpret()
+    return _fused(qkv, int(num_heads), float(scale), bool(interpret))
+
+
 def _dense_reference(q, k, v, causal, scale):
     s = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale

@@ -36,6 +36,18 @@ def _context_mesh():
     return mesh
 
 
+def batch_parallel_only() -> bool:
+    """Is the data axis the only axis of the context mesh that spans
+    devices (manual axes aside: inside a shard_map a device holds its own
+    rows)? Under tensor parallelism the heads are split over another axis;
+    `over_data_axis` would gather them and run every head on every device
+    of that axis, where XLA partitions the dense expression by heads."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh.empty or all(
+        size == 1 for name, size in mesh.shape.items()
+        if name != DATA_AXIS and name not in mesh.manual_axes)
+
+
 def over_data_axis(kernel: Callable, batched: Sequence[bool]) -> Callable:
     """`kernel(*args)` with each `batched[i]` arg (and every output) split
     along dim 0 over the context mesh's data axis; other args replicate.

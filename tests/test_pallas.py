@@ -1,15 +1,21 @@
-"""Flash attention kernel: interpret-mode CPU tests against dense golden."""
+"""Attention kernels: interpret-mode CPU tests against dense golden.
+
+The streaming kernel's tests are jit-heavy and stay out of the fast tier
+(`-m "not slow"`); the single-block kernel's, below them, are in it: every
+ViT on a TPU takes that path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from deep_vision_tpu.ops.pallas.flash_attention import (
+    FUSED_MAX_TOKENS,
     _dense_reference,
     flash_attention,
+    fused_attention,
 )
 
-pytestmark = pytest.mark.slow  # jit-heavy: excluded from the fast tier (`-m "not slow"`)
+slow = pytest.mark.slow
 
 
 def _qkv(b=2, t=64, h=2, d=32, seed=0, tk=None):
@@ -21,6 +27,7 @@ def _qkv(b=2, t=64, h=2, d=32, seed=0, tk=None):
     return q, k, v
 
 
+@slow
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_matches_dense(causal):
     q, k, v = _qkv()
@@ -30,6 +37,7 @@ def test_flash_matches_dense(causal):
                                rtol=2e-4, atol=2e-5)
 
 
+@slow
 def test_flash_cross_attention_shapes():
     q, k, v = _qkv(t=32, tk=64)
     got = flash_attention(q, k, v, block_q=16, block_k=16)
@@ -39,6 +47,7 @@ def test_flash_cross_attention_shapes():
                                rtol=2e-4, atol=2e-5)
 
 
+@slow
 def test_flash_single_block():
     q, k, v = _qkv(t=16)
     got = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
@@ -47,6 +56,7 @@ def test_flash_single_block():
                                rtol=2e-4, atol=2e-5)
 
 
+@slow
 def test_flash_extreme_scores_stable():
     q, k, v = _qkv(seed=3)
     q = q * 120.0  # rows with true max << 0 must survive online softmax
@@ -57,6 +67,7 @@ def test_flash_extreme_scores_stable():
                                rtol=2e-3, atol=1e-4)
 
 
+@slow
 def test_flash_grads_match_dense():
     q, k, v = _qkv(b=1, t=32, h=1, d=16)
 
@@ -74,6 +85,7 @@ def test_flash_grads_match_dense():
                                    rtol=2e-3, atol=1e-4)
 
 
+@slow
 def test_flash_bf16_io():
     q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(t=32))
     got = flash_attention(q, k, v, block_q=16, block_k=16)
@@ -85,6 +97,7 @@ def test_flash_bf16_io():
     )
 
 
+@slow
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_bwd_kernel_matches_dense(causal):
     """The Pallas backward (dq + dkv kernels) vs autodiff of dense attention,
@@ -107,6 +120,7 @@ def test_flash_bwd_kernel_matches_dense(causal):
                                    rtol=2e-4, atol=2e-4, err_msg=name)
 
 
+@slow
 def test_flash_bwd_cross_attention():
     """Tq != Tk exercises the independent q/k grid extents in both kernels."""
     rng = np.random.RandomState(4)
@@ -127,6 +141,7 @@ def test_flash_bwd_cross_attention():
                                    rtol=2e-4, atol=2e-4)
 
 
+@slow
 def test_flash_bwd_bf16():
     rng = np.random.RandomState(5)
     q, k, v = (jnp.asarray(rng.randn(1, 32, 1, 8), jnp.bfloat16)
@@ -147,6 +162,7 @@ def test_flash_bwd_bf16():
                                    rtol=0.1, atol=0.1)
 
 
+@slow
 def test_flash_with_lse_grads_include_lse_cotangent():
     """A loss that uses BOTH outputs must differentiate exactly (the ring
     merge depends on lse; its cotangent shifts the delta term)."""
@@ -176,3 +192,119 @@ def test_flash_with_lse_grads_include_lse_cotangent():
     for a, b, name in zip(g1, g2, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-4, atol=5e-4, err_msg=name)
+
+
+# -- a sequence that fits one block: fused_attention --------------------------
+
+def _dense_from_qkv(qkv, heads):
+    """models/vit.py Attention's dense expression on the projection's output."""
+    b, t, d3 = qkv.shape
+    dh = d3 // 3 // heads
+    q, k, v = (qkv.reshape(b, t, 3, heads, dh)[:, :, i] for i in range(3))
+    s = jnp.einsum("bthd,bshd->bhts", q, k) * dh ** -0.5
+    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
+    return jnp.einsum("bhts,bshd->bthd", p, v).reshape(b, t, heads * dh)
+
+
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("heads", [12, 6])
+@pytest.mark.parametrize("t", [196, 50, 256])  # unaligned, tiny, aligned
+def test_fused_matches_dense_fwd_and_grad(t, heads, dtype, tol):
+    """Forward, and the gradient with respect to q, k and v, through the
+    qkv-in / o-out interface, against the dense expression."""
+    rng = np.random.RandomState(t + heads)
+    qkv = jnp.asarray(rng.randn(2, t, 3 * heads * 64), dtype)
+    g = jnp.asarray(rng.randn(2, t, heads * 64), dtype)
+    got, vjp = jax.vjp(lambda x: fused_attention(x, heads), qkv)
+    want, ref_vjp = jax.vjp(lambda x: _dense_from_qkv(x, heads), qkv)
+    assert got.shape == want.shape and got.dtype == dtype
+    f32 = lambda x: np.asarray(x, np.float32)
+    np.testing.assert_allclose(f32(got), f32(want), rtol=tol, atol=tol)
+    (dqkv,), (ref,) = vjp(g), ref_vjp(g)
+    assert dqkv.shape == qkv.shape and dqkv.dtype == dtype
+    for name, a, b in zip("qkv", np.split(f32(dqkv), 3, axis=-1),
+                          np.split(f32(ref), 3, axis=-1)):
+        np.testing.assert_allclose(a, b, rtol=tol, atol=tol * np.abs(b).max(),
+                                   err_msg=f"d{name}")
+    # the inference primal (no log-sum-exp written) is the same forward
+    np.testing.assert_array_equal(f32(fused_attention(qkv, heads)), f32(got))
+
+
+def test_fused_padded_keys_carry_no_probability():
+    """196 keys pad to 208 rows of zeros in VMEM, whose logits would be 0.
+    With every real logit at -128 an unmasked pad would take all of the
+    probability and the output would be V's padding, 0; masked before the
+    row max, the output is the mean of the 196 real values."""
+    t, heads = 196, 2
+    rng = np.random.RandomState(0)
+    v = rng.randn(2, t, heads * 64).astype(np.float32)
+    qkv = jnp.asarray(np.concatenate(
+        [np.full_like(v, 4.0), np.full_like(v, -4.0), v], axis=-1))
+    out = fused_attention(qkv, heads)
+    want = np.broadcast_to(v.mean(axis=1, keepdims=True), v.shape)
+    assert np.abs(want).max() > 0.1
+    np.testing.assert_allclose(np.asarray(out), want, rtol=1e-4, atol=1e-5)
+
+
+def test_fused_refuses_what_does_not_fit_one_block():
+    x = jnp.zeros((1, FUSED_MAX_TOKENS + 1, 3 * 128), jnp.float32)
+    with pytest.raises(ValueError, match="fused_attention takes"):
+        fused_attention(x, 2)
+    with pytest.raises(ValueError, match="fused_attention takes"):
+        fused_attention(jnp.zeros((1, 16, 3 * 192), jnp.float32), 4)  # Dh 48
+
+
+def _fake_platform(monkeypatch, name):
+    from deep_vision_tpu.core import backend
+
+    monkeypatch.setattr(backend, "current_platform", lambda: name)
+
+
+@pytest.mark.parametrize("t, tpu", [
+    (FUSED_MAX_TOKENS, "fused"), (FUSED_MAX_TOKENS + 1, "dense"),
+    (196, "fused"), (1024, "streaming"), (1536, "dense")])
+def test_attention_routes_by_shape(monkeypatch, t, tpu):
+    from deep_vision_tpu.models.vit import attention_path
+
+    assert attention_path(t, 12, 768) == "dense"  # the CPU: no compiled Pallas
+    _fake_platform(monkeypatch, "tpu")
+    assert attention_path(t, 12, 768) == tpu
+    # heads that do not fill 128-lane slabs keep the dense expression
+    assert attention_path(196, 4, 192) == "dense"
+
+
+def test_attention_stays_dense_where_the_mesh_splits_the_heads(
+        monkeypatch, mesh8, mesh4x2):
+    """Data-parallel meshes run the kernel per shard; under tensor
+    parallelism XLA partitions the dense expression by heads, which a
+    Mosaic call would have gathered."""
+    from deep_vision_tpu.models.vit import attention_path
+
+    _fake_platform(monkeypatch, "tpu")
+    with jax.set_mesh(mesh8):
+        assert attention_path(196, 12, 768) == "fused"
+    with jax.set_mesh(mesh4x2):
+        assert attention_path(196, 12, 768) == "dense"
+
+
+@pytest.mark.parametrize("platform, want", [
+    ("tpu", {"fused": 12, "streaming": 0, "dense": 0}),
+    ("cpu", {"fused": 0, "streaming": 0, "dense": 12})])
+def test_attention_sites_are_counted_by_path(monkeypatch, platform, want):
+    """`attention_sites_total{path=...}`: one increment per Attention site
+    traced. vit_b16 has 12; with compiled Pallas all take the fused path."""
+    from deep_vision_tpu.models import get_model
+    from deep_vision_tpu.obs.registry import get_registry
+
+    model = get_model("vit_b16", dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((2, 224, 224, 3), jnp.bfloat16)
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype),
+                           train=False))
+    count = lambda path: get_registry().counter(
+        "attention_sites_total", labels={"path": path}).value
+    _fake_platform(monkeypatch, platform)
+    before = {path: count(path) for path in want}
+    jax.eval_shape(lambda v, x: model.apply(v, x, train=False), variables, x)
+    assert {path: count(path) - before[path] for path in want} == want

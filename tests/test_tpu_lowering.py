@@ -9,9 +9,11 @@ running out of VMEM fails here too. Only execution (numbers, times) is left
 to chip_smoke.py. Shapes are the production ones chip_smoke.py runs.
 
 BatchNorm's tail was such a kernel until PR 30 and is plain jax.numpy now
-(tests/test_bn_tail.py); the last test here holds what that bought: a
-ResNet block compiled for the v5e has no custom call and no layout copy
-of an activation.
+(tests/test_bn_tail.py); a test here holds what that bought: a ResNet block
+compiled for the v5e has no custom call and no layout copy of an
+activation. The last test holds what the single-block attention kernel
+(PR 32) buys a ViT block and what it does not: no (T, T) tensor in the
+program, and exactly the four layout copies of the kernel's own operands.
 """
 import math
 import os
@@ -23,7 +25,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from deep_vision_tpu.ops.pallas.flash_attention import flash_attention
+from deep_vision_tpu.ops.pallas.flash_attention import (
+    flash_attention,
+    fused_attention,
+)
 from deep_vision_tpu.ops.pallas.nms import pallas_nms
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -48,6 +53,20 @@ def test_flash_attention_lowers_fwd_bwd(t, causal):
 
     q = S((2, t, 12, 64), jnp.bfloat16)
     lower_for_tpu(fwd_bwd, q, q, q)
+
+
+def _fused_fwd_bwd(qkv):
+    def loss(qkv):
+        return jnp.sum(fused_attention(qkv, 12, interpret=False)
+                       .astype(jnp.float32))
+    return jax.value_and_grad(loss)(qkv)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_fused_attention_lowers_fwd_bwd_at_vit_b16(dtype):
+    # the cell's shape: batch 128, 196 tokens, 3 x 12 heads x 64
+    text = lower_for_tpu(_fused_fwd_bwd, S((128, 196, 2304), dtype))
+    assert text.count("tpu_custom_call") == 2  # one forward, one backward
 
 
 @pytest.mark.parametrize("batch", [1, 8])
@@ -75,6 +94,8 @@ _Q = S((2, 1024, 12, 64), jnp.bfloat16)
     ("flash_bwd_dkv", _flash_fwd_bwd, (_Q, _Q, _Q)),
     ("nms", lambda b, s: pallas_nms(b, s, 100, 0.5, 0.5, interpret=False),
      (S((1, 10647, 4), jnp.float32), S((1, 10647), jnp.float32))),
+    ("attn_fused_fwd", _fused_fwd_bwd, (S((2, 196, 2304), jnp.bfloat16),)),
+    ("attn_fused_bwd", _fused_fwd_bwd, (S((2, 196, 2304), jnp.bfloat16),)),
 ])
 def test_each_kernel_carries_its_name_into_the_program(name, fn, specs):
     """A `name=` on every `pallas_call`: a trace's reader finds the kernel
@@ -85,18 +106,23 @@ def test_each_kernel_carries_its_name_into_the_program(name, fn, specs):
     assert re.search(rf"\b{name}\b", text), name
 
 
-def test_kernel_in_a_multi_device_program_needs_the_mesh_context(mesh8):
+@pytest.mark.parametrize("fn, shapes", [
+    (_flash_fwd_bwd, [(8, 1024, 12, 64)] * 3),
+    (_fused_fwd_bwd, [(8, 196, 2304)]),
+], ids=["streaming", "fused"])
+def test_kernel_in_a_multi_device_program_needs_the_mesh_context(
+        mesh8, fn, shapes):
     """XLA cannot partition a Mosaic call: in a program over 8 devices the
     lowering refuses it, and under the trainers' `jax.set_mesh` context the
     kernel runs per data-axis shard instead (ops/pallas/partition.py)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    q = S((8, 1024, 12, 64), jnp.bfloat16,
-          sharding=NamedSharding(mesh8, P("data")))
+    specs = [S(shape, jnp.bfloat16, sharding=NamedSharding(mesh8, P("data")))
+             for shape in shapes]
     with pytest.raises(NotImplementedError, match="shard_map"):
-        lower_for_tpu(_flash_fwd_bwd, q, q, q)
+        lower_for_tpu(fn, *specs)
     with jax.set_mesh(mesh8):
-        text = lower_for_tpu(_flash_fwd_bwd, q, q, q)
+        text = lower_for_tpu(fn, *specs)
     assert "all-gather" not in text and "all_gather" not in text
 
 
@@ -135,7 +161,7 @@ def _sharded(mesh, *arrays):
     return [jax.device_put(a, NamedSharding(mesh, P("data"))) for a in arrays]
 
 
-def test_flash_and_nms_per_shard_match_their_references(mesh8):
+def test_kernels_per_shard_match_their_references(mesh8):
     import numpy as np
 
     from deep_vision_tpu.ops import nms as lax_nms
@@ -156,6 +182,18 @@ def test_flash_and_nms_per_shard_match_their_references(mesh8):
             interpret=True))(q, k, v)
     want = attn(lambda q, k, v: fa._dense_reference(
         q, k, v, True, 8 ** -0.5))(q, k, v)
+    for u, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(u), np.asarray(w),
+                                   rtol=2e-4, atol=2e-4)
+
+    (qkv,) = _sharded(mesh8, rng.randn(8, 20, 3 * 128).astype(np.float32))
+    with jax.set_mesh(mesh8):
+        got = jax.jit(jax.value_and_grad(lambda x: jnp.sum(fused_attention(
+            x, 2, interpret=True) ** 2)))(qkv)
+    heads = lambda x, i: x[..., i * 128:(i + 1) * 128].reshape(8, 20, 2, 64)
+    want = jax.jit(jax.value_and_grad(lambda x: jnp.sum(fa._dense_reference(
+        heads(x, 0), heads(x, 1), heads(x, 2), False, 64 ** -0.5) ** 2)))(qkv)
     for u, w in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(np.asarray(u), np.asarray(w),
@@ -212,6 +250,10 @@ def test_nms_and_flash_compile_for_v5e(v5e):
 
     q = S((1, 4096, 12, 64), jnp.bfloat16)
     compile_for_v5e(v5e, fwd_bwd, q, q, q)
+    # the single-block kernel at its bound: 256 tokens, ViT-L's 16 heads
+    compile_for_v5e(v5e, jax.value_and_grad(lambda x: jnp.sum(fused_attention(
+        x, 16, interpret=False).astype(jnp.float32))),
+        S((8, 256, 3 * 1024), jnp.bfloat16))
 
 
 @pytest.mark.parametrize("hw, c", [(56, 256), (7, 2048)])
@@ -256,3 +298,50 @@ def test_resnet_blocks_compile_to_xla_fusions_alone(v5e, hw, c):
         r"= \w+\[([\d,]+)\]\S* (?:copy|reshape|transpose)\(", entry)
         if math.prod(map(int, m.group(1).split(","))) >= 128 * hw * hw * c]
     assert not moved, moved
+
+
+def test_vit_block_compiles_with_attention_in_vmem(v5e, monkeypatch):
+    """A ViTBlock at ViT-B/16's shape, bf16[128,196,768], forward and
+    backward, as a TPU routes it: the scores never reach the program (no
+    array with two 196 extents; the dense expression keeps ten passes over
+    a bf16[128,12,196,196] a block), and the attention is two Mosaic calls.
+
+    What the kernel does NOT buy (PERF.md §6, PR 32): XLA keeps this
+    model's activations batch-minor ({0,2,1}: the batch of 128 fills the
+    lanes, 196 tokens would pad them), and a Pallas call's operands are
+    row-major by contract, so each of the kernel's four operands (qkv, o,
+    dO, d(qkv)) costs one layout copy. Held here at exactly those four, so
+    that a fifth, or their removal, shows."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from deep_vision_tpu.core import backend
+    from deep_vision_tpu.models.vit import ViTBlock
+
+    monkeypatch.setattr(backend, "current_platform", lambda: "tpu")
+    block = ViTBlock(12, dtype=jnp.bfloat16)
+    x = S((128, 196, 768), jnp.bfloat16)
+    variables = jax.eval_shape(block.init, jax.random.PRNGKey(0), x)
+
+    def fwd_bwd(variables, x):
+        def loss(params, x):
+            y, _ = block.apply({"params": params}, x)
+            return jnp.sum(y.astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1))(variables["params"], x)
+
+    here = SingleDeviceSharding(v5e)
+    specs = jax.tree.map(lambda s: S(s.shape, s.dtype, sharding=here),
+                         (variables, x))
+    text = jax.jit(fwd_bwd).lower(*specs).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    assert entry.count('custom_call_target="tpu_custom_call"') == 2
+    for name in ("attn_fused_fwd", "attn_fused_bwd"):
+        assert re.search(rf"\b{name}\b", entry), name
+    shapes = [list(map(int, dims.split(",")))
+              for dims in re.findall(r"\w+\[([\d,]+)\]", entry)]
+    assert not [s for s in shapes if s.count(196) >= 2]
+    moved = [m.group(1) for m in re.finditer(
+        r"= \w+\[([\d,]+)\]\S* (?:copy|reshape|transpose)\(", entry)
+        if math.prod(map(int, m.group(1).split(","))) >= 128 * 196 * 768]
+    assert sorted(moved) == sorted(["128,196,2304"] * 2 + ["128,196,768"] * 2)
