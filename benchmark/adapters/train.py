@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import gc
 import importlib
+import math
 import shutil
 import sys
 import tempfile
@@ -224,9 +225,11 @@ def reference_steps(config, pool, seed, devices, control=False, rows=None):
         NamedSharding(mesh, P()), donate=True)
     batches = [jax.device_put(b, NamedSharding(mesh, P("rows")))
                for b in pool[:COMPARED_STEPS]]
-    return steps.run_steps(
-        module, config, variables, batches, control=control, rows=rows,
-        row_blocks=config.get("reference_row_blocks", 1))
+    row_blocks = config.get("reference_row_blocks", 1)
+    if rows is not None:  # a block stays whole rows where few rows are kept
+        row_blocks = math.gcd(row_blocks, rows)
+    return steps.run_steps(module, config, variables, batches,
+                           control=control, rows=rows, row_blocks=row_blocks)
 
 
 def as_program(reference_out):
@@ -283,6 +286,22 @@ def timed_window(trainer, pool, seconds, trace_dir):
     steps = len(stamps) - 1
     stamps[-1] = t_end  # the last step's interval runs to the window's close
     return steps, t_end - t_start, list(np.diff(stamps))
+
+
+def window_summary(intervals) -> dict:
+    """Whether the window stalled: its steps, the median and the longest
+    step interval, how many intervals took over three times the median,
+    and the five longest as `[step, ms]`, steps from 0. The last interval
+    runs to the window's close, over the step in flight too, so it is two
+    steps long."""
+    ms = np.asarray(intervals) * 1e3
+    median = float(np.median(ms))
+    longest = np.argsort(-ms, kind="stable")[:5]
+    return {"steps": len(ms),
+            "interval_median_ms": median,
+            "interval_max_ms": float(ms[longest[0]]),
+            "intervals_over_3x_median": int(np.sum(ms > 3 * median)),
+            "longest": [[int(i), float(ms[i])] for i in longest]}
 
 
 def memory_peak_bytes(devices) -> int:
@@ -356,6 +375,7 @@ def run(cell, config, traffic, seed, seconds, trace, t_process_start):
         "correct": correct and not faults, "faults": faults,
         "compared": compared, "attempted": steps, "failed": bad,
         "steps": steps, "window_s": window_s, "intervals_s": intervals,
+        "window": window_summary(intervals),
         "global_batch": traffic["global_batch"], "chips": cell["chips"],
         "setup_s": t_ready - t_process_start, "warmup_s": t_ready - t_warm,
         "reference_s": time.perf_counter() - t_ref,

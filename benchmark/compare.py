@@ -18,6 +18,14 @@ each with a limit of its own from the cell's file (`cells/<cell>.json`):
   element): a rule on the reference's gradient, applied inside leaves too,
   because a fused qkv bias holds the key's bias, which softmax leaves
   without a gradient, beside two that have one.
+
+`readings.py` reads a fourth beside them, which no run is judged by:
+`delta_distance`, the norm of the *difference* of the two changes over the
+same elements and the same scale. A leaf whose norm is right and whose
+elements lie elsewhere shows there alone: under a normalising optimizer
+the part of a leaf with a small gradient (q and k in a fused qkv kernel)
+moves as far as the rest, so noise on it is in neither `grad_gap` nor,
+at its size, `delta_gap` (PERF.md section 7, PR 33).
 """
 from __future__ import annotations
 
@@ -47,11 +55,17 @@ def median_with_gradient(norms: np.ndarray) -> float:
     return float(np.median(nonzero)) if nonzero.size else 0.0
 
 
-def worst_leaf_gap(prog: np.ndarray, ref: np.ndarray):
-    """-> (the widest gap, the index of its leaf)."""
+def worst_leaf(apart: np.ndarray, ref: np.ndarray):
+    """-> (the widest of `apart` over the reference's norm of that leaf or
+    of the median leaf, whichever is larger; the index of its leaf)."""
     scale = np.maximum(ref, median_with_gradient(ref))
-    gap = np.abs(prog - ref) / np.maximum(scale, 1e-300)
+    gap = apart / np.maximum(scale, 1e-300)
     return float(np.max(gap)), int(np.argmax(gap))
+
+
+def worst_leaf_gap(prog: np.ndarray, ref: np.ndarray):
+    """-> (the widest gap between the two norms, the index of its leaf)."""
+    return worst_leaf(np.abs(prog - ref), ref)
 
 
 def masked_delta_norms(delta_prog, delta_ref, grad_ref, floor: float):
@@ -71,9 +85,23 @@ def masked_delta_norms(delta_prog, delta_ref, grad_ref, floor: float):
     return np.sqrt(sums[:, 0]), np.sqrt(sums[:, 1])
 
 
-def gaps(program: dict, reference: dict, normalised_update: bool) -> dict:
+def masked_delta_distances(delta_prog, delta_ref, grad_ref, floor: float):
+    """Per-leaf norm of the two changes' difference, over the same
+    elements as `masked_delta_norms` keeps. A program of its own, so that
+    the norms' program is the one every run has always compiled."""
+    def norms(dp, dr, g, floor):
+        return [_sq((a - b) * (jnp.abs(c) >= floor).astype(jnp.float32))
+                for a, b, c in zip(*map(jax.tree.leaves, (dp, dr, g)))]
+
+    return np.sqrt(np.asarray(jax.device_get(jax.jit(norms)(
+        delta_prog, delta_ref, grad_ref, jnp.float32(floor))), np.float64))
+
+
+def gaps(program: dict, reference: dict, normalised_update: bool,
+         distance: bool = False) -> dict:
     """`program`: {"losses", "grad_norms": per leaf, "delta": tree};
-    `reference`: {"losses", "grad": tree, "delta": tree}; one leaf order."""
+    `reference`: {"losses", "grad": tree, "delta": tree}; one leaf order.
+    With `distance`, `delta_distance` beside the three numbers compared."""
     lp, lr = np.asarray(program["losses"]), np.asarray(reference["losses"])
     ref_grad_norms = leaf_norms(reference["grad"])
     floor = 0.0
@@ -89,12 +117,18 @@ def gaps(program: dict, reference: dict, normalised_update: bool) -> dict:
     grad_gap, grad_leaf = worst_leaf_gap(program["grad_norms"],
                                          ref_grad_norms)
     delta_gap, delta_leaf = worst_leaf_gap(dp, dr)
-    return {
+    out = {
         "loss_gap": float(np.max(np.abs(lp - lr) / np.abs(lr))),
         "grad_gap": grad_gap, "delta_gap": delta_gap,
         "worst_leaves": {"grad_gap": names[grad_leaf],
                          "delta_gap": names[delta_leaf]},
     }
+    if distance:
+        out["delta_distance"], leaf = worst_leaf(masked_delta_distances(
+            program["delta"], reference["delta"], reference["grad"], floor),
+            dr)
+        out["worst_leaves"]["delta_distance"] = names[leaf]
+    return out
 
 
 def judge(values: dict, limits: dict):

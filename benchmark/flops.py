@@ -64,6 +64,10 @@ def train_step_flops(module, cfg, batch_spec) -> float:
     and its jaxpr counts them. Where it defines none, the jaxpr count."""
     if hasattr(module, "step_flops"):
         return float(module.step_flops(cfg, batch_spec))
+    # a reference that recomputes in its backward pass (`reference_remat`)
+    # holds its forward twice in that jaxpr: the count is of the mathematics,
+    # so it is taken with the recomputation off (tracing allocates nothing)
+    cfg = {**cfg, "reference_remat": False}
     variables = jax.eval_shape(lambda: module.init(cfg, jax.random.PRNGKey(0)))
 
     def step(params, stats, batch):
@@ -73,3 +77,22 @@ def train_step_flops(module, cfg, batch_spec) -> float:
 
     return flops_of(step, variables["params"], variables["batch_stats"],
                     batch_spec)
+
+
+def attention_flops(batch: int, tokens: int, heads: int, head_dim: int,
+                    depth: int) -> float:
+    """FLOPs of the attention products of one training step, the
+    mathematics: a head of an image of a block multiplies Q K^T and P V
+    forward (2 x 2 T^2 d) and dP = dO V^T, dV = P^T dO, dQ = dS K,
+    dK = dS^T Q backward (4 x 2 T^2 d): 12 T^2 d. A kernel that recomputes
+    the scores in its backward pass executes more; that is not counted, so
+    the same work is read whatever implements it."""
+    return float(depth * batch * heads * 12 * tokens * tokens * head_dim)
+
+
+def attention_bytes(batch: int, tokens: int, heads: int, head_dim: int,
+                    depth: int, itemsize: int) -> float:
+    """The least HBM traffic of the same: q, k, v, o forward and dO, dq,
+    dk, dv backward, each once in the io dtype; no score ever leaves the
+    chip's near memory."""
+    return float(depth * 8 * batch * tokens * heads * head_dim * itemsize)

@@ -13,8 +13,10 @@ chip at a cell's own size, many seeds in one process:
   exchange between chips left out (the number of chips).
 
 Each reading is passed through `compare.judge` at the cell's own limits,
-as a run's is, and its row says whether it came out `correct`. Not run by
-the benchmark's own runs.
+as a run's is, and its row says whether it came out `correct`. Beside the
+three numbers compared a row holds `delta_distance` (`compare.py`): how far
+the elements of the worst leaf's change lie from the reference's, which no
+run is judged by. Not run by the benchmark's own runs.
 
 Memory: the programs' readings are all taken first and wait on the host,
 one float32 tree of the parameters (the change after three steps) a seed,
@@ -80,7 +82,7 @@ def main(argv=None):
     normalised = train.normalised_update(config)
 
     def judged(readings, reference):
-        values = compare.gaps(readings, reference, normalised)
+        values = compare.gaps(readings, reference, normalised, distance=True)
         values["correct"], _ = compare.judge(values, cell["limits"])
         return values
 

@@ -9,8 +9,16 @@ epsilon 1e-6, as flax's defaults that the program runs.
 
 Imports nothing of the program; the variable tree carries the program's
 leaf names. `q` rounds each matmul operand (identity for the reference).
+
+A configuration whose file says `"reference_remat": true` has every
+block recomputed in the backward pass (`jax.checkpoint`): the step then
+keeps each block's input and one block's scores, not every block's, which
+at 4096 tokens are 1.6 GB a block an image. Recomputation changes no
+mathematics, and the FLOP count is taken without it (`flops.py`).
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -91,6 +99,13 @@ def _block(q, x, p):
     return x + q(y) @ q(m["Dense_1"]["kernel"]) + m["Dense_1"]["bias"]
 
 
+def _block_of(cfg, q):
+    """`_block` as the configuration's file has it run: as it stands, or
+    recomputed in the backward pass."""
+    block = functools.partial(_block, q)
+    return jax.checkpoint(block) if cfg.get("reference_remat") else block
+
+
 def forward(cfg, variables, images, q=lambda x: x):
     """images: (B, H, W, C) -> (logits, {})."""
     params = variables["params"]
@@ -100,8 +115,9 @@ def forward(cfg, variables, images, q=lambda x: x):
         q(images.astype(jnp.float32)), q(pe["kernel"]), (p, p), "VALID",
         dimension_numbers=("NHWC", "HWIO", "NHWC")) + pe["bias"]
     x = x.reshape(x.shape[0], -1, x.shape[-1]) + params["pos_embed"]
+    block = _block_of(cfg, q)
     for i in range(cfg["depth"]):
-        x = _block(q, x, params[f"ViTBlock_{i}"])
+        x = block(x, params[f"ViTBlock_{i}"])
     x = jnp.mean(_ln(x, params["LayerNorm_0"]), axis=1)
     d = params["Dense_0"]
     return q(x) @ q(d["kernel"]) + d["bias"], {}

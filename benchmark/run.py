@@ -158,6 +158,8 @@ def run_cell(manifest, workload, seed, seconds, trace, require_chip=True):
             "wall_per_period_ms": red["window_s"] / red["periods"] * 1e3,
             "interval_median_ms": statistics.median(
                 record["intervals_s"]) * 1e3}
+    # whether the window stalled between two steps; read by no metric
+    result["window"] = record["window"]
     result["reference_s"] = record["reference_s"]
     result["faults"] = record["faults"]
     result["compared"] = record["compared"]

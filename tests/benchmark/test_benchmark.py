@@ -28,6 +28,8 @@ from benchmark.adapters import train as train_adapter  # noqa: E402
 from benchmark.reference import resnet, steps, vit  # noqa: E402
 
 REHEARSAL = os.path.join(ROOT, "tests", "benchmark", "rehearsal.json")
+# the cells that wait outside BENCHMARK.json, a manifest of their own
+WAITING = os.path.join(ROOT, "benchmark", "waiting.json")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
@@ -189,6 +191,102 @@ def test_a_written_count_is_the_count_and_the_jaxpr_stands_in_for_none():
     spec = {"x": jax.ShapeDtypeStruct((4, 8), jnp.float32)}
     assert flops.train_step_flops(_Plain, cfg, spec) == 2 * (2 * 4 * 8 * 8)
     assert flops.train_step_flops(_Written, cfg, spec) == 4 * 8 * 8
+
+
+def benchmark_config(name):
+    with open(os.path.join(ROOT, "benchmark", "configs", name + ".json")) as f:
+        return json.load(f)
+
+
+def test_flops_of_vit_b16_1024px_are_the_mathematics_not_the_recomputation():
+    """`reference_remat` puts every block's forward into the jaxpr of
+    `value_and_grad` twice; the count is taken with it off, and is three
+    times the forward's, as the closed form has it."""
+    config = benchmark_config("vit_b16_1024px")
+    assert config["reference_remat"] is True
+    traffic = {"kind": "resident_pool", "global_batch": 1, "pool_batches": 1}
+    spec = traffic_mod.batch_spec(traffic, config, (1024, 1024, 3))
+    counted = flops.train_step_flops(vit, config, spec)
+    without = {k: v for k, v in config.items() if k != "reference_remat"}
+    assert counted == flops.train_step_flops(vit, without, spec)
+    variables = jax.eval_shape(lambda: vit.init(config, jax.random.PRNGKey(0)))
+    forward = flops.flops_of(
+        lambda p, b: vit.loss_fn(without, p, {}, b)[0], variables["params"],
+        spec)
+    # no gradient flows to the image: the patch embedding has one backward
+    # product, every other matmul two
+    t, d, patch_in = 4096, 768, 16 * 16 * 3
+    embed = 2.0 * t * patch_in * d
+    assert counted == 3 * forward - embed
+    per_token = 2.0 * (4 * d * d + 2 * 4 * d * d)  # qkv, out, the MLP's two
+    closed = 3 * (12 * (t * per_token + 4.0 * t * t * d) + 2.0 * d * 1000) \
+        + 2 * embed
+    assert counted == closed
+    # the reference as the cell runs it does hold the blocks' forward twice
+    # (but for each block's last product, which no gradient needs again)
+    remat = flops.flops_of(
+        lambda p, b: jax.value_and_grad(
+            lambda p: vit.loss_fn(config, p, {}, b)[0])(p),
+        variables["params"], spec)
+    assert remat > counted + 0.8 * forward
+
+
+@pytest.mark.parametrize("batch,tokens,heads,head_dim", [(2, 16, 3, 8),
+                                                         (1, 64, 2, 16)])
+def test_attention_flops_are_the_six_products_of_the_plain_form(
+        batch, tokens, heads, head_dim):
+    """The written count against the jaxpr's, of the reference's two score
+    einsums and the softmax between them under `value_and_grad`."""
+    def attention(q, k, v):
+        s = jnp.einsum("bthk,bshk->bhts", q, k) * head_dim ** -0.5
+        return jnp.sum(jnp.einsum("bhts,bshk->bthk",
+                                  jax.nn.softmax(s, axis=-1), v))
+
+    x = jax.ShapeDtypeStruct((batch, tokens, heads, head_dim), jnp.float32)
+    counted = flops.flops_of(jax.value_and_grad(attention, argnums=(0, 1, 2)),
+                             x, x, x)
+    assert counted == flops.attention_flops(batch, tokens, heads, head_dim,
+                                            depth=1)
+    assert flops.attention_flops(batch, tokens, heads, head_dim, depth=5) \
+        == 5 * counted
+    assert flops.attention_bytes(batch, tokens, heads, head_dim, 5, 2) \
+        == 5 * 8 * x.size * 2
+
+
+def roofline_record(ops, cell=None, trace=True):
+    config = {"input_shape": [64, 32, 3], "patch": 8, "num_heads": 2,
+              "dim": 32, "depth": 3, "compute_dtype": "bfloat16"}
+    return {"trace": {"op_s_per_step": ops} if trace else None,
+            "peaks": {"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e9},
+            "config": config, "global_batch": 8, "chips": 4,
+            "cell": {"attention_kernel_ops": ["flash_fwd", "flash_bwd_dq",
+                                              "flash_bwd_dkv"]}
+            if cell is None else cell}
+
+
+def test_flash_roofline_reads_the_hand_worked_share_or_nothing():
+    reader = run.load_py(os.path.join(ROOT, "benchmark", "metrics",
+                                      "flash_roofline.py"))
+    ops = {"flash_fwd.1": 1e-3, "flash_fwd.12": 2e-3, "flash_bwd_dq": 3e-3,
+           "flash_bwd_dkv.7": 4e-3,
+           # not the kernels: another op, and one that only shares letters
+           "fusion.3": 5.0, "flash_fwd_other.2": 7.0, "flash_bwd_dqx": 9.0}
+    # 2 rows a chip, 8 x 4 = 32 tokens, 2 heads of 16, 3 blocks:
+    # 3 * 2 * 2 * 12 * 32^2 * 16 = 2,359,296 FLOP at 1e9/s = 2.359296 ms,
+    # over 10 ms of kernels; the bytes (3 * 8 * 2 * 32 * 32 * 2 = 98,304 at
+    # 1e9/s) are the lower roof
+    assert reader.read(roofline_record(ops)) == pytest.approx(23.59296)
+    # a short sequence is held to the memory roof: with FLOPs a thousand
+    # times cheaper the bytes' 0.098304 ms over 10 ms
+    cheap = roofline_record(ops)
+    cheap["peaks"]["bf16_flops_per_s"] = 1e12
+    assert reader.read(cheap) == pytest.approx(0.98304)
+    # nothing to read: no trace, no peaks, no kernel op in the step (the
+    # dense fall-back), a cell that names no ops
+    assert reader.read(roofline_record(ops, trace=False)) is None
+    assert reader.read({**roofline_record(ops), "peaks": None}) is None
+    assert reader.read(roofline_record({"fusion.3": 5.0})) is None
+    assert reader.read(roofline_record(ops, cell={})) is None
 
 
 # -- the feed ----------------------------------------------------------------
@@ -508,10 +606,65 @@ def test_reference_matches_the_programs_model(tiny_models, family, module,
             jnp.linalg.norm(b)) + 1e-7
 
 
+def test_a_recomputed_block_changes_no_mathematics():
+    """`"reference_remat": true` against the same configuration without
+    it: the loss and every leaf of the gradient, and through `run_steps`
+    (one image a row block) each step's loss, the first gradient and the
+    parameters' change."""
+    config = rehearsal_config("tiny_vit_tokens")
+    plain = {k: v for k, v in config.items() if k != "reference_remat"}
+    rng = np.random.RandomState(0)
+    batch = {"image": rng.rand(8, 64, 64, 3).astype(np.float32),
+             "label": rng.randint(0, 10, (8,)).astype(np.int32)}
+    params = vit.init(config, jax.random.PRNGKey(3))["params"]
+    grad = lambda cfg: jax.jit(jax.value_and_grad(
+        lambda p: vit.loss_fn(cfg, p, {}, batch)[0]))(params)
+    with jax.default_matmul_precision("highest"):
+        (lr, gr), (lp, gp) = grad(config), grad(plain)
+    assert float(lr) == pytest.approx(float(lp), rel=1e-6)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(gr)[0],
+                            jax.tree.leaves(gp)):
+        assert float(jnp.linalg.norm(a - b)) <= 1e-5 * float(
+            jnp.linalg.norm(b)) + 1e-9, jax.tree_util.keystr(path)
+    # the jaxprs differ: the recomputing one holds its blocks under remat
+    text = lambda cfg: str(jax.make_jaxpr(jax.grad(
+        lambda p: vit.loss_fn(cfg, p, {}, batch)[0]))(params))
+    assert ("checkpoint" in text(config) or "remat" in text(config))
+    assert "checkpoint" not in text(plain) and "remat" not in text(plain)
+    follow = lambda cfg: steps.run_steps(
+        vit, cfg, vit.init(cfg, jax.random.PRNGKey(3)), [batch] * 3,
+        row_blocks=cfg["reference_row_blocks"])
+    got, want = follow(config), follow(plain)
+    assert got["losses"] == pytest.approx(want["losses"], rel=1e-6)
+    for key in ("grad", "delta"):
+        for a, b in zip(jax.tree.leaves(got[key]),
+                        jax.tree.leaves(want[key])):
+            assert float(jnp.linalg.norm(a - b)) <= 1e-4 * float(
+                jnp.linalg.norm(b)) + 1e-9, key
+
+
+def test_a_fault_that_keeps_fewer_rows_than_row_blocks_keeps_whole_rows():
+    """Half of a batch of 8 left out under `reference_row_blocks` 8: four
+    blocks of one row, and the reading is the reference's over those four
+    rows alone."""
+    config = rehearsal_config("tiny_vit_tokens")
+    _, _, traffic = run.resolve(run.load_manifest(REHEARSAL),
+                                "tiny_vit_tokens_train")
+    pool = traffic_mod.make_pool(traffic, config, (64, 64, 3), 5)
+    devices = jax.devices()[:1]
+    halved = train_adapter.reference_steps(config, pool, 5, devices, rows=4)
+    cut = [{k: v[:4] for k, v in batch.items()} for batch in pool]
+    want = train_adapter.reference_steps(
+        {**config, "reference_row_blocks": 1}, cut, 5, devices)
+    whole = train_adapter.reference_steps(config, pool, 5, devices)
+    assert halved["losses"] == pytest.approx(want["losses"], rel=1e-5)
+    assert halved["losses"][0] != pytest.approx(whole["losses"][0], rel=1e-4)
+
+
 # -- the manifest ------------------------------------------------------------
 
 @pytest.mark.parametrize("path", [os.path.join(ROOT, "BENCHMARK.json"),
-                                  REHEARSAL])
+                                  REHEARSAL, WAITING])
 def test_manifest_is_consistent(path):
     m = run.load_manifest(path)
     configs = {c["name"]: c for c in m["configs"]}
@@ -551,6 +704,48 @@ def test_manifest_is_consistent(path):
         assert NAME.match(name)
 
 
+def test_a_waiting_cell_is_no_cell_of_the_benchmark_and_is_ready_to_enter():
+    """`benchmark/waiting.json` runs its cells through the same harness
+    (`--manifest`); what it holds beside them is BENCHMARK.json's own, so
+    that admitting a cell is moving its entries over and nothing else."""
+    real = run.load_manifest(os.path.join(ROOT, "BENCHMARK.json"))
+    waiting = run.load_manifest(WAITING)
+    names = lambda m, group: [x["name"] for x in m[group]]
+    for group in ("configs", "workloads"):
+        assert not set(names(real, group)) & set(names(waiting, group))
+    assert "flash_roofline" not in names(real, "per_layer")
+    for key in ("paths", "run_seconds", "end_to_end"):
+        assert waiting[key] == real[key]
+    own = [x for x in waiting["per_layer"] if x not in real["per_layer"]]
+    assert names({"own": own}, "own") == ["flash_roofline"]
+    assert [x for x in waiting["per_layer"] if x not in own] \
+        == real["per_layer"]
+    assert waiting["command"][-2:] == [
+        "--manifest", os.path.relpath(WAITING, ROOT)]
+
+
+@pytest.mark.parametrize("path,own", [
+    (WAITING, "vit_b16_train_1024px"),
+    (REHEARSAL, "tiny_vit_tokens_train")])
+def test_flash_roofline_is_read_in_its_own_cell_and_no_other(path, own):
+    m = run.load_manifest(path)
+    metric = {x["name"]: x for x in m["per_layer"]}["flash_roofline"]
+    assert metric["layer"] == "kernels" and metric["unit"] == "%"
+    read_in = [c["name"] for c in m["workloads"]
+               if run.applies(metric, c["name"])]
+    assert read_in == [own]
+    # its cell's file names the ops, and its configuration the recomputation
+    cell, config, traffic = run.resolve(m, own)
+    assert cell["attention_kernel_ops"] == ["flash_fwd", "flash_bwd_dq",
+                                            "flash_bwd_dkv"]
+    assert config["reference_remat"] is True
+    assert config["reference_row_blocks"] == traffic["global_batch"] == 8
+    # the other cells' references are the parent's: no recomputation
+    for c in m["workloads"]:
+        if c["name"] != own:
+            assert "reference_remat" not in run.resolve(m, c["name"])[1]
+
+
 def test_run_refuses_a_real_cell_without_the_chip():
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     done = subprocess.run(
@@ -569,7 +764,8 @@ def rehearse(cell):
                         0.3, 0, require_chip=False)
 
 
-@pytest.mark.parametrize("cell", ["tiny_resnet_train", "tiny_vit_train"])
+@pytest.mark.parametrize("cell", ["tiny_resnet_train", "tiny_vit_train",
+                                  "tiny_vit_tokens_train"])
 def test_rehearsal_run_is_correct(tiny_models, cell):
     result = rehearse(cell)
     assert result["correct"], (result["compared"], result["faults"])
@@ -578,9 +774,60 @@ def test_rehearsal_run_is_correct(tiny_models, cell):
                                       "setup_s"}
     assert result["attempted"] > 0 and result["failed"] == 0
     assert list(result)[-1] == "compared"
+    # whether the window stalled, from every step interval of it
+    window = result["window"]
+    assert window["steps"] == result["attempted"]
+    assert 0 < window["interval_median_ms"] <= window["interval_max_ms"]
+    assert window["longest"][0][1] == window["interval_max_ms"]
+    assert all(0 <= step < window["steps"] for step, _ in window["longest"])
+    assert 0 <= window["intervals_over_3x_median"] < window["steps"]
     # both peak readings, before the reference and after the comparison
     device = result["device"]
     assert device["memory_peak_bytes_after"] >= device["memory_peak_bytes"]
+
+
+def test_the_distance_sees_a_leaf_whose_norm_is_right_and_elements_not():
+    """`delta_distance`, which `readings.py` reads beside the numbers
+    compared: two leaves of four elements, one of them rotated, so that
+    its norm is the reference's and its elements are not; and an element
+    under the rule on the reference's gradient counts for nothing."""
+    ref = {"a": jnp.array([3.0, 0.0, 4.0, 9.0]),
+           "b": jnp.array([1.0, 2.0, 2.0, 0.0])}
+    got = {"a": jnp.array([0.0, 3.0, 4.0, -9.0]), "b": ref["b"]}
+    # every gradient element 1, but `a`'s last: under a thousandth
+    grad = {"a": jnp.array([1.0, 1.0, 1.0, 1e-6]), "b": jnp.ones(4)}
+    program = {"losses": [1.0], "delta": got,
+               "grad_norms": compare.leaf_norms(grad)}
+    reference = {"losses": [1.0], "grad": grad, "delta": ref}
+    values = compare.gaps(program, reference, True, distance=True)
+    assert values["delta_gap"] == 0.0
+    # |(3, -3, 0)| over |(3, 0, 4)|
+    assert values["delta_distance"] == pytest.approx(18 ** 0.5 / 5)
+    assert values["worst_leaves"]["delta_distance"] == "['a']"
+    assert "delta_distance" not in compare.gaps(program, reference, True)
+    # no run is judged by it
+    limits = {"loss_gap": 1e-4, "grad_gap": 1e-3, "delta_gap": 1e-3}
+    assert compare.judge(values, limits)[0]
+    # without the rule (an optimizer that does not normalise) all count
+    assert compare.gaps(program, reference, False, distance=True)[
+        "delta_distance"] == pytest.approx((18 + 18 ** 2) ** 0.5
+                                           / (25 + 81) ** 0.5)
+
+
+def test_the_window_summary_names_the_stall():
+    """A window of 40 steps of 100 ms with one gap of 2.1 s before step 17
+    and one slow step under three times the median."""
+    intervals = [0.1] * 40
+    intervals[17], intervals[30] = 2.1, 0.25
+    assert train_adapter.window_summary(intervals) == {
+        "steps": 40, "interval_median_ms": pytest.approx(100.0),
+        "interval_max_ms": pytest.approx(2100.0),
+        "intervals_over_3x_median": 1,
+        "longest": [[17, pytest.approx(2100.0)], [30, pytest.approx(250.0)],
+                    [0, pytest.approx(100.0)], [1, pytest.approx(100.0)],
+                    [2, pytest.approx(100.0)]]}
+    assert train_adapter.window_summary([0.1, 0.1, 0.1])[
+        "intervals_over_3x_median"] == 0
 
 
 def test_a_state_kept_in_bfloat16_is_kept_so_by_program_and_reference(
@@ -723,13 +970,15 @@ def test_every_number_is_held_to_its_limit():
 
 
 @pytest.mark.parametrize("cell", ["tiny_resnet_train", "tiny_vit_train",
-                                  "tiny_vit_bf16state_train"])
+                                  "tiny_vit_bf16state_train",
+                                  "tiny_vit_tokens_train"])
 def test_the_lower_precision_control_fails(cell):
     """The reference computed in bfloat16, the precision below the float32
     these rehearsal configurations state, put in the program's place and
     judged at the cell's limits."""
     cell, config, traffic = run.resolve(run.load_manifest(REHEARSAL), cell)
-    shape = (16, 16, 12) if config["reference"] == "resnet" else (32, 32, 3)
+    shape = (16, 16, 12) if config["reference"] == "resnet" \
+        else tuple(config["input_shape"])
     pool = traffic_mod.make_pool(traffic, config, shape, 5)
     devices = jax.devices()[:1]
     reference = train_adapter.reference_steps(config, pool, 5, devices)
@@ -739,7 +988,9 @@ def test_the_lower_precision_control_fails(cell):
     same, _ = compare.judge(compare.gaps(
         train_adapter.as_program(reference), reference, normalised),
         cell["limits"])
-    ok, compared = compare.judge(compare.gaps(
-        train_adapter.as_program(control), reference, normalised),
-        cell["limits"])
+    values = compare.gaps(train_adapter.as_program(control), reference,
+                          normalised, distance=True)
+    ok, compared = compare.judge(values, cell["limits"])
     assert same and not ok, compared
+    # elements lie at least as far apart as their norms do
+    assert values["delta_distance"] >= values["delta_gap"] > 0
