@@ -69,6 +69,28 @@ def attention_path(t: int, num_heads: int, dim: int) -> str:
     return "dense"
 
 
+class QkvProjection(nn.Module):
+    """`nn.DenseGeneral((3, H, Dh))` with its bias handed back, not added:
+    -> (x @ kernel (B, T, 3, H, Dh), bias (3, H, Dh)). The fused attention
+    adds the bias itself, because its backward kernel returns the bias's
+    gradient (`fused_attention`). The parameters are DenseGeneral's own,
+    `kernel` and `bias` in this module's scope, from the same initialisers
+    in the same order: a seed gives the same weights, and a checkpoint
+    loads, whichever of the two wrote it."""
+
+    num_heads: int
+    dtype: Optional[jnp.dtype] = None
+
+    @nn.compact
+    def __call__(self, x):
+        features = (3, self.num_heads, x.shape[-1] // self.num_heads)
+        dense = nn.DenseGeneral(features, use_bias=False, dtype=self.dtype)
+        nn.share_scope(self, dense)
+        y = dense(x)
+        bias = self.param("bias", nn.initializers.zeros, features, jnp.float32)
+        return y, bias
+
+
 class Attention(nn.Module):
     num_heads: int
     dtype: Optional[jnp.dtype] = None
@@ -78,17 +100,16 @@ class Attention(nn.Module):
         b, t, d = x.shape
         h = self.num_heads
         assert d % h == 0, f"dim {d} not divisible by {h} heads"
-        qkv = nn.DenseGeneral((3, h, d // h), dtype=self.dtype,
-                              name="qkv")(x)  # (B, T, 3, H, Dh)
+        qkv, bias = QkvProjection(h, dtype=self.dtype, name="qkv")(x)
         path = attention_path(t, h, d)
         # the choice is made while tracing, so that is where it is counted
         get_registry().counter(
             "attention_sites_total", "Attention sites traced, by the path "
             "their shape chose", labels={"path": path}).inc()
         if path == "fused":
-            o = fused_attention(qkv.reshape(b, t, 3 * d), h)
-            o = o.reshape(b, t, h, d // h)
+            o = fused_attention(qkv, h, bias).reshape(b, t, h, d // h)
         else:
+            qkv = qkv + bias.astype(qkv.dtype)  # (B, T, 3, H, Dh)
             q, k, v = (qkv[:, :, i] for i in range(3))  # (B, T, H, Dh)
             if path == "streaming":
                 o = flash_attention(q, k, v)

@@ -20,7 +20,10 @@ sequential grid:
     across the inner k loop;
   - dkv kernel: grid (BH, k_blocks, q_blocks), dk/dv accumulate across the
     inner q loop.
-Both use delta = rowsum(dO * O), computed outside (one fused XLA pass).
+Both take delta = rowsum(dO * O), computed outside (one fused XLA pass) from
+the forward's float32 output, which is that pass's residual: the output
+rounded to bf16 put dq 47-292% off where tokens are alike
+(`_flash_backward_shard`; PERF.md §6, PR 34).
 
 Grid layout note: TPU executes the grid sequentially (last dim fastest), so
 VMEM scratch legally carries accumulators across the innermost dimension —
@@ -35,6 +38,7 @@ come from that sweep.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -147,10 +151,18 @@ def _flash_forward(q, k, v, **kw):
 
 def _flash_forward_shard(q, k, v, *, causal: bool, scale: float, block_q: int,
                          block_k: int, interpret: bool, need_lse: bool = True):
-    """Returns (out (B,T,H,D), lse (B*H, T, 128) f32 lane-broadcast).
+    """Returns (out (B,T,H,D), lse (B*H, T, 128) f32 lane-broadcast, o32
+    (B,T,H,D) float32).
 
-    With need_lse=False (the inference-only primal) the lse output and its
-    HBM write are elided entirely and None is returned for it."""
+    o32 is the output before it is rounded to the io dtype: the backward
+    takes `delta` from it (`_flash_backward_shard` says why), so with
+    need_lse the kernel writes float32 and `out` is XLA's cast of it, inside
+    the transpose the output pays anyway. Kept in the model's layout, not
+    the kernel's (B*H, T, D): 64 lanes of a head pad to 128 in HBM, and 12
+    padded float32 residuals are 1.2 GB more peak at 8 x 4096 tokens where
+    these are 0.5 GB less than the rounded ones were (PERF.md §6, PR 34).
+    With need_lse=False (the inference-only primal) the kernel rounds, lse
+    and its HBM write are elided entirely, and None is returned for both."""
     b, t, h, d = q.shape
     tk = k.shape[1]
     block_q = min(block_q, t)
@@ -167,7 +179,8 @@ def _flash_forward_shard(q, k, v, *, causal: bool, scale: float, block_q: int,
         _flash_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, need_lse=need_lse,
     )
-    out_shape = [jax.ShapeDtypeStruct((b * h, t, d), q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct(
+        (b * h, t, d), jnp.float32 if need_lse else q.dtype)]
     out_specs = [pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0))]
     if need_lse:
         # lse broadcast across a 128-lane minor dim: Mosaic requires
@@ -195,8 +208,10 @@ def _flash_forward_shard(q, k, v, *, causal: bool, scale: float, block_q: int,
         interpret=interpret,
         name="flash_fwd",
     )(qr, kr, vr)
-    out, lse = res if need_lse else (res[0], None)
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3), lse
+    o = res[0].reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    if not need_lse:
+        return o, None, None
+    return o.astype(q.dtype), res[1], o
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -288,14 +303,14 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, out, lse, g, *, delta_shift=None, **kw):
+def _flash_backward(q, k, v, o32, lse, g, *, delta_shift=None, **kw):
     """`_flash_backward_shard` per data-axis shard (see _flash_forward); a
     None delta_shift is an empty pytree its spec does not touch."""
     return over_data_axis(functools.partial(_flash_backward_shard, **kw),
-                          (True,) * 7)(q, k, v, out, lse, g, delta_shift)
+                          (True,) * 7)(q, k, v, o32, lse, g, delta_shift)
 
 
-def _flash_backward_shard(q, k, v, out, lse, g, delta_shift=None, *,
+def _flash_backward_shard(q, k, v, o32, lse, g, delta_shift=None, *,
                           causal: bool, scale: float, block_q: int,
                           block_k: int, interpret: bool):
     b, t, h, d = q.shape
@@ -306,14 +321,17 @@ def _flash_backward_shard(q, k, v, out, lse, g, delta_shift=None, *,
     kr = k.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
     vr = v.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
     dor = g.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-    outr = out.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     # delta = rowsum(dO * O): one fused elementwise+reduce pass in XLA,
     # broadcast across the 128-lane residual layout (see _flash_forward).
+    # O is the forward's float32 output, NOT the one rounded to the io
+    # dtype: in ds = P (dP - delta) the part of dP common to a row cancels
+    # only if delta is that row's sum_j P dP. An O rounded to bf16 leaves
+    # its rounding error times the row's MEAN key in dq, and the true dq is
+    # made of the keys' deviations from that mean: where tokens are alike
+    # the error wins (dq 47-292% off, PERF.md §6, PR 34).
     # `delta_shift` (an lse cotangent, _flash_lse_bwd) subtracts in here.
-    delta_row = jnp.sum(
-        dor.astype(jnp.float32) * outr.astype(jnp.float32),
-        axis=-1, keepdims=True,
-    )
+    delta_row = jnp.sum(g.astype(jnp.float32) * o32, axis=-1).transpose(
+        0, 2, 1).reshape(b * h, t, 1)
     if delta_shift is not None:
         delta_row = delta_row - delta_shift[..., None]
     delta = jnp.broadcast_to(delta_row, (b * h, t, 128))
@@ -370,7 +388,10 @@ def _flash_backward_shard(q, k, v, out, lse, g, delta_shift=None, *,
 # HBM, and operands in the layouts the neighbouring projections write and
 # read: in, the qkv projection's output (B, T, 3*H*Dh), heads picked out in
 # the kernel; out, (B, T, H*Dh), which the out projection contracts over.
-# The backward saves only the float32 log-sum-exp (B, H, T) and recomputes P.
+# The backward saves only the float32 log-sum-exp (B, H, T) and recomputes P;
+# it takes delta as sum_k P dP from the float32 tiles it holds, and hands back
+# the projection bias's gradient (d(qkv) summed over an image's tokens) from
+# the same tiles, which is why `fused_attention` adds that bias itself.
 #
 # Shaped by the v5e's schedule (bundle dumps, PERF.md §6 PR 32):
 # - Scores are held KEY-major, S^T (keys on sublanes, queries on lanes): a
@@ -544,9 +565,9 @@ def _fused_forward_shard(qkv, *, heads: int, scale: float, interpret: bool,
     return (res[0], res[1]) if need_lse else (res[0], None)
 
 
-def _fused_bwd_kernel(qkv_ref, do_ref, lse_ref, dqkv_ref, pad_scr, do_scr,
-                      lse_scr, s_scr, dp_scr, p_scr, ds_scr, *, heads: int,
-                      dh: int, t: int, scale: float):
+def _fused_bwd_kernel(qkv_ref, do_ref, lse_ref, dqkv_ref, dbias_ref, pad_scr,
+                      do_scr, lse_scr, s_scr, dp_scr, p_scr, ds_scr, *,
+                      heads: int, dh: int, t: int, scale: float):
     d = heads * dh
     g = 128 // dh
     tp, tr = pad_scr.shape[0], s_scr.shape[1]
@@ -602,27 +623,38 @@ def _fused_bwd_kernel(qkv_ref, do_ref, lse_ref, dqkv_ref, pad_scr, do_scr,
                 kt[i * dh:(i + 1) * dh], ds_scr[h], _NN,
                 preferred_element_type=jnp.float32))
         dq = jnp.concatenate(dq_t, axis=0).T
-        dqkv_ref[0, :, sl] = (dq[0:t] * scale).astype(dqkv_ref.dtype)
-        dqkv_ref[0, :, d + j * 128:d + (j + 1) * 128] = (
-            dk[0:t] * scale).astype(dqkv_ref.dtype)
-        dqkv_ref[0, :, 2 * d + j * 128:2 * d + (j + 1) * 128] = dv[0:t].astype(
-            dqkv_ref.dtype)
+        # The qkv bias's gradient is d(qkv) summed over tokens: taken here
+        # from the float32 tiles, a pass over d(qkv) in HBM otherwise. Whole
+        # tiles are summed, padded rows too, which hold exact zeros: a
+        # padded query's dO is 0, so are its dP, delta and dS column, hence
+        # its dQ row; a padded key's P and dS rows are exp2(-huge) = 0 or
+        # zeroed above, hence its dK and dV rows.
+        for part, tile, by in ((0, dq, scale), (1, dk, scale), (2, dv, None)):
+            lanes = slice(part * d + j * 128, part * d + (j + 1) * 128)
+            rows, total = tile[0:t], jnp.sum(tile, axis=0, keepdims=True)
+            if by is not None:
+                rows, total = rows * by, total * by
+            dqkv_ref[0, :, lanes] = rows.astype(dqkv_ref.dtype)
+            dbias_ref[0, :, lanes] = total
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "scale", "interpret"))
 def _fused_backward_shard(qkv, lse, g, *, heads: int, scale: float,
                           interpret: bool):
+    """-> (d(qkv) (B, T, 3D), its sum over tokens an image (B, 1, 3D)
+    float32: per-image partials, so that the grid stays `parallel`)."""
     b, t, d3 = qkv.shape
     d = d3 // 3
     tp, tr = _round_up(t, 128), _round_up(t, 16)
     return pl.pallas_call(
         functools.partial(_fused_bwd_kernel, heads=heads, dh=d // heads, t=t,
                           scale=scale),
-        out_shape=jax.ShapeDtypeStruct((b, t, d3), qkv.dtype),
+        out_shape=[jax.ShapeDtypeStruct((b, t, d3), qkv.dtype),
+                   jax.ShapeDtypeStruct((b, 1, d3), jnp.float32)],
         grid=(b,),
         in_specs=[_per_image(t, d3), _per_image(t, d),
                   _per_image(heads, t)],
-        out_specs=_per_image(t, d3),
+        out_specs=[_per_image(t, d3), _per_image(1, d3)],
         scratch_shapes=[
             pltpu.VMEM((tp, d3), qkv.dtype),            # qkv, zero-padded
             pltpu.VMEM((tp, d), qkv.dtype),             # dO, zero-padded
@@ -640,54 +672,77 @@ def _fused_backward_shard(qkv, lse, g, *, heads: int, scale: float,
     )(qkv, g, lse)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def _fused(qkv, heads, scale, interpret):
+def _biased(qkv, bias):
+    """(B, T, *bias.shape) + bias -> the kernels' (B, T, 3D). The add is made
+    in the projection's own shape, BEFORE the reshape: there XLA puts it in
+    the projection matmul's epilogue; after it, it is a pass of its own
+    over the activation (PERF.md §6, PR 34)."""
+    b, t = qkv.shape[:2]
+    return (qkv + bias.astype(qkv.dtype)).reshape(b, t, -1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _fused(qkv, bias, heads, scale, interpret):
     # primal (inference) path: no log-sum-exp is computed or written
     return over_data_axis(functools.partial(
         _fused_forward_shard, heads=heads, scale=scale, interpret=interpret,
-        need_lse=False), (True,))(qkv)[0]
+        need_lse=False), (True,))(_biased(qkv, bias))[0]
 
 
-def _fused_fwd(qkv, heads, scale, interpret):
+def _fused_fwd(qkv, bias, heads, scale, interpret):
+    x = _biased(qkv, bias)
     o, lse = over_data_axis(functools.partial(
         _fused_forward_shard, heads=heads, scale=scale, interpret=interpret,
-        need_lse=True), (True,))(qkv)
-    return o, (qkv, lse)
+        need_lse=True), (True,))(x)
+    return o, (x, lse, bias)
 
 
 def _fused_bwd(heads, scale, interpret, res, g):
-    qkv, lse = res
-    return (over_data_axis(functools.partial(
+    x, lse, bias = res
+    dx, dbias = over_data_axis(functools.partial(
         _fused_backward_shard, heads=heads, scale=scale,
-        interpret=interpret), (True, True, True))(qkv, lse, g),)
+        interpret=interpret), (True, True, True))(x, lse, g)
+    return (dx.reshape(*x.shape[:2], *bias.shape),
+            jnp.sum(dbias, axis=(0, 1)).reshape(bias.shape).astype(bias.dtype))
 
 
 _fused.defvjp(_fused_fwd, _fused_bwd)
 
 
-def fused_attention(qkv, num_heads: int, *, scale: Optional[float] = None,
+def fused_attention(qkv, num_heads: int, bias=None, *,
+                    scale: Optional[float] = None,
                     interpret: Optional[bool] = None):
     """Self-attention of a sequence that fits one block (see
     `fused_attention_fits`), fused, in the projections' layouts.
 
     qkv: (B, T, 3*H*Dh), the last dimension ordered [q | k | v][head][Dh]
-    as `DenseGeneral((3, H, Dh))` writes it. Returns (B, T, H*Dh). The
-    arithmetic is the dense expression's: bf16 (the io dtype's) operands
-    into the MXU with float32 accumulation, softmax in float32 on the
-    float32 scores, probabilities in the io dtype for P V. Differentiable;
-    the backward recomputes P from the saved float32 log-sum-exp.
+    as `DenseGeneral((3, H, Dh))` writes it, or that projection's own
+    (B, T, 3, H, Dh). bias: the projection's, of qkv's shape past (B, T);
+    it is added here and not by the caller, because the backward kernel
+    holds d(qkv) in VMEM and hands back the bias's gradient from there,
+    where XLA would read d(qkv) from HBM once more to sum it. Returns
+    (B, T, H*Dh). The arithmetic is the dense expression's: bf16 (the io
+    dtype's) operands into the MXU with float32 accumulation, softmax in
+    float32 on the float32 scores, probabilities in the io dtype for P V.
+    Differentiable in qkv and bias; the backward recomputes P from the
+    saved float32 log-sum-exp.
     """
-    b, t, d3 = qkv.shape
-    if d3 % 3 or not fused_attention_fits(t, num_heads, d3 // 3):
+    d3 = math.prod(qkv.shape[2:])
+    if d3 % 3 or not fused_attention_fits(qkv.shape[1], num_heads, d3 // 3):
         raise ValueError(
             f"fused_attention takes qkv of (B, T <= {FUSED_MAX_TOKENS}, "
             f"3*H*Dh) with H*Dh a multiple of 128 and Dh dividing 128; got "
             f"{qkv.shape} with {num_heads} heads")
+    if bias is None:
+        bias = jnp.zeros(qkv.shape[2:], qkv.dtype)
+    if bias.shape != qkv.shape[2:]:
+        raise ValueError(f"fused_attention takes a bias of qkv's shape past "
+                         f"(B, T), {qkv.shape[2:]}; got {bias.shape}")
     if scale is None:
         scale = (d3 // 3 // num_heads) ** -0.5
     if interpret is None:
         interpret = dvt_backend.pallas_interpret()
-    return _fused(qkv, int(num_heads), float(scale), bool(interpret))
+    return _fused(qkv, bias, int(num_heads), float(scale), bool(interpret))
 
 
 def _dense_reference(q, k, v, causal, scale):
@@ -704,22 +759,21 @@ def _dense_reference(q, k, v, causal, scale):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
     # primal (inference) path: skip computing/writing the lse residual
-    out, _ = _flash_forward(q, k, v, causal=causal, scale=scale,
-                            block_q=block_q, block_k=block_k,
-                            interpret=interpret, need_lse=False)
-    return out
+    return _flash_forward(q, k, v, causal=causal, scale=scale,
+                          block_q=block_q, block_k=block_k,
+                          interpret=interpret, need_lse=False)[0]
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    out, lse = _flash_forward(q, k, v, causal=causal, scale=scale,
-                              block_q=block_q, block_k=block_k,
-                              interpret=interpret)
-    return out, (q, k, v, out, lse)
+    out, lse, o32 = _flash_forward(q, k, v, causal=causal, scale=scale,
+                                   block_q=block_q, block_k=block_k,
+                                   interpret=interpret)
+    return out, (q, k, v, o32, lse)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
-    q, k, v, out, lse = res
-    return _flash_backward(q, k, v, out, lse, g, causal=causal, scale=scale,
+    q, k, v, o32, lse = res
+    return _flash_backward(q, k, v, o32, lse, g, causal=causal, scale=scale,
                            block_q=block_q, block_k=block_k,
                            interpret=interpret)
 
@@ -731,14 +785,14 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 def _flash_lse(q, k, v, causal, scale, block_q, block_k, interpret):
     return _flash_forward(q, k, v, causal=causal, scale=scale,
                           block_q=block_q, block_k=block_k,
-                          interpret=interpret, need_lse=True)
+                          interpret=interpret, need_lse=True)[:2]
 
 
 def _flash_lse_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    out, lse = _flash_forward(q, k, v, causal=causal, scale=scale,
-                              block_q=block_q, block_k=block_k,
-                              interpret=interpret, need_lse=True)
-    return (out, lse), (q, k, v, out, lse)
+    out, lse, o32 = _flash_forward(q, k, v, causal=causal, scale=scale,
+                                   block_q=block_q, block_k=block_k,
+                                   interpret=interpret, need_lse=True)
+    return (out, lse), (q, k, v, o32, lse)
 
 
 def _flash_lse_bwd(causal, scale, block_q, block_k, interpret, res, cts):
@@ -750,13 +804,12 @@ def _flash_lse_bwd(causal, scale, block_q, block_k, interpret, res, cts):
     ds = p (dp - (delta - g_lse)) scale. The kernels take delta as an input,
     so the shift needs no kernel change.
     """
-    q, k, v, out, lse = res
+    q, k, v, o32, lse = res
     g_out, g_lse = cts
-    b, t, h, d = q.shape
     # cotangent of the 128-lane broadcast = sum over lanes
     g_lse_row = jnp.sum(g_lse.astype(jnp.float32), axis=-1)  # (BH, T)
     return _flash_backward(
-        q, k, v, out, lse, g_out, causal=causal, scale=scale,
+        q, k, v, o32, lse, g_out, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret,
         delta_shift=g_lse_row,
     )

@@ -1,8 +1,10 @@
 """Attention kernels: interpret-mode CPU tests against dense golden.
 
-The streaming kernel's tests are jit-heavy and stay out of the fast tier
-(`-m "not slow"`); the single-block kernel's, below them, are in it: every
-ViT on a TPU takes that path."""
+The streaming kernel's older tests are jit-heavy and stay out of the fast
+tier (`-m "not slow"`), and their unit-normal inputs cannot see a wrong
+`delta` (PERF.md §6, PR 34): `test_flash_bwd_where_tokens_are_alike` is in
+the fast tier for that. The single-block kernel's tests, below them, are in
+it too: every ViT on a TPU takes that path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,7 @@ from deep_vision_tpu.ops.pallas.flash_attention import (
     FUSED_MAX_TOKENS,
     _dense_reference,
     flash_attention,
+    flash_attention_with_lse,
     fused_attention,
 )
 
@@ -194,6 +197,66 @@ def test_flash_with_lse_grads_include_lse_cotangent():
                                    rtol=5e-4, atol=5e-4, err_msg=name)
 
 
+def _rel(got, want):
+    """Distance of `got` from `want`, as a share of `want`'s norm."""
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+@pytest.mark.parametrize("with_lse", [False, True], ids=["out", "out+lse"])
+@pytest.mark.parametrize("causal", [False, True],
+                         ids=["bidirectional", "causal"])
+@pytest.mark.parametrize("c", [0, 3, 10])
+def test_flash_bwd_where_tokens_are_alike(c, causal, with_lse):
+    """dq, dk and dv in bf16 against autodiff of the float32 dense
+    expression on the same bf16 values, where every token is a common
+    vector (c times a unit normal one) plus its own unit normal part, in q,
+    k and v alike. In ds = P (dP - delta) what is common to a row of dP must
+    cancel, and the true dq is what the keys' deviations from their mean
+    leave: with `delta` taken from the bf16-rounded output, dq read 47% off
+    at c = 3 and 292% at c = 10 (17%, 130% causal), dk 1.3%, and nothing at
+    c = 0, where every older test sits. The dense bf16 path reads <= 1.7%.
+    With an lse cotangent, `delta_shift` rides the same sum (the ring's
+    merge, parallel/ring_attention.py)."""
+    b, t, h, dh = 1, 1024, 2, 64
+    rng = np.random.RandomState(c)
+    q, k, v = (jnp.asarray(c * rng.randn(1, 1, h, dh) + rng.randn(b, t, h, dh),
+                           jnp.bfloat16) for _ in range(3))
+    g = jnp.asarray(rng.randn(b, t, h, dh), jnp.float32)
+    g_lse = jnp.asarray(rng.randn(b * h, t), jnp.float32)
+    scale = 0.1 * dh ** -0.5
+
+    def f_flash(q, k, v):
+        if not with_lse:
+            out = flash_attention(q, k, v, causal=causal, scale=scale,
+                                  block_q=256, block_k=256)
+            return jnp.vdot(out.astype(jnp.float32), g)
+        out, lse = flash_attention_with_lse(q, k, v, causal=causal,
+                                            scale=scale, block_q=256,
+                                            block_k=256)
+        return (jnp.vdot(out.astype(jnp.float32), g)
+                + jnp.vdot(lse[:, :, 0], g_lse))
+
+    def f_dense(q, k, v):
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        s = jnp.einsum("bthd,bshd->bhts", q, k, precision="highest") * scale
+        if causal:
+            s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -1e30)
+        out = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(s, axis=-1), v,
+                         precision="highest")
+        loss = jnp.vdot(out, g)
+        if with_lse:
+            lse = jax.scipy.special.logsumexp(s, axis=-1)  # (B, H, T)
+            loss = loss + jnp.vdot(lse.reshape(b * h, t), g_lse)
+        return loss
+
+    got = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(f_dense, argnums=(0, 1, 2))(q, k, v)
+    for name, a, w in zip(("dq", "dk", "dv"), got, want):
+        assert a.dtype == jnp.bfloat16
+        assert _rel(a, w) < 0.01, (name, _rel(a, w))
+
+
 # -- a sequence that fits one block: fused_attention --------------------------
 
 def _dense_from_qkv(qkv, heads):
@@ -206,29 +269,53 @@ def _dense_from_qkv(qkv, heads):
     return jnp.einsum("bhts,bshd->bthd", p, v).reshape(b, t, heads * dh)
 
 
+@pytest.mark.parametrize("with_bias", [False, True], ids=["qkv", "qkv+bias"])
 @pytest.mark.parametrize("dtype, tol", [(jnp.float32, 2e-5),
                                         (jnp.bfloat16, 3e-2)])
 @pytest.mark.parametrize("heads", [12, 6])
 @pytest.mark.parametrize("t", [196, 50, 256])  # unaligned, tiny, aligned
-def test_fused_matches_dense_fwd_and_grad(t, heads, dtype, tol):
+def test_fused_matches_dense_fwd_and_grad(t, heads, dtype, tol, with_bias):
     """Forward, and the gradient with respect to q, k and v, through the
-    qkv-in / o-out interface, against the dense expression."""
+    qkv-in / o-out interface, against the dense expression. With a bias:
+    the projection's output in its own (B, T, 3, H, Dh) and its bias, added
+    before the dense expression; the bias's gradient is what the backward
+    kernel sums over an image's tokens in VMEM, where rows 197-208 and up
+    of its tiles are padding and must add nothing."""
     rng = np.random.RandomState(t + heads)
     qkv = jnp.asarray(rng.randn(2, t, 3 * heads * 64), dtype)
     g = jnp.asarray(rng.randn(2, t, heads * 64), dtype)
-    got, vjp = jax.vjp(lambda x: fused_attention(x, heads), qkv)
-    want, ref_vjp = jax.vjp(lambda x: _dense_from_qkv(x, heads), qkv)
+    bias = None
+    if with_bias:
+        qkv = qkv.reshape(2, t, 3, heads, 64)
+        bias = jnp.asarray(rng.randn(3, heads, 64), jnp.float32)
+
+    def dense(x, b):
+        x = x if b is None else x + b.astype(x.dtype)
+        return _dense_from_qkv(x.reshape(2, t, -1), heads)
+
+    got, vjp = jax.vjp(lambda x, b: fused_attention(x, heads, b), qkv, bias)
+    want, ref_vjp = jax.vjp(dense, qkv, bias)
     assert got.shape == want.shape and got.dtype == dtype
     f32 = lambda x: np.asarray(x, np.float32)
     np.testing.assert_allclose(f32(got), f32(want), rtol=tol, atol=tol)
-    (dqkv,), (ref,) = vjp(g), ref_vjp(g)
+    (dqkv, dbias), (ref, ref_bias) = vjp(g), ref_vjp(g)
     assert dqkv.shape == qkv.shape and dqkv.dtype == dtype
-    for name, a, b in zip("qkv", np.split(f32(dqkv), 3, axis=-1),
-                          np.split(f32(ref), 3, axis=-1)):
+    for name, a, b in zip("qkv", np.split(f32(dqkv).reshape(2, t, -1), 3, -1),
+                          np.split(f32(ref).reshape(2, t, -1), 3, -1)):
         np.testing.assert_allclose(a, b, rtol=tol, atol=tol * np.abs(b).max(),
                                    err_msg=f"d{name}")
+    if with_bias:
+        # sums of 2 t rounded terms: held by the norm, not element by
+        # element; over all three parts, because k's is nought but for
+        # rounding (a vector added to every key moves each row of scores
+        # by a constant)
+        assert dbias.shape == bias.shape and dbias.dtype == jnp.float32
+        assert _rel(dbias, ref_bias) < tol
+        with pytest.raises(ValueError, match="takes a bias of"):
+            fused_attention(qkv, heads, bias.reshape(-1))
     # the inference primal (no log-sum-exp written) is the same forward
-    np.testing.assert_array_equal(f32(fused_attention(qkv, heads)), f32(got))
+    np.testing.assert_array_equal(f32(fused_attention(qkv, heads, bias)),
+                                  f32(got))
 
 
 def test_fused_padded_keys_carry_no_probability():
@@ -308,3 +395,44 @@ def test_attention_sites_are_counted_by_path(monkeypatch, platform, want):
     before = {path: count(path) for path in want}
     jax.eval_shape(lambda v, x: model.apply(v, x, train=False), variables, x)
     assert {path: count(path) - before[path] for path in want} == want
+
+
+@pytest.mark.parametrize("dtype", [None, jnp.bfloat16], ids=["f32", "bf16"])
+def test_attention_keeps_dense_generals_parameters(dtype):
+    """`Attention` projects through `QkvProjection`, which hands its bias to
+    the attention rather than adding it. Its parameters are those of the
+    `nn.DenseGeneral((3, H, Dh), name="qkv")` it replaced: the same tree, and
+    from a seed the same values to the bit, so that a seeded reference
+    (benchmark/reference/vit.py) starts from the program's weights and a
+    checkpoint of either loads into the other. On the dense path the output
+    is DenseGeneral's too."""
+    import flax.linen as nn
+
+    from deep_vision_tpu.models.vit import Attention
+
+    class Before(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            qkv = nn.DenseGeneral((3, 4, 64), dtype=dtype, name="qkv")(x)
+            q, k, v = (qkv[:, :, i] for i in range(3))
+            s = jnp.einsum("bthd,bshd->bhts", q, k) * 64 ** -0.5
+            p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
+            o = jnp.einsum("bhts,bshd->bthd", p, v)
+            return nn.DenseGeneral(256, axis=(-2, -1), dtype=dtype,
+                                   name="out")(o)
+
+    x = jnp.asarray(np.random.RandomState(0).randn(2, 20, 256), jnp.float32)
+    want = Before().init(jax.random.PRNGKey(7), x)
+    got = Attention(4, dtype=dtype).init(jax.random.PRNGKey(7), x)
+    assert jax.tree.map(jnp.shape, got) == {"params": {
+        "qkv": {"kernel": (256, 3, 4, 64), "bias": (3, 4, 64)},
+        "out": {"kernel": (4, 64, 256), "bias": (256,)}}}
+    assert (jax.tree_util.tree_structure(got)
+            == jax.tree_util.tree_structure(want))
+    jax.tree.map(np.testing.assert_array_equal, got, want)
+    # a bias that is not nought, so that the add is seen
+    want["params"]["qkv"]["bias"] = jnp.asarray(
+        np.random.RandomState(1).randn(3, 4, 64), jnp.float32)
+    np.testing.assert_array_equal(
+        np.asarray(Attention(4, dtype=dtype).apply(want, x), np.float32),
+        np.asarray(Before().apply(want, x), np.float32))

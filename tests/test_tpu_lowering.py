@@ -13,7 +13,10 @@ BatchNorm's tail was such a kernel until PR 30 and is plain jax.numpy now
 compiled for the v5e has no custom call and no layout copy of an
 activation. The last test holds what the single-block attention kernel
 (PR 32) buys a ViT block and what it does not: no (T, T) tensor in the
-program, and exactly the four layout copies of the kernel's own operands.
+program, and exactly the four layout copies of the kernel's own operands;
+the one after it what the backward kernel's second output buys (PR 34): the
+qkv bias's gradient without a pass over d(qkv), the bias still in the
+projection matmul's epilogue.
 """
 import math
 import os
@@ -300,6 +303,38 @@ def test_resnet_blocks_compile_to_xla_fusions_alone(v5e, hw, c):
     assert not moved, moved
 
 
+def _vit_blocks_hlo(v5e, monkeypatch, blocks: int) -> str:
+    """The optimized HLO of `blocks` ViTBlocks at ViT-B/16's shape,
+    bf16[128,196,768], forward and backward, as a TPU routes them."""
+    from jax.sharding import SingleDeviceSharding
+
+    from deep_vision_tpu.core import backend
+    from deep_vision_tpu.models.vit import ViTBlock
+
+    monkeypatch.setattr(backend, "current_platform", lambda: "tpu")
+
+    class Net(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            for _ in range(blocks):
+                x, _ = ViTBlock(12, dtype=jnp.bfloat16)(x)
+            return x
+
+    x = S((128, 196, 768), jnp.bfloat16)
+    variables = jax.eval_shape(Net().init, jax.random.PRNGKey(0), x)
+
+    def fwd_bwd(variables, x):
+        def loss(params, x):
+            y = Net().apply({"params": params}, x)
+            return jnp.sum(y.astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1))(variables["params"], x)
+
+    here = SingleDeviceSharding(v5e)
+    specs = jax.tree.map(lambda s: S(s.shape, s.dtype, sharding=here),
+                         (variables, x))
+    return jax.jit(fwd_bwd).lower(*specs).compile().as_text()
+
+
 def test_vit_block_compiles_with_attention_in_vmem(v5e, monkeypatch):
     """A ViTBlock at ViT-B/16's shape, bf16[128,196,768], forward and
     backward, as a TPU routes it: the scores never reach the program (no
@@ -314,26 +349,7 @@ def test_vit_block_compiles_with_attention_in_vmem(v5e, monkeypatch):
     that a fifth, or their removal, shows."""
     import re
 
-    from jax.sharding import SingleDeviceSharding
-
-    from deep_vision_tpu.core import backend
-    from deep_vision_tpu.models.vit import ViTBlock
-
-    monkeypatch.setattr(backend, "current_platform", lambda: "tpu")
-    block = ViTBlock(12, dtype=jnp.bfloat16)
-    x = S((128, 196, 768), jnp.bfloat16)
-    variables = jax.eval_shape(block.init, jax.random.PRNGKey(0), x)
-
-    def fwd_bwd(variables, x):
-        def loss(params, x):
-            y, _ = block.apply({"params": params}, x)
-            return jnp.sum(y.astype(jnp.float32))
-        return jax.value_and_grad(loss, argnums=(0, 1))(variables["params"], x)
-
-    here = SingleDeviceSharding(v5e)
-    specs = jax.tree.map(lambda s: S(s.shape, s.dtype, sharding=here),
-                         (variables, x))
-    text = jax.jit(fwd_bwd).lower(*specs).compile().as_text()
+    text = _vit_blocks_hlo(v5e, monkeypatch, 1)
     entry = text[text.index("\nENTRY "):]
     assert entry.count('custom_call_target="tpu_custom_call"') == 2
     for name in ("attn_fused_fwd", "attn_fused_bwd"):
@@ -345,3 +361,48 @@ def test_vit_block_compiles_with_attention_in_vmem(v5e, monkeypatch):
         r"= \w+\[([\d,]+)\]\S* (?:copy|reshape|transpose)\(", entry)
         if math.prod(map(int, m.group(1).split(","))) >= 128 * 196 * 768]
     assert sorted(moved) == sorted(["128,196,2304"] * 2 + ["128,196,768"] * 2)
+
+
+def test_vit_blocks_take_the_qkv_bias_gradient_from_the_kernel(v5e,
+                                                               monkeypatch):
+    """Two ViTBlocks at ViT-B/16's shape, forward and backward. The qkv
+    bias's gradient is d(qkv) summed over images and tokens; `attn_fused_bwd`
+    sums each image's in VMEM (its second output, f32[128,1,2304]), so the
+    program holds no `reduce` over the bf16[128,196,2304] the kernel wrote
+    (a pass of 115.6 MB a block, 2.0 ms a step over ViT-B's 12: PERF.md §6,
+    PR 34). Where the bias is ADDED decides whether that is a gain: in the
+    projection's own (B, T, 3, H, Dh) it stays in the projection matmul's
+    epilogue (a `convolution` fusion that takes the [3,12,64] bias); after
+    the reshape to (B, T, 2304) XLA splits it out as a pass of its own over
+    the activation, which costs more than the `reduce` did. So nothing may
+    write a tensor of that size but, a block: the projection, the two
+    kernels' operands' copies in and the backward kernel and its copy out."""
+    import re
+
+    text = _vit_blocks_hlo(v5e, monkeypatch, 2)
+    entry = text[text.index("\nENTRY "):]
+    instr = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$",
+                       re.M)
+    size = 128 * 196 * 2304
+    sizes, writes, reduced = {}, [], []
+    for name, result, op, rest in instr.findall(entry):
+        sizes[name] = [math.prod(map(int, dims.split(",")))
+                       for dims in re.findall(r"\w+\[([\d,]+)\]", result)]
+        if op == "reduce":
+            reduced += [(name, operand) for operand in re.findall(
+                r"%([\w.\-]+)", rest.split(")")[0])
+                if size in sizes.get(operand, ())]
+        if size in sizes[name] and op not in ("bitcast", "get-tuple-element"):
+            writes.append((op, name, rest))
+    assert not reduced, reduced
+    assert (sorted(op for op, _, _ in writes)
+            == ["copy"] * 4 + ["custom-call"] * 2 + ["fusion"] * 2), [
+                w[:2] for w in writes]
+    assert all("attn_fused_bwd" in name
+               for op, name, _ in writes if op == "custom-call")
+    for op, name, rest in writes:
+        if op == "fusion":  # the projection, the bias inside
+            called = re.search(r"calls=%([\w.\-]+)", rest).group(1)
+            body = text[text.index(f"\n%{called} ("):]
+            body = body[:body.index("\n}")]
+            assert " convolution(" in body and "[3,12,64]" in body, name
