@@ -86,15 +86,15 @@ def _scale_bias_act(x, scale, bias, residual, act: Optional[str]):
 def _scale_bias_act_bwd(act, res, g):
     """-> (dx, dscale, dbias, dresidual) from the saved (x, scale, bias, y)."""
     x, scale, bias, y = res
-    gf = g.astype(jnp.float32)
-    if act == "relu":
-        gf = jnp.where(y > 0, gf, 0.0)
+    # masked in the io dtype g arrives in, widened after: the one array XLA
+    # then stores for every consumer (scale_bias_act's docstring)
+    gb = jnp.where(y > 0, g, 0) if act == "relu" else g
+    gf = gb.astype(jnp.float32)
     axes = tuple(range(x.ndim - 1))
     dx = (gf * scale.astype(jnp.float32)).astype(x.dtype)
     dscale = jnp.sum(gf * x.astype(jnp.float32), axis=axes)
     dbias = jnp.sum(gf, axis=axes)
-    return (dx, dscale.astype(scale.dtype), dbias.astype(bias.dtype),
-            gf.astype(x.dtype))
+    return dx, dscale.astype(scale.dtype), dbias.astype(bias.dtype), gb
 
 
 # one custom_vjp per arity, so `residual=None` never ships a zeros tensor
@@ -142,7 +142,11 @@ def scale_bias_act(x, scale, bias, residual=None,
     masks on the saved io-dtype `y` and reduces dscale/dbias in one pass
     over (g, x, y), where autodiff of the float32 expression keeps float32
     tensors of the activation's size alive across the step (PERF.md §6,
-    PR 30). At y == 0 the ReLU's slope is 0.
+    PR 30). At y == 0 the ReLU's slope is 0. The mask is applied to the
+    cotangent in its io dtype, before anything widens it: masked in float32,
+    XLA finds (unmasked bf16 g, `pred` mask) the cheaper pair to store, at
+    three bytes an element, and repeats the select in each of g's three
+    consumers (PERF.md §6, PR 35); masked in bf16, it stores that array.
     """
     if act not in ("relu", None):
         raise ValueError(f"unsupported act {act!r}")

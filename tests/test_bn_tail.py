@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from deep_vision_tpu.models.resnet import BasicBlock, BottleneckBlock
+from deep_vision_tpu.nn import layers
 from deep_vision_tpu.nn.layers import ConvBN, FusedBatchNorm, scale_bias_act
 
 _TIE_MARGIN = 0.1
@@ -85,6 +86,46 @@ def test_tail_value_and_gradients_match_plain_float32(c, dtype, residual, act):
         np.testing.assert_allclose(
             np.asarray(u, np.float32), np.asarray(v, np.float32),
             rtol=tol, atol=tol * float(jnp.abs(v).max()), err_msg=name)
+
+
+def _bwd_before_pr35(act, res, g):
+    """`_scale_bias_act_bwd` as PR 30 wrote it and PR 35 found it: the
+    oracle. The cotangent is widened to float32 first and masked there."""
+    x, scale, bias, y = res
+    gf = g.astype(jnp.float32)
+    if act == "relu":
+        gf = jnp.where(y > 0, gf, 0.0)
+    axes = tuple(range(x.ndim - 1))
+    dx = (gf * scale.astype(jnp.float32)).astype(x.dtype)
+    dscale = jnp.sum(gf * x.astype(jnp.float32), axis=axes)
+    dbias = jnp.sum(gf, axis=axes)
+    return (dx, dscale.astype(scale.dtype), dbias.astype(bias.dtype),
+            gf.astype(x.dtype))
+
+
+@pytest.mark.parametrize("c", [256, 24])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("residual", [True, False])
+@pytest.mark.parametrize("act", ["relu", None])
+def test_backward_is_bit_equal_to_the_one_before_the_stored_gradient(
+        c, dtype, residual, act):
+    """PR 35 masks the cotangent in the io dtype it arrives in and widens
+    it after (so that XLA stores the masked array and no `pred` beside the
+    unmasked one: tests/test_tpu_lowering.py). A select commutes with an
+    exact cast: no value may change, jitted as the step runs it."""
+    x, a, b, r, g = _inputs(c, dtype, residual)
+    y = scale_bias_act(x, a, b, residual=r, act=act)
+    # ties too: a dead position, and one the mask must not let through
+    y = y.at[0, 0, 0, :4].set(0)
+    res, g = (x, a, b, y), g.astype(dtype)
+    got = jax.jit(layers._scale_bias_act_bwd, static_argnums=0)(act, res, g)
+    want = jax.jit(_bwd_before_pr35, static_argnums=0)(act, res, g)
+    for name, u, v in zip(("dx", "dscale", "dbias", "dresidual"), got, want):
+        assert u.dtype == v.dtype, name
+        np.testing.assert_array_equal(np.asarray(u, np.float32),
+                                      np.asarray(v, np.float32), err_msg=name)
+    if act == "relu":
+        assert not np.asarray(got[0], np.float32)[0, 0, 0, :4].any()
 
 
 @pytest.mark.parametrize("residual", [True, False])

@@ -36,6 +36,22 @@ HLO = """HloModule jit_step, is_scheduled=true
   ROOT %subtract.1 = f32[1,1,64,64]{3,2,1,0} subtract(%p0, %p1)
 }
 
+%fused_mask (p0: bf16[8,4,4,128]) -> (bf16[8,4,4,128], pred[8,4,4,128]) {
+  %p0 = bf16[8,4,4,128]{3,0,2,1} parameter(0)
+  %zero = bf16[] constant(0)
+  %zeros = bf16[8,4,4,128]{3,0,2,1} broadcast(%zero), dimensions={}
+  %compare.1 = pred[8,4,4,128]{3,0,2,1} compare(%p0, %zeros), direction=GT
+  ROOT %tuple.2 = (bf16[8,4,4,128]{3,0,2,1}, pred[8,4,4,128]{3,0,2,1}) tuple(%p0, %compare.1)
+}
+
+%fused_select (p0: bf16[8,4,4,128], p1: pred[8,4,4,128]) -> bf16[8,4,4,128] {
+  %p0 = bf16[8,4,4,128]{3,0,2,1} parameter(0)
+  %p1 = pred[8,4,4,128]{3,0,2,1} parameter(1)
+  %zero = bf16[] constant(0)
+  %zeros = bf16[8,4,4,128]{3,0,2,1} broadcast(%zero), dimensions={}
+  ROOT %select.1 = bf16[8,4,4,128]{3,0,2,1} select(%p1, %p0, %zeros)
+}
+
 ENTRY %main.1 (x: bf16[8,4,4,128], w: f32[1,1,64,64]) -> (bf16[8,4,4,128], f32[1,1,64,64]) {
   %x = bf16[8,4,4,128]{3,2,1,0:T(8,128)(2,1)} parameter(0)
   %w = f32[1,1,64,64]{3,2,1,0:T(8,128)} parameter(1)
@@ -49,11 +65,20 @@ ENTRY %main.1 (x: bf16[8,4,4,128], w: f32[1,1,64,64]) -> (bf16[8,4,4,128], f32[1
   %fusion.3 = bf16[8,4,4,128]{3,0,2,1:T(8,128)(2,1)} fusion(%fusion.1), kind=kLoop, calls=%fused_relu
   %fusion.4 = f32[1,1,64,64]{3,2,1,0:T(8,128)} fusion(%w, %w), kind=kLoop, calls=%fused_sgd
   %all-reduce.5 = f32[64]{0:T(128)} all-reduce(%fusion.2), replica_groups={}
+  %fusion.6 = (bf16[8,4,4,128]{3,0,2,1:T(8,128)(2,1)}, pred[8,4,4,128]{3,0,2,1:T(8,128)(4,1)}) fusion(%fusion.1), kind=kLoop, calls=%fused_mask
+  %get-tuple-element.1 = bf16[8,4,4,128]{3,0,2,1:T(8,128)(2,1)} get-tuple-element(%fusion.6), index=0
+  %get-tuple-element.2 = pred[8,4,4,128]{3,0,2,1:T(8,128)(4,1)} get-tuple-element(%fusion.6), index=1
+  %copy-start.2 = (pred[8,4,4,128]{3,0,2,1:T(8,128)(4,1)S(1)}, pred[8,4,4,128]{3,0,2,1:T(8,128)(4,1)}, u32[]{:S(2)}) copy-start(%get-tuple-element.2)
+  %copy-done.2 = pred[8,4,4,128]{3,0,2,1:T(8,128)(4,1)S(1)} copy-done(%copy-start.2)
+  %fusion.7 = bf16[8,4,4,128]{3,0,2,1:T(8,128)(2,1)} fusion(%get-tuple-element.1, %copy-done.2), kind=kLoop, calls=%fused_select
+  %fusion.8 = bf16[8,4,4,128]{3,0,2,1:T(8,128)(2,1)} fusion(%get-tuple-element.1, %get-tuple-element.2), kind=kLoop, calls=%fused_select
+  %compare.9 = pred[64]{0:T(128)(4,1)} compare(%fusion.2, %fusion.2), direction=GT
   ROOT %tuple.1 = (bf16[8,4,4,128]{3,0,2,1:T(8,128)(2,1)}, f32[1,1,64,64]{3,2,1,0:T(8,128)}) tuple(%fusion.3, %fusion.4)
 }
 """
 ACT = 8 * 4 * 4 * 128 * 2  # the activation, bf16
 W = 64 * 64 * 4
+MASK = 8 * 4 * 4 * 128  # the activation's mask, a byte an element
 
 
 @pytest.mark.parametrize("name, kind, moved", [
@@ -65,6 +90,9 @@ W = 64 * 64 * 4
     ("fusion.3", "elementwise fusion", 2 * ACT),
     ("fusion.4", "optimizer", 3 * W),
     ("all-reduce.5", "all-reduce", 2 * 64 * 4),
+    ("fusion.6", "elementwise fusion", 2 * ACT + MASK),  # two results
+    ("copy-start.2", "async copy/slice", MASK + 4),
+    ("fusion.8", "elementwise fusion", 2 * ACT + MASK),
 ])
 def test_each_instruction_is_charged_its_operands_and_result(
         name, kind, moved):
@@ -85,3 +113,12 @@ def test_what_moves_nothing_is_left_out_and_traced_time_joins_by_name():
     assert "reshape/transpose" not in table["kinds"]
     assert table["largest"][0] == [
         "fusion.1", "convolution fusion", (2 * ACT + W) / 1e6, 2.0]
+
+
+def test_a_mask_stored_beside_what_it_masks_is_counted_where_it_moves():
+    """`pred_gb`: written by the fusion that makes it, once more by the
+    async copy's destination, read by each fusion that selects on it; a
+    channel's worth of `pred` is under the floor."""
+    assert step_bytes.pred_bytes(HLO, over=1000) == 4 * MASK
+    assert step_bytes.pred_bytes(HLO, over=10) == 4 * MASK + 64
+    assert step_bytes.pred_bytes(HLO) == 0  # nothing here is over 1 MB

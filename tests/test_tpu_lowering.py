@@ -11,15 +11,18 @@ to chip_smoke.py. Shapes are the production ones chip_smoke.py runs.
 BatchNorm's tail was such a kernel until PR 30 and is plain jax.numpy now
 (tests/test_bn_tail.py); a test here holds what that bought: a ResNet block
 compiled for the v5e has no custom call and no layout copy of an
-activation. The last test holds what the single-block attention kernel
-(PR 32) buys a ViT block and what it does not: no (T, T) tensor in the
+activation, and (PR 35) no `pred` mask of an activation's size stored
+beside the gradient it masks. The last test holds what the single-block
+attention kernel (PR 32) buys a ViT block and what it does not: no (T, T) tensor in the
 program, and exactly the four layout copies of the kernel's own operands;
 the one after it what the backward kernel's second output buys (PR 34): the
 qkv bias's gradient without a pass over d(qkv), the bias still in the
 projection matmul's epilogue.
 """
+import functools
 import math
 import os
+import re
 import subprocess
 import sys
 
@@ -103,8 +106,6 @@ _Q = S((2, 1024, 12, 64), jnp.bfloat16)
 def test_each_kernel_carries_its_name_into_the_program(name, fn, specs):
     """A `name=` on every `pallas_call`: a trace's reader finds the kernel
     by it, on one chip and on four, not by its position in the program."""
-    import re
-
     text = lower_for_tpu(fn, *specs)
     assert re.search(rf"\b{name}\b", text), name
 
@@ -259,16 +260,12 @@ def test_nms_and_flash_compile_for_v5e(v5e):
         S((8, 256, 3 * 1024), jnp.bfloat16))
 
 
-@pytest.mark.parametrize("hw, c", [(56, 256), (7, 2048)])
-def test_resnet_blocks_compile_to_xla_fusions_alone(v5e, hw, c):
-    """A convolution and two identity bottlenecks, forward and backward,
-    bf16, batch 128: every activation has a convolution before and after
-    it, as in the model. BatchNorm's tail is XLA's to fuse in the
-    convolutions' layouts: no `tpu_custom_call`, and no `copy`, `reshape`
-    or `transpose` of its own that writes a tensor of the activation's
-    size (the Pallas tail cost two or three per site, PERF.md §5)."""
-    import re
-
+@functools.lru_cache(maxsize=None)
+def _resnet_blocks_hlo(v5e, hw: int, c: int) -> str:
+    """The optimized HLO of a convolution and two identity bottlenecks,
+    forward and backward, bf16, batch 128: every activation has a
+    convolution before and after it, as in the model, and the loss gives
+    the last tail a cotangent that is no constant."""
     from jax.sharding import SingleDeviceSharding
 
     from deep_vision_tpu.models.resnet import BottleneckBlock
@@ -288,19 +285,48 @@ def test_resnet_blocks_compile_to_xla_fusions_alone(v5e, hw, c):
         def loss(params):
             y, _ = Net().apply({**variables, "params": params}, x,
                                mutable=["batch_stats"])
-            return jnp.sum(y.astype(jnp.float32))
+            return jnp.sum(jnp.square(y.astype(jnp.float32)))
         return jax.value_and_grad(loss)(variables["params"])
 
     here = SingleDeviceSharding(v5e)
     specs = jax.tree.map(lambda s: S(s.shape, s.dtype, sharding=here),
                          (variables, x))
-    text = jax.jit(fwd_bwd).lower(*specs).compile().as_text()
-    assert "tpu_custom_call" not in text
+    return jax.jit(fwd_bwd).lower(*specs).compile().as_text()
+
+
+def _entry_arrays(text: str, pattern: str, elements: int) -> list:
+    """Matches of `pattern` (group 1: a shape's dims) in the entry
+    computation whose array has at least `elements` elements."""
     entry = text[text.index("\nENTRY "):]
-    moved = [m.group(0) for m in re.finditer(
-        r"= \w+\[([\d,]+)\]\S* (?:copy|reshape|transpose)\(", entry)
-        if math.prod(map(int, m.group(1).split(","))) >= 128 * hw * hw * c]
+    return [m.group(0) for m in re.finditer(pattern, entry)
+            if math.prod(map(int, m.group(1).split(","))) >= elements]
+
+
+@pytest.mark.parametrize("hw, c", [(56, 256), (7, 2048)])
+def test_resnet_blocks_compile_to_xla_fusions_alone(v5e, hw, c):
+    """BatchNorm's tail is XLA's to fuse in the convolutions' layouts: no
+    `tpu_custom_call`, and no `copy`, `reshape` or `transpose` of its own
+    that writes a tensor of the activation's size (the Pallas tail cost two
+    or three per site, PERF.md §5)."""
+    text = _resnet_blocks_hlo(v5e, hw, c)
+    assert "tpu_custom_call" not in text
+    moved = _entry_arrays(
+        text, r"= \w+\[([\d,]+)\]\S* (?:copy|reshape|transpose)\(",
+        128 * hw * hw * c)
     assert not moved, moved
+
+
+@pytest.mark.parametrize("hw, c", [(56, 256), (7, 2048)])
+def test_resnet_blocks_store_no_mask_beside_the_gradient(v5e, hw, c):
+    """The tail's backward masks the cotangent in bf16, and XLA stores the
+    masked array once (PR 35). Masked in float32, XLA pushed the
+    `where(y > 0, g, 0)` into the three consumers and made its operands
+    the producer's outputs: a `pred` array of the activation's size beside
+    the unmasked gradient, three bytes an element across HBM four times
+    where two do."""
+    masks = _entry_arrays(_resnet_blocks_hlo(v5e, hw, c),
+                          r"pred\[([\d,]+)\]", 128 * hw * hw * c)
+    assert not masks, masks
 
 
 def _vit_blocks_hlo(v5e, monkeypatch, blocks: int) -> str:
@@ -347,8 +373,6 @@ def test_vit_block_compiles_with_attention_in_vmem(v5e, monkeypatch):
     row-major by contract, so each of the kernel's four operands (qkv, o,
     dO, d(qkv)) costs one layout copy. Held here at exactly those four, so
     that a fifth, or their removal, shows."""
-    import re
-
     text = _vit_blocks_hlo(v5e, monkeypatch, 1)
     entry = text[text.index("\nENTRY "):]
     assert entry.count('custom_call_target="tpu_custom_call"') == 2
@@ -377,8 +401,6 @@ def test_vit_blocks_take_the_qkv_bias_gradient_from_the_kernel(v5e,
     the activation, which costs more than the `reduce` did. So nothing may
     write a tensor of that size but, a block: the projection, the two
     kernels' operands' copies in and the backward kernel and its copy out."""
-    import re
-
     text = _vit_blocks_hlo(v5e, monkeypatch, 2)
     entry = text[text.index("\nENTRY "):]
     instr = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$",

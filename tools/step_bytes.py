@@ -12,9 +12,11 @@ op names are the chip's. Every instruction of the optimized entry
 computation is then charged its operands' bytes plus its result's, and
 summed by kind. A step bound by HBM bandwidth takes that sum over 819 GB/s
 (PERF.md §5, which also reckons 14.5 GB as ResNet-50's floor at batch 128).
-Nothing runs: no time comes from here. `--ops` takes a
-chip's traced table {op name: seconds a step} (`--dump-ops`, on the chip,
-writes one) and lays its milliseconds beside the bytes, by the same kinds.
+`pred_gb` is the part of all that in `pred` arrays over 1 MB: a mask
+stored beside what it masks (PR 35). Nothing runs: no time comes from
+here. `--ops` takes a chip's traced table {op name: seconds a step}
+(`--dump-ops`, on the chip, writes one) and lays its milliseconds beside
+the bytes, by the same kinds.
 """
 from __future__ import annotations
 
@@ -43,10 +45,11 @@ _FREE = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
          "after-all", "partition-id", "replica-id", "iota"}
 
 
-def shapes_bytes(text: str) -> list:
-    """Bytes of each array shape written in `text`, in order."""
+def shapes_bytes(text: str, only: str | None = None) -> list:
+    """Bytes of each array shape written in `text`, in order; of dtype
+    `only` alone where given."""
     return [_DTYPE_BYTES[d] * math.prod(int(n) for n in dims.split(",") if n)
-            for d, dims in _SHAPE.findall(text)]
+            for d, dims in _SHAPE.findall(text) if only in (None, d)]
 
 
 def computations(hlo: str) -> dict:
@@ -69,38 +72,55 @@ def _opcodes(lines) -> set:
     return {m.group(3) for m in map(_INSTR.match, lines) if m}
 
 
+def _moves(hlo: str, shape_bytes=shapes_bytes):
+    """(name, opcode, the line's rest, operands' bytes, the result's parts'
+    bytes, bytes moved) of each instruction of the entry computation that moves any, an array's
+    bytes as `shape_bytes` counts them."""
+    sizes = {}
+    for line in computations(hlo)["ENTRY"]:
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, result, opcode, rest = m.groups()
+        parts = shape_bytes(result)
+        sizes[name] = sum(parts)
+        if opcode in _FREE or opcode.endswith("-done"):
+            continue  # a -done is charged at its -start
+        operands = [sizes.get(o, 0) for o in re.findall(
+            r"%([\w.\-]+)", rest.split("), ", 1)[0])]
+        read = sum(operands)
+        # an async copy goes to or from the chip's near memory (`S(1)` in
+        # a layout): the destination crosses HBM once. The start's result
+        # repeats its operand beside the destination.
+        moved = (sizes[name] - read if opcode in ("copy-start", "slice-start")
+                 else sizes[name] + read)
+        yield name, opcode, rest, operands, parts, moved
+
+
+def pred_bytes(hlo: str, over: int = 1_000_000) -> int:
+    """Bytes of `pred` arrays over `over` bytes that the entry computation
+    writes and reads, async pairs included: a mask stored beside what it
+    masks (PERF.md §6, PR 35)."""
+    return sum(m[-1] for m in _moves(hlo, lambda text: [
+        b for b in shapes_bytes(text, "pred") if b > over]))
+
+
 def classify(hlo: str, param_bytes: set = frozenset()) -> dict:
     """{instruction of the entry computation: (kind, bytes)}. A fusion is a
     convolution fusion if a convolution is inside, else a reduction fusion
     if a reduce is, else elementwise; one whose every big operand and
     result has a parameter's size (`param_bytes`) is the optimizer's."""
     comps = computations(hlo)
-    sizes, out = {}, {}
-    for line in comps["ENTRY"]:
-        m = _INSTR.match(line)
-        if not m:
-            continue
-        name, result, opcode, rest = m.groups()
-        parts = shapes_bytes(result)
-        sizes[name] = sum(parts)
-        if opcode in _FREE:
-            continue
-        operands = re.findall(r"%([\w.\-]+)", rest.split("), ", 1)[0])
-        moved = sizes[name] + sum(sizes.get(o, 0) for o in operands)
-        if opcode.endswith("-done"):
-            continue  # charged at its -start
+    out = {}
+    for name, opcode, rest, operands, parts, moved in _moves(hlo):
         if opcode in ("copy-start", "slice-start"):
-            # to or from the chip's near memory (`S(1)` in a layout): the
-            # destination crosses HBM once. The start's result repeats its
-            # operand beside the destination.
-            moved = sizes[name] - sum(sizes.get(o, 0) for o in operands)
             kind = "async copy/slice"
         elif opcode.endswith("-start"):
             kind = "all-reduce"
         elif opcode == "fusion":
             called = re.search(r"calls=%([\w.\-]+)", rest)
             inside = _opcodes(comps.get(called.group(1), [])) if called else ()
-            big = [sizes.get(o, 0) for o in operands] + parts
+            big = operands + parts
             if "convolution" in inside:
                 kind = "convolution fusion"
             elif param_bytes and all(
@@ -270,6 +290,7 @@ def main(argv=None):
         workload=args.workload, compile_s=round(time.time() - t0, 1),
         tpu_custom_calls=text.count('custom_call_target="tpu_custom_call"'),
         temp_gb=mem.temp_size_in_bytes / 1e9,
+        pred_gb=pred_bytes(text) / 1e9,
         cost_analysis_gb=(cost[0] if isinstance(cost, list) else cost).get(
             "bytes accessed", 0.0) / 1e9,
         sync_gb=sum(v["gb"] for k, v in result["kinds"].items()
