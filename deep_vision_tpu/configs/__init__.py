@@ -18,10 +18,12 @@ from typing import Any, Dict, Optional, Tuple
 @dataclasses.dataclass
 class ExperimentConfig:
     name: str
-    task: str  # classification | detection | pose | centernet | dcgan | cyclegan
+    # classification | detection | pose | centernet | causal_lm (Trainer);
+    # dcgan | cyclegan (their own trainers)
+    task: str
     model: str
     model_kwargs: Dict[str, Any] = dataclasses.field(default_factory=dict)
-    input_shape: Tuple[int, ...] = (224, 224, 3)
+    input_shape: Tuple[int, ...] = (224, 224, 3)  # causal_lm: (tokens,)
     num_classes: int = 1000
     batch_size: int = 128  # global batch (reference: per-replica x replicas)
     epochs: int = 90
@@ -243,3 +245,19 @@ for _name, _model, _mkw in (
                   "total_epochs": 90},
         dataset={"kind": "imagenet"},
     ))
+
+# -- token-sequence decoders (net-new; no reference counterpart) -------------
+
+register_config(ExperimentConfig(
+    # allenai/Olmo-Hybrid-7B at its published widths and depth; the recipe
+    # is OLMo 2's (arXiv:2501.00656): AdamW 3e-4, betas 0.9 / 0.95, decay
+    # 0.1, warm-up then cosine. Two sequences of 2048 tokens a chip is what
+    # 16 GB holds of it beside a pipeline stage's state; token data has no
+    # reader yet, so the dataset is the fake one (ROADMAP B2)
+    name="olmo_hybrid_7b", task="causal_lm", model="olmo_hybrid_7b",
+    input_shape=(2048,), batch_size=2, epochs=1,
+    optimizer={"name": "adamw", "learning_rate": 3e-4, "b1": 0.9,
+               "b2": 0.95, "weight_decay": 0.1},
+    schedule={"kind": "cosine", "warmup_epochs": 5, "total_epochs": 90},
+    dataset={"kind": "fake"},
+))

@@ -1,3 +1,4 @@
+from deep_vision_tpu.losses.causal_lm import causal_lm_loss_fn
 from deep_vision_tpu.losses.classification import (
     cross_entropy_loss,
     classification_loss_fn,
@@ -15,6 +16,7 @@ from deep_vision_tpu.losses.yolo import (
 from deep_vision_tpu.losses import gan
 
 __all__ = [
+    "causal_lm_loss_fn",
     "cross_entropy_loss",
     "classification_loss_fn",
     "centernet_focal_loss",

@@ -40,3 +40,4 @@ from deep_vision_tpu.models import centernet  # noqa: E402,F401
 from deep_vision_tpu.models import dcgan  # noqa: E402,F401
 from deep_vision_tpu.models import cyclegan  # noqa: E402,F401
 from deep_vision_tpu.models import vit  # noqa: E402,F401
+from deep_vision_tpu.models import olmo_hybrid  # noqa: E402,F401

@@ -317,3 +317,37 @@ class DepthwiseSeparableConv(nn.Module):
             features=self.features, kernel=(1, 1), act=self.act, dtype=self.dtype
         )(x, train)
         return x
+
+
+# -- decoder blocks' parts (models/olmo_hybrid.py) ---------------------------
+
+class RMSNorm(nn.Module):
+    """`x * rsqrt(mean(x^2) + eps) * scale` over the last axis: statistic
+    and product in float32, result in x's dtype; `scale` starts at one."""
+
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        y = x.astype(jnp.float32)
+        y = y * jax.lax.rsqrt(
+            jnp.mean(jnp.square(y), axis=-1, keepdims=True) + self.eps)
+        return (y * scale).astype(x.dtype)
+
+
+class SwiGLU(nn.Module):
+    """`down(silu(gate x) * up x)`, no biases (Shazeer, arXiv:2002.05202)."""
+
+    hidden: int
+    dtype: Optional[jnp.dtype] = None
+    kernel_init: Callable = nn.initializers.lecun_normal()
+
+    @nn.compact
+    def __call__(self, x):
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                                  kernel_init=self.kernel_init)
+        y = nn.silu(dense(self.hidden, name="gate")(x)) \
+            * dense(self.hidden, name="up")(x)
+        return dense(x.shape[-1], name="down")(y)

@@ -192,6 +192,10 @@ class StepClock:
         self._c_steps = r.counter(f"{name}_steps_total", "steps executed")
         self._c_examples = r.counter(f"{name}_examples_total",
                                      "examples consumed")
+        self._c_tokens = r.counter(
+            f"{name}_tokens_total",
+            "tokens consumed (rows x sequence length a dispatch; a feed of "
+            "images counts none)")
         self._c_starved = r.counter(
             f"{name}_data_starved_steps_total",
             "steps whose data wait exceeded their dispatch time")
@@ -232,8 +236,8 @@ class StepClock:
 
     # -- step side ---------------------------------------------------------
 
-    def step(self, batch_size: int = 0,
-             auto_commit: bool = True) -> "_StepRecord":
+    def step(self, batch_size: int = 0, auto_commit: bool = True,
+             tokens: int = 0) -> "_StepRecord":
         """`auto_commit=False` defers the registry/journal write to an
         explicit `rec.commit(step=..., metrics=...)` AFTER the with-block
         (the Trainer's loop: one step later, once it has read the step's
@@ -242,7 +246,7 @@ class StepClock:
         self._steps_seen += 1
         do_sample = (self._steps_seen % self.sample_every) == 0
         return _StepRecord(self, batch_size, self._last_data_wait_ms,
-                           do_sample, auto_commit)
+                           do_sample, auto_commit, tokens)
 
     def _finish(self, rec: "_StepRecord") -> None:
         self._c_steps.inc()
@@ -250,6 +254,8 @@ class StepClock:
             self._c_covered.inc()
         if rec.batch_size:
             self._c_examples.inc(rec.batch_size)
+        if rec.tokens:
+            self._c_tokens.inc(rec.tokens)
         self._g_data_wait.set(rec.data_wait_ms)
         self._g_step.set(rec.step_time_ms)
         self._h_step.observe(rec.step_time_ms)
@@ -296,9 +302,11 @@ class _StepRecord:
     """Context manager for one step; collects the timing fields."""
 
     def __init__(self, clock: StepClock, batch_size: int,
-                 data_wait_ms: float, sampled: bool, auto_commit: bool):
+                 data_wait_ms: float, sampled: bool, auto_commit: bool,
+                 tokens: int = 0):
         self._clock = clock
         self.batch_size = batch_size
+        self.tokens = tokens
         self.data_wait_ms = data_wait_ms
         self.sampled = sampled
         self.index = clock.steps_seen  # this dispatch, as the spans count it

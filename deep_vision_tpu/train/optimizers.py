@@ -111,7 +111,12 @@ def cast_optimizer_state(
     untouched.
     """
 
+    @jax.jit
     def init(params):
+        # one program: the zeros are made in the stored type. Eagerly the
+        # float32 moments would lie whole beside their rounded copies,
+        # twice the training state at the peak (7.4 GB more at 0.93 B
+        # parameters under AdamW)
         return _cast_float_leaves(tx.init(params), state_dtype)
 
     def update(updates, state, params=None, **extra):

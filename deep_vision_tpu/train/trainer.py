@@ -276,6 +276,11 @@ class Trainer:
                         pass  # already fingerprinted: identity is fixed
         self._tx = tx
         self._sample_input = sample_input
+        # a row of integer ids (B, T) is T tokens: `train_tokens_total`
+        self._tokens_per_row = (
+            int(sample_input.shape[1])
+            if getattr(sample_input, "ndim", 0) == 2
+            and jnp.issubdtype(sample_input.dtype, jnp.integer) else 0)
         self._init_rng = rng
 
         # declarative sharding (parallel/shardmap.py): with a rules table
@@ -1293,7 +1298,8 @@ class Trainer:
                 span("train/step", step=i, epoch=epoch, **args):
             # dispatch_ms is enqueue-only (the starvation signal compares
             # data_wait against it); the record commits when it is read
-            with self.clock.step(batch_size=n, auto_commit=False) as rec:
+            with self.clock.step(batch_size=n, auto_commit=False,
+                                 tokens=n * self._tokens_per_row) as rec:
                 report = (self._dispatch_superstep(item) if group
                           else self._dispatch_step(item))
             late = self._in_flight

@@ -21,11 +21,26 @@ from deep_vision_tpu.configs import CONFIG_REGISTRY, ExperimentConfig, get_confi
 
 def model_input_shape(cfg: ExperimentConfig):
     """The shape the MODEL consumes: cfg.input_shape after any host-side
-    layout transform (stem='s2d' ships (H/2, W/2, 4C), models/resnet.py)."""
-    h, w, c = cfg.input_shape
+    layout transform (stem='s2d' ships (H/2, W/2, 4C), models/resnet.py).
+    A token sequence's is rank 1, `(T,)`, and has no layout to change."""
     if cfg.model_kwargs.get("stem") == "s2d":
+        h, w, c = cfg.input_shape
         return (h // 2, w // 2, 4 * c)
-    return cfg.input_shape
+    return tuple(cfg.input_shape)
+
+
+def model_input(cfg: ExperimentConfig):
+    """(the key of the batch's array the model is applied to, its dtype)."""
+    if cfg.task == "causal_lm":
+        return "tokens", np.int32
+    return "image", np.float32
+
+
+def sample_input(cfg: ExperimentConfig):
+    """Two rows of the model's input in its own dtype, to initialise on."""
+    import jax.numpy as jnp
+
+    return jnp.ones((2, *model_input_shape(cfg)), model_input(cfg)[1])
 
 
 # -- fake datasets -----------------------------------------------------------
@@ -40,6 +55,17 @@ def _fake_classification(cfg: ExperimentConfig, n_batches: int):
         }
         for _ in range(n_batches)
     ]
+
+
+def _fake_tokens(cfg: ExperimentConfig, n_batches: int):
+    """Token sequences, ids uniform below the model's vocabulary."""
+    from deep_vision_tpu.models import get_model
+
+    rng = np.random.RandomState(0)
+    vocab = get_model(cfg.model, **cfg.model_kwargs).vocab_size
+    shape = (cfg.batch_size, *model_input_shape(cfg))
+    return [{"tokens": rng.randint(0, vocab, shape).astype(np.int32)}
+            for _ in range(n_batches)]
 
 
 def _fake_detection(cfg: ExperimentConfig, n_batches: int, max_boxes=20):
@@ -159,6 +185,7 @@ def build_dataloaders(cfg: ExperimentConfig, data_dir: str, fake: bool,
             "centernet": _fake_centernet,
             "dcgan": _fake_classification,
             "cyclegan": _fake_classification,
+            "causal_lm": _fake_tokens,
         }[cfg.task]
         data = maker(cfg, fake_batches)
         return (lambda: data), (lambda: data)
@@ -345,10 +372,9 @@ def build_trainer(cfg: ExperimentConfig, train_fn, ckpt_dir: Optional[str],
                   telemetry=None):
     import functools
 
-    import jax.numpy as jnp
-
     from deep_vision_tpu.core import CheckpointManager
     from deep_vision_tpu.losses import (
+        causal_lm_loss_fn,
         centernet_loss_fn,
         classification_loss_fn,
         hourglass_loss_fn,
@@ -392,13 +418,21 @@ def build_trainer(cfg: ExperimentConfig, train_fn, ckpt_dir: Optional[str],
         model = get_model(cfg.model, num_classes=cfg.num_classes, **cfg.model_kwargs)
         loss_fn = functools.partial(centernet_loss_fn, **cfg.loss_kwargs)
         plateau_metric = cfg.plateau_metric
+    elif cfg.task == "causal_lm":
+        model = get_model(cfg.model, **cfg.model_kwargs)
+        loss_fn = functools.partial(causal_lm_loss_fn, **cfg.loss_kwargs)
+        plateau_metric = "loss"
+    elif cfg.task in ("dcgan", "cyclegan"):
+        raise ValueError(f"task {cfg.task!r} uses a GAN trainer "
+                         "(build_gan_trainer), not Trainer")
     else:
-        raise ValueError(f"task {cfg.task!r} uses a GAN trainer, not Trainer")
+        raise ValueError(
+            f"unknown task {cfg.task!r}: Trainer has classification, "
+            "detection, pose, centernet and causal_lm")
 
     plateau = ReduceLROnPlateau(**cfg.plateau) if cfg.plateau else None
     # journal-wired: quarantines and sidecar retries become typed events
     ckpt = CheckpointManager(ckpt_dir, journal=journal) if ckpt_dir else None
-    sample = jnp.ones((2, *model_input_shape(cfg)), jnp.float32)
     from deep_vision_tpu.core.metrics import MetricLogger
     from deep_vision_tpu.obs.registry import get_registry
 
@@ -416,7 +450,8 @@ def build_trainer(cfg: ExperimentConfig, train_fn, ckpt_dir: Optional[str],
     eval_logger = MetricLogger(tb_writer=tb, name="val", print_every=0,
                                registry=get_registry())
     return Trainer(
-        model, tx, loss_fn, sample, plateau=plateau,
+        model, tx, loss_fn, sample_input(cfg),
+        input_key=model_input(cfg)[0], plateau=plateau,
         plateau_metric=plateau_metric, checkpoint_manager=ckpt,
         logger=logger, eval_logger=eval_logger, profile_dir=profile_dir,
         checkify_errors=checkify_errors, ema_decay=ema_decay,
@@ -1349,12 +1384,9 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     if args.summary:
         from deep_vision_tpu.core.summary import model_summary
-        import jax.numpy as _jnp
 
         # summarize the exact module build_trainer constructed, not a rebuild
-        print(model_summary(
-            trainer.model, _jnp.ones((2, *model_input_shape(cfg)), _jnp.float32)
-        ))
+        print(model_summary(trainer.model, sample_input(cfg)))
     print(f"model {cfg.model}: {count_params(trainer.state.params):,} trainable params")
     start_epoch = 0
     if args.checkpoint:
