@@ -35,9 +35,12 @@ of tokens at a time, so a step never holds `(B, T, V)` float32 logits and
 their gradient (`causal_lm.logits` makes them where a caller wants them).
 Each block is recomputed in the backward pass (`remat`) but for what is
 dear to make and small to keep (`_KEPT`): the projections' outputs, 0.4 GB
-a block at 2 x 2048 tokens in bfloat16, so that the second forward is the
-elementwise ops, the delta rule and the attention kernel, and no matmul
-over the width.
+a block at 2 x 2048 tokens in bfloat16, and a delta-rule layer's inverse
+triangles `T = (I + A)^-1`, kept by the name `ops/gated_delta.py` gives
+them (31.5 MB a layer there, float32: the backward pass reads them and the
+second forward does not invert again). So the second forward is the
+elementwise ops, the delta rule's products and scan and the attention
+kernel, and no matmul over the width.
 """
 from __future__ import annotations
 
@@ -52,13 +55,21 @@ from deep_vision_tpu.models import register_model
 from deep_vision_tpu.models.vit import attention_path, flash_attention
 from deep_vision_tpu.nn.layers import RMSNorm, SwiGLU
 from deep_vision_tpu.obs.registry import get_registry
-from deep_vision_tpu.ops.gated_delta import CHUNK, gated_delta_rule, short_conv
+from deep_vision_tpu.ops.gated_delta import (
+    CHUNK,
+    INVERSE_NAME,
+    gated_delta_rule,
+    short_conv,
+)
 
 LINEAR, FULL = "linear_attention", "full_attention"
 _INIT = nn.initializers.normal(0.02)
 # what a recomputed block keeps from its first forward: every product with
-# a kernel (no batch dimension: not the delta rule's, not the scores)
-_KEPT = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+# a kernel (no batch dimension: not the delta rule's, not the scores), and
+# the delta rule's inverse triangles, by name
+_KEPT = jax.checkpoint_policies.save_from_both_policies(
+    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    jax.checkpoint_policies.save_only_these_names(INVERSE_NAME))
 
 
 def _dense(features, dtype, name):

@@ -32,6 +32,23 @@ state, the decays and `T` are float32; the matmuls take operands in
 `mm_dtype` (bfloat16 on the chip) and accumulate in float32. The backward
 pass is autodiff's, with the scan's body recomputed (`jax.checkpoint`): a
 step keeps each chunk's `M`, 74 KB a head, and not what the body makes of it.
+
+`T` is a value of the program, made once a layer a step (PR 37). It comes
+from row-by-row forward substitution with the chunks in the lanes, one
+Pallas call (`ops/pallas/tril_inverse.py`, `gdn_inverse`; below its sizes,
+a tiny test's chunk, from one `solve_triangular` against the identity),
+under the scope `delta_inverse`, and carries the name `INVERSE_NAME`, by
+which a recomputed block keeps it (`models/olmo_hybrid._KEPT`): 64 x 64
+float32 a chunk, 31.5 MB a layer at 2 x 2048 tokens and 30 heads. `W, U =
+T rhs` is then a product, and so is the backward pass (`_solve`'s
+`custom_vjp`), which reads `T` where autodiff through a solve would invert
+again: from `G = d(W, U)`,
+
+    X = T^T G,    d rhs = X,    dA = -tril_(X (W, U)^T)
+
+Those three products are float32 in and out at `Precision.HIGHEST`, as the
+solve's own were: `W` and `U` are rounded to `mm_dtype` only where the
+scan's body takes them.
 """
 from __future__ import annotations
 
@@ -40,8 +57,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+from deep_vision_tpu.obs.registry import get_registry
+from deep_vision_tpu.ops.pallas.tril_inverse import (
+    tril_inverse,
+    tril_inverse_fits,
+)
 
 CHUNK = 64
+# the name `T` carries to a recomputation's policy (`models/olmo_hybrid.py`)
+INVERSE_NAME = "delta_rule_inverse"
+_EXACT = lax.Precision.HIGHEST
 
 
 def short_conv(x, kernel):
@@ -59,6 +86,49 @@ def short_conv(x, kernel):
 def _mm(spec, a, b, mm_dtype):
     return jnp.einsum(spec, a.astype(mm_dtype), b.astype(mm_dtype),
                       preferred_element_type=jnp.float32)
+
+
+def _unit_lower_inverse(a):
+    """`(I + a)^-1` for `a` of `(N, B, H, C, C)`, strictly lower triangular,
+    float32. Where the kernel takes the size (C a multiple of 8, C^2 of
+    128) it is row-by-row substitution with the chunks in the lanes, the
+    batch in front for its partitioning; a smaller chunk (a tiny test's) is
+    one `solve_triangular` against the identity."""
+    c = a.shape[-1]
+    if tril_inverse_fits(c):
+        return jnp.moveaxis(tril_inverse(jnp.moveaxis(a, 1, 0)), 0, 1)
+    eye = jnp.eye(c, dtype=a.dtype)
+    return jax.scipy.linalg.solve_triangular(
+        a + eye, jnp.broadcast_to(eye, a.shape), lower=True,
+        unit_diagonal=True)
+
+
+@jax.custom_vjp
+def _solve(a, rhs):
+    """`(I + a)^-1 rhs`, float32: `a` (N, B, H, C, C) strictly lower
+    triangular, `rhs` (N, B, H, C, d)."""
+    return _solve_fwd(a, rhs)[0]
+
+
+def _solve_fwd(a, rhs):
+    get_registry().counter(
+        "delta_rule_inverse_sites_total",
+        "Inversions of a delta-rule layer's chunk triangles traced").inc()
+    with jax.named_scope("delta_inverse"):
+        t = checkpoint_name(_unit_lower_inverse(a), INVERSE_NAME)
+    wu = jnp.einsum("...ij,...jd->...id", t, rhs, precision=_EXACT)
+    return wu, (t, wu)
+
+
+def _solve_bwd(residuals, d_wu):
+    t, wu = residuals
+    x = jnp.einsum("...ji,...jd->...id", t, d_wu, precision=_EXACT)
+    d_a = -jnp.tril(jnp.einsum("...id,...jd->...ij", x, wu,
+                               precision=_EXACT), -1)
+    return d_a, x
+
+
+_solve.defvjp(_solve_fwd, _solve_bwd)
 
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK,
@@ -91,9 +161,7 @@ def _chunked(q, k, v, g, beta, chunk, mm_dtype):
                   * mm("nbhid,nbhjd->nbhij", k, k), 0.0)
     rhs = jnp.concatenate([(beta * jnp.exp(y))[..., None] * k,
                            beta[..., None] * v], axis=-1)
-    wu = jax.scipy.linalg.solve_triangular(
-        a + jnp.eye(chunk, dtype=a.dtype), rhs, lower=True,
-        unit_diagonal=True)
+    wu = _solve(a, rhs)
     w, u = wu[..., :dk], wu[..., dk:]
     qk = decay * mm("nbhid,nbhjd->nbhij", q, k)
     q_in = q * jnp.exp(y)[..., None]

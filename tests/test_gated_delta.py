@@ -4,7 +4,13 @@ against its definition. float32 on the CPU: both sides are the same
 mathematics in another order of sums, so they part by float32 rounding
 alone (1e-7 to 2e-6 of a norm at these sizes); the tolerance, 2e-5, is
 ten times that and a thousand times under what a wrong decay, a missing
-`T` or an off-by-one mask reads (order 1e-2 to 1)."""
+`T` or an off-by-one mask reads (order 1e-2 to 1).
+
+Since PR 37 `T = (I + A)^-1` is a value of its own: the kernel that makes
+it (`ops/pallas/tril_inverse.py`, interpreted here) against
+`numpy.linalg.inv`, and the solve's `custom_vjp`, which reads `T` where
+autodiff through `solve_triangular` would invert again, against exactly
+that."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +18,16 @@ import pytest
 
 from jax import lax
 
-from deep_vision_tpu.ops.gated_delta import gated_delta_rule, short_conv
+from deep_vision_tpu.ops.gated_delta import (
+    _solve,
+    _unit_lower_inverse,
+    gated_delta_rule,
+    short_conv,
+)
+from deep_vision_tpu.ops.pallas.tril_inverse import (
+    tril_inverse,
+    tril_inverse_fits,
+)
 
 def gated_delta_recurrent(q, k, v, g, beta):
     """The recurrence itself, token by token, float32: `S_t = a_t S_{t-1}
@@ -37,16 +52,16 @@ B, T, H, DK, DV = 2, 32, 3, 8, 16
 TOL = 2e-5
 
 
-def inputs(decay: float, beta_scale: float, seed: int = 0):
+def inputs(decay: float, beta_scale: float, seed: int = 0, t: int = T):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-    q = unit(jax.random.normal(ks[0], (B, T, H, DK))) * DK ** -0.5
-    k = unit(jax.random.normal(ks[1], (B, T, H, DK)))
-    v = jax.random.normal(ks[2], (B, T, H, DV))
+    q = unit(jax.random.normal(ks[0], (B, t, H, DK))) * DK ** -0.5
+    k = unit(jax.random.normal(ks[1], (B, t, H, DK)))
+    v = jax.random.normal(ks[2], (B, t, H, DV))
     # beta up to 2: above 1 the state's eigenvalue along k is negative
-    beta = 2 * jax.nn.sigmoid(beta_scale * jax.random.normal(ks[3], (B, T, H)))
-    g = -decay * jax.nn.softplus(jax.random.normal(ks[4], (B, T, H)))
-    cotangent = jax.random.normal(ks[5], (B, T, H, DV))
+    beta = 2 * jax.nn.sigmoid(beta_scale * jax.random.normal(ks[3], (B, t, H)))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[4], (B, t, H)))
+    cotangent = jax.random.normal(ks[5], (B, t, H, DV))
     return (q, k, v, g, beta), cotangent
 
 
@@ -64,6 +79,10 @@ def test_chunked_is_the_recurrence_outputs_and_all_gradients(chunk, decay,
                                                              beta_scale):
     args, ct = inputs(decay, beta_scale)
     assert float(jnp.max(args[4])) > 1.5
+    is_the_recurrence(args, ct, chunk)
+
+
+def is_the_recurrence(args, ct, chunk):
     with jax.default_matmul_precision("highest"):
         chunked = lambda *a: gated_delta_rule(*a, chunk=chunk)
         assert apart(chunked(*args), gated_delta_recurrent(*args)) < TOL
@@ -73,6 +92,85 @@ def test_chunked_is_the_recurrence_outputs_and_all_gradients(chunk, decay,
                         argnums=range(5))(*args)
     for name, a, b in zip("q k v g beta".split(), got, want):
         assert apart(a, b) < TOL, name
+
+
+def test_the_cells_chunk_of_64_is_the_recurrence():
+    """Two chunks of 64, the size the kernel inverts on the chip."""
+    assert tril_inverse_fits(64)
+    is_the_recurrence(*inputs(1.0, 3.0, seed=1, t=128), chunk=64)
+
+
+def test_alike_keys_and_beta_near_2_stay_the_recurrence():
+    """Where `I + A` is worst conditioned: every key of a head the same,
+    beta 1.99, hardly any decay. `A`'s entries are then all near 1.99 and
+    `T`'s alternate in sign up to 1.99 (a Neumann product's `A^32` would
+    pass 1e27 on the way): substitution's intermediates are `T`'s own."""
+    (q, k, v, g, beta), ct = inputs(1e-4, 1.0, seed=2, t=128)
+    k = jnp.broadcast_to(k[:, :1], k.shape)
+    beta = jnp.full_like(beta, 1.99)
+    is_the_recurrence((q, k, v, g, beta), ct, chunk=64)
+
+
+def lower_triangles(c: int, lead=(2, 2, 3), seed: int = 0):
+    a = jax.random.normal(jax.random.PRNGKey(seed), lead + (c, c))
+    return jnp.tril(a, -1)
+
+
+# 1 to 16: the parametrised cases' chunks; 32: one chunk a sequence; 64: the
+# cell's. From 16 on the kernel's row-by-row substitution, below it the solve
+@pytest.mark.parametrize("c", [1, 4, 8, 16, 32, 64])
+def test_unit_lower_inverse_is_numpys(c):
+    a = lower_triangles(c)
+    want = np.linalg.inv(np.asarray(a, np.float64) + np.eye(c))
+    got = np.asarray(_unit_lower_inverse(a))
+    assert np.abs(got - want).max() < 2e-6 * np.abs(want).max()
+    # unit lower triangular to the bit: nothing above the diagonal
+    np.testing.assert_array_equal(np.triu(got, 1), 0.0)
+    np.testing.assert_array_equal(np.diagonal(got, axis1=-2, axis2=-1), 1.0)
+
+
+@pytest.mark.parametrize("rows", [5, 128, 200])
+def test_inverse_kernel_pads_its_last_tile(rows):
+    """128 matrices a grid step: fewer, exactly one tile, one and a part."""
+    a = lower_triangles(16, lead=(rows,), seed=rows)
+    want = np.linalg.inv(np.asarray(a, np.float64) + np.eye(16))
+    got = np.asarray(tril_inverse(a, interpret=True))
+    assert got.shape == a.shape
+    assert np.abs(got - want).max() < 2e-6 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("c", [8, 64])
+def test_solve_vjp_is_autodiff_through_the_solve(c):
+    """`_solve`'s backward reads `T`: `d rhs = T^T g`, `dA = -(d rhs) WU^T`
+    below the diagonal. The same numbers as autodiff through
+    `solve_triangular`, and `dA` exactly zero where `A` has no entry."""
+    a = 0.5 * lower_triangles(c, seed=3)
+    ks = jax.random.split(jax.random.PRNGKey(4), 2)
+    rhs = jax.random.normal(ks[0], a.shape[:-1] + (12,))
+    ct = jax.random.normal(ks[1], rhs.shape)
+
+    def plain(a, rhs):
+        return jax.scipy.linalg.solve_triangular(
+            a + jnp.eye(c), rhs, lower=True, unit_diagonal=True)
+
+    with jax.default_matmul_precision("highest"):
+        assert apart(_solve(a, rhs), plain(a, rhs)) < 2e-6
+        got = jax.grad(lambda *x: jnp.sum(_solve(*x) * ct), (0, 1))(a, rhs)
+        want = jax.grad(lambda *x: jnp.sum(plain(*x) * ct), (0, 1))(a, rhs)
+    np.testing.assert_array_equal(np.triu(np.asarray(got[0])), 0.0)
+    # autodiff hands back a full matrix: `a`'s own mask is upstream of it
+    assert apart(got[0], jnp.tril(want[0], -1)) < 2e-6
+    assert apart(got[1], want[1]) < 2e-6
+
+
+def test_solve_counts_its_inversions_while_tracing():
+    from deep_vision_tpu.obs.registry import get_registry
+
+    counter = get_registry().counter("delta_rule_inverse_sites_total")
+    before = counter.value
+    args, _ = inputs(1.0, 1.0)
+    jax.jit(lambda *a: gated_delta_rule(*a, chunk=8)).lower(*args)
+    assert counter.value - before == 1
 
 
 def test_bfloat16_operands_stay_near_the_float32_rule():

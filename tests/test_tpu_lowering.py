@@ -17,7 +17,9 @@ attention kernel (PR 32) buys a ViT block and what it does not: no (T, T) tensor
 program, and exactly the four layout copies of the kernel's own operands;
 the one after it what the backward kernel's second output buys (PR 34): the
 qkv bias's gradient without a pass over d(qkv), the bias still in the
-projection matmul's epilogue.
+projection matmul's epilogue. The last holds the delta rule's inverse (PR
+37): one `gdn_inverse` call a layer, kept across the block's recomputation
+by its name, and no inversion of XLA's.
 """
 import functools
 import math
@@ -36,6 +38,7 @@ from deep_vision_tpu.ops.pallas.flash_attention import (
     fused_attention,
 )
 from deep_vision_tpu.ops.pallas.nms import pallas_nms
+from deep_vision_tpu.ops.pallas.tril_inverse import tril_inverse
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 S = jax.ShapeDtypeStruct
@@ -102,6 +105,8 @@ _Q = S((2, 1024, 12, 64), jnp.bfloat16)
      (S((1, 10647, 4), jnp.float32), S((1, 10647), jnp.float32))),
     ("attn_fused_fwd", _fused_fwd_bwd, (S((2, 196, 2304), jnp.bfloat16),)),
     ("attn_fused_bwd", _fused_fwd_bwd, (S((2, 196, 2304), jnp.bfloat16),)),
+    ("gdn_inverse", lambda a: tril_inverse(a, interpret=False),
+     (S((2, 32, 30, 64, 64), jnp.float32),)),
 ])
 def test_each_kernel_carries_its_name_into_the_program(name, fn, specs):
     """A `name=` on every `pallas_call`: a trace's reader finds the kernel
@@ -113,7 +118,8 @@ def test_each_kernel_carries_its_name_into_the_program(name, fn, specs):
 @pytest.mark.parametrize("fn, shapes", [
     (_flash_fwd_bwd, [(8, 1024, 12, 64)] * 3),
     (_fused_fwd_bwd, [(8, 196, 2304)]),
-], ids=["streaming", "fused"])
+    (lambda a: tril_inverse(a, interpret=False), [(8, 6, 64, 64)]),
+], ids=["streaming", "fused", "inverse"])
 def test_kernel_in_a_multi_device_program_needs_the_mesh_context(
         mesh8, fn, shapes):
     """XLA cannot partition a Mosaic call: in a program over 8 devices the
@@ -214,6 +220,14 @@ def test_kernels_per_shard_match_their_references(mesh8):
         b, s, 20, 0.5, 0.3))(boxes, scores)
     np.testing.assert_array_equal(np.asarray(sel_i), np.asarray(ref_i))
     np.testing.assert_array_equal(np.asarray(sel_s), np.asarray(ref_s))
+
+    (a,) = _sharded(mesh8, np.tril(rng.randn(8, 3, 16, 16), -1).astype(
+        np.float32))
+    with jax.set_mesh(mesh8):
+        inverse = jax.jit(lambda a: tril_inverse(a, interpret=True))(a)
+    np.testing.assert_allclose(
+        np.asarray(inverse), np.linalg.inv(np.asarray(a) + np.eye(16)),
+        rtol=2e-4, atol=2e-4)
 
 
 # -- the real compiler, no chip: a compile-only v5e topology -----------------
@@ -428,3 +442,58 @@ def test_vit_blocks_take_the_qkv_bias_gradient_from_the_kernel(v5e,
             body = text[text.index(f"\n%{called} ("):]
             body = body[:body.index("\n}")]
             assert " convolution(" in body and "[3,12,64]" in body, name
+
+
+def _delta_layer_hlo(v5e, monkeypatch, kept: bool) -> str:
+    """The optimized HLO of a `GatedDeltaNet` layer at the cell's shapes
+    (2 x 2048 tokens, width 3840, 30 heads of 96 / 192, bf16), forward and
+    backward, recomputed as `OlmoHybrid` recomputes a block: under `_KEPT`,
+    or under the plain policy that keeps the projections' outputs alone.
+    Traced as a TPU routes it (the kernel compiled, not interpreted)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from deep_vision_tpu.core import backend
+    from deep_vision_tpu.models.olmo_hybrid import _KEPT, GatedDeltaNet
+
+    policy = _KEPT if kept else \
+        jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    layer = nn.remat(GatedDeltaNet, policy=policy)(30, 96, 192,
+                                                   dtype=jnp.bfloat16)
+    x = S((2, 2048, 3840), jnp.bfloat16)
+    variables = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+
+    def fwd_bwd(params, x):
+        return jax.value_and_grad(lambda p, x: jnp.sum(jnp.square(
+            layer.apply({"params": p}, x).astype(jnp.float32))),
+            argnums=(0, 1))(params, x)
+
+    here = SingleDeviceSharding(v5e)
+    specs = jax.tree.map(lambda s: S(s.shape, s.dtype, sharding=here),
+                         (variables["params"], x))
+    monkeypatch.setattr(backend, "current_platform", lambda: "tpu")
+    return jax.jit(fwd_bwd).lower(*specs).compile().as_text()
+
+
+@pytest.mark.parametrize("kept, inversions", [(True, 1), (False, 2)],
+                         ids=["kept_by_name", "projections_alone"])
+def test_delta_rule_layer_inverts_its_triangles_once(v5e, monkeypatch, kept,
+                                                     inversions):
+    """`T = (I + A)^-1` is made by one Pallas call, `gdn_inverse`, under the
+    scope `delta_inverse`, and a recomputed block keeps it by its name
+    (`INVERSE_NAME` in `_KEPT`): one call a layer, forward and backward
+    together. Under the plain policy the second forward makes it again:
+    two, which is what the name buys. Either way nothing is left for XLA
+    to invert (the parent's `solve_triangular` cost two
+    `InvertDiagBlocksLowerTriangular` calls a layer, 5.1 ms each on a v5e:
+    PERF.md §6, PR 37), and the scans stay three `while` ops: forward, the
+    recomputed forward, backward."""
+    text = _delta_layer_hlo(v5e, monkeypatch, kept)
+    entry = text[text.index("\nENTRY "):]
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    calls = re.findall(r"^\s*%?(\S+) = .*custom_call_target=\"tpu_custom_call\""
+                       r".*op_name=\"([^\"]*)\"", entry, re.M)
+    assert len(calls) == inversions, calls
+    for name, op_name in calls:
+        assert name.startswith("gdn_inverse"), name
+        assert "/gated_delta/delta_inverse/" in op_name, op_name
+    assert len(re.findall(r"^\s*%?\S+ = .*? while\(", entry, re.M)) == 3
