@@ -12,8 +12,10 @@ records the reference's instrumentation as one examples/sec print):
 - `stepclock`: host data-wait vs dispatch vs device-compute breakdown
   with periodic `block_until_ready` fences, plus recompile and HBM
   tracking (`StepClock`, `recompile_count`, `hbm_bytes_in_use`).
-- `trace`: Chrome trace-event spans across the data pipeline, trainers,
-  and inference — *where* the time went (`Tracer`, `span`, `set_tracer`).
+- `trace`: spans across the data pipeline, trainers and inference —
+  *where* the time went: always in a ring on the clock a profiler
+  capture is on (`trace.spans()`), and as Chrome trace events under
+  `--trace` (`Tracer`, `span`, `set_tracer`).
 - `health`: NaN/Inf guard with warn/skip_step/abort policies, rolling
   z-score divergence detection, and a hang watchdog that dumps thread
   stacks — *why* the run died (`HealthMonitor`, `TrainingHealthError`).

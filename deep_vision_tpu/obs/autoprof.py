@@ -33,11 +33,12 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from collections import deque
 from typing import Callable, Optional, Tuple
 
 from deep_vision_tpu.obs.registry import Registry, get_registry
-from deep_vision_tpu.obs.trace import start_profiler
+from deep_vision_tpu.obs.trace import start_profiler, write_capture_spans
 
 REASONS = ("static_window", "step_time_z", "data_wait_z",
            "recompile_burst", "hbm_jump", "manual")
@@ -121,6 +122,7 @@ class AutoProfiler:
         self._capturing = False
         self._capture_reason = ""
         self._capture_dir = ""
+        self._capture_since_ns = 0
         self._capture_start = 0
         self._stop_at = 0
         self._captures = 0              # auto captures started (budget)
@@ -272,7 +274,7 @@ class AutoProfiler:
         d = os.path.join(self.profile_dir, f"cap-{self._seq:03d}-{reason}")
         try:
             os.makedirs(d, exist_ok=True)
-            start_profiler(d)
+            self._capture_since_ns = start_profiler(d)
         except Exception as e:
             _release_capture()
             self._journal(reason, "failed", step=step,
@@ -300,6 +302,10 @@ class AutoProfiler:
             import jax
 
             jax.profiler.stop_trace()
+            # the loop's spans during the capture, on its clock: what a
+            # reader lays over the device's idle gaps (tools/trace_digest)
+            write_capture_spans(self._capture_dir, self._capture_since_ns,
+                                time.time_ns())
         except Exception:
             pass
         finally:

@@ -253,11 +253,16 @@ class FlightRecorder:
         return final
 
     def _span_tail(self) -> List[dict]:
+        """The last spans before the dump, as trace events: the tracer's
+        buffer where one is installed (`--trace`), else the ring's."""
         try:
-            from deep_vision_tpu.obs.trace import get_tracer
+            from deep_vision_tpu.obs import trace
 
-            t = get_tracer()
-            return t.tail(self.span_tail) if t is not None else []
+            t = trace.get_tracer()
+            if t is not None:
+                return t.tail(self.span_tail)
+            return [trace.chrome_event(s, os.getpid())
+                    for s in trace.spans()[-self.span_tail:]]
         except Exception:
             return []
 

@@ -14,6 +14,14 @@ data-wait, dispatch, and device work. StepClock separates them:
   sync_ms        on sampled steps only: how long the host waited, in a
                  block_until_ready, for the device to finish the step
 
+Every duration here is a difference of `time.perf_counter()` reads: they
+feed triggers (obs/autoprof.py, the alert rules, the stall rule below), and
+a monotonic clock cannot be stepped under them. The spans of the same
+regions (`{name}/data_wait`, `{name}/fetch`) are stamped with
+`time.time_ns()` for the ring (obs/trace.py); a committed record lays its
+`step_time_ms` on that axis as `wall_ns`, from one `time_ns()` read at
+commit, for the stall event's split.
+
 `Trainer`'s loop keeps one step in flight: it dispatches step N, then
 reads step N-1's report (post-update step counter, learning rate and
 metrics, outputs of the step program, in one `device_get`), commits and
@@ -43,12 +51,14 @@ provides it (TPU yes, CPU None).
 """
 from __future__ import annotations
 
+import statistics
 import threading
 import time
+from collections import deque
 from typing import Iterable, Iterator, Optional
 
 from deep_vision_tpu.obs.registry import Registry, get_registry
-from deep_vision_tpu.obs.trace import span
+from deep_vision_tpu.obs.trace import GC_SPAN, Span, span, spans, split_wall
 
 # -- recompile tracking ------------------------------------------------------
 
@@ -324,6 +334,9 @@ class _StepRecord:
         self.hbm_bytes: Optional[int] = None
         self.hbm_peak_bytes: Optional[int] = None
         self._t0 = 0.0
+        # `step_time_ms` laid on the span ring's axis (`time.time_ns()`,
+        # read once at commit): the interval that ends at this commit
+        self.wall_ns: "tuple[int, int]" = (0, 0)
         self._fenced = None
         self._auto_commit = auto_commit
         self._committed = False
@@ -393,6 +406,8 @@ class _StepRecord:
         else:
             self.step_time_ms = (now - mark) * 1e3
             self._clock._t_mark = now
+        end_ns = time.time_ns()
+        self.wall_ns = (end_ns - int(self.step_time_ms * 1e6), end_ns)
         if self.batch_size and self.step_time_ms > 0:
             self.examples_per_sec = self.batch_size / self.step_time_ms * 1e3
         self._clock._finish(self)
@@ -420,3 +435,53 @@ class _StepRecord:
         if self.metrics:
             out["metrics"] = {k: float(v) for k, v in self.metrics.items()}
         return out
+
+
+# -- stalls --------------------------------------------------------------------
+
+STALL_FACTOR, STALL_HISTORY, STALL_MIN_SEEN = 3.0, 64, 16
+#: the stall event's names for the loop's spans (innermost first; what no
+#: span covers is `other`); `{name}` is the clock's
+STALL_BUCKETS = {"{name}/data_wait": "data_wait", "{name}/place": "place",
+                 "{name}/dispatch": "dispatch", "{name}/fetch": "fetch",
+                 "{name}/log": "log", GC_SPAN: "gc"}
+
+
+class StallRule:
+    """Which committed steps are stalls: a `step_time_ms` over
+    `STALL_FACTOR` times the median of the last `STALL_HISTORY` committed,
+    once `STALL_MIN_SEEN` have been seen. The check is one comparison a
+    step; the limit is taken anew every `STALL_MIN_SEEN` steps."""
+
+    def __init__(self):
+        self._recent: deque = deque(maxlen=STALL_HISTORY)
+        self._seen = 0
+        self.limit_ms = float("inf")
+
+    @property
+    def median_ms(self) -> float:
+        return self.limit_ms / STALL_FACTOR
+
+    def observe(self, step_time_ms: float) -> bool:
+        stalled = step_time_ms > self.limit_ms
+        self._recent.append(step_time_ms)
+        self._seen += 1
+        if self._seen % STALL_MIN_SEEN == 0:
+            self.limit_ms = STALL_FACTOR * statistics.median(self._recent)
+        return stalled
+
+
+def stall_split(rec: "_StepRecord", open_spans=()) -> dict:
+    """A committed step's wall in ms over `data_wait / place / dispatch /
+    fetch / log / gc / other`, from the ring's spans of the calling thread
+    that overlap `rec.wall_ns`, clipped to it; `open_spans`: those the
+    caller is still inside (the `train/log` that commits), taken to the
+    commit."""
+    lo, hi = rec.wall_ns
+    name = rec._clock.name
+    held = spans(since_ns=lo, thread=threading.get_ident())
+    held += [Span(s.name, s.start_ns, hi, s.step, 0, None)
+             for s in open_spans]
+    split = split_wall(held, lo, hi, {k.format(name=name): v
+                                      for k, v in STALL_BUCKETS.items()})
+    return {k: round(v * 1e-6, 3) for k, v in split.items()}

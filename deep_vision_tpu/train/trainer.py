@@ -18,6 +18,7 @@ mesh:
 """
 from __future__ import annotations
 
+import sys
 import time
 from typing import Callable, Iterable, NamedTuple, Optional
 
@@ -32,8 +33,8 @@ from deep_vision_tpu.data.device_prefetch import DevicePrefetcher, PlacedBatch
 from deep_vision_tpu.obs import perfwatch
 from deep_vision_tpu.obs.alerts import AlertEngine, default_training_rules
 from deep_vision_tpu.obs.goodput import GoodputMeter
-from deep_vision_tpu.obs.stepclock import StepClock
-from deep_vision_tpu.obs.trace import span
+from deep_vision_tpu.obs.stepclock import StallRule, StepClock, stall_split
+from deep_vision_tpu.obs.trace import span, watch_gc
 from deep_vision_tpu.parallel.mesh import (
     DATA_AXIS,
     assert_sharding_coverage,
@@ -177,6 +178,14 @@ class Trainer:
         # goodput_interval events and a terminal goodput_summary (flushed
         # by a journal closer); alert engine (obs/alerts.py) evaluates
         # the knob-tuned training budgets over the same stream
+        # a dispatch over three times the recent median is journaled as a
+        # `stall` with where the loop's thread spent it (`_note_stall`);
+        # the interpreter's collections are among the causes
+        self._stalls = StallRule()
+        self._c_stalls = self.clock.registry.counter(
+            "train_stalls_total",
+            "dispatches whose wall exceeded three times the recent median")
+        watch_gc()
         self.goodput = (GoodputMeter(journal=journal,
                                      registry=self.clock.registry)
                         if journal is not None else None)
@@ -296,7 +305,8 @@ class Trainer:
         self.sharding_rules = sharding_rules
         self._state_shardings = None
         self._batch_axes = (DATA_AXIS,)
-        state = create_train_state(model, tx, sample_input, rng)
+        with span("setup/init_state"):
+            state = create_train_state(model, tx, sample_input, rng)
         if sharding_rules is not None:
             shardings, report = sharding_rules.resolve(state, self.mesh)
             # startup hard check FIRST: a stale table must fail before
@@ -1350,15 +1360,16 @@ class Trainer:
             host = self._host_fetch(read)
         self.clock.note_host_fetches(1)
         with span("train/log", step=rec.index) as sp:
-            opt_step = self._log_report(flight, host)
+            opt_step = self._log_report(flight, host, sp)
             sp.set(opt_step=opt_step)
         return opt_step
 
-    def _log_report(self, flight: _InFlight, host: dict) -> int:
+    def _log_report(self, flight: _InFlight, host: dict, log_span) -> int:
         """Every sink fed after a dispatch: clock (registry + journal),
-        anomaly triggers, loggers, health guard. A superstep's K
-        microsteps are recovered from the scanned stack and logged and
-        health-checked exactly as K single steps would have been."""
+        the stall rule, anomaly triggers, loggers, health guard. A
+        superstep's K microsteps are recovered from the scanned stack and
+        logged and health-checked exactly as K single steps would have
+        been. `log_span`: the `train/log` span this runs inside."""
         rec, k, epoch = flight.rec, flight.k, flight.epoch
         steps = [int(s) for s in np.atleast_1d(host.pop(_STEP))]
         opt_step = steps[-1]
@@ -1379,6 +1390,8 @@ class Trainer:
                    metrics={"loss": last["loss"], "lr": lr}
                    if "loss" in last else {"lr": lr},
                    extra={"multistep": k} if k else None)
+        if self._stalls.observe(rec.step_time_ms):
+            self._note_stall(rec, opt_step, log_span)
         # publish the host-side mirror the telemetry scraper reads (plain
         # attribute writes: benign to race, never a device fetch)
         self._live_step, self._live_epoch = opt_step, epoch
@@ -1414,6 +1427,23 @@ class Trainer:
                                        grad_norm=grad_norm_f,
                                        skipped=skipped)
         return opt_step
+
+    def _note_stall(self, rec, opt_step: int, log_span) -> None:
+        """A dispatch took over three times the median of the last 64:
+        journal it with where the loop's thread spent its wall."""
+        split = stall_split(rec, open_spans=(log_span,))
+        cause = max(split, key=split.get)
+        self._c_stalls.inc()
+        fields = dict(step=opt_step, dispatch=rec.index,
+                      step_time_ms=round(rec.step_time_ms, 3),
+                      median_ms=round(self._stalls.median_ms, 3),
+                      cause=cause, split_ms=split)
+        if self.journal is not None:
+            self.journal.write("stall", **fields)
+        print(f"[stall] step {opt_step} took {rec.step_time_ms:.1f} ms "
+              f"(median {self._stalls.median_ms:.1f}): " + ", ".join(
+                  f"{k} {v:.1f}" for k, v in split.items() if v),
+              file=sys.stderr, flush=True)
 
     def _post_epoch(self, summary, eval_data_fn, epoch, save_every):
         # failure detection the reference has none of (SURVEY §5): a

@@ -10,13 +10,16 @@ end on any host, TPU or CPU.
 from __future__ import annotations
 
 import argparse
+import functools
 import os
 import sys as _sys
+import threading
 from typing import List, Optional
 
 import numpy as np
 
 from deep_vision_tpu.configs import CONFIG_REGISTRY, ExperimentConfig, get_config
+from deep_vision_tpu.obs.trace import span, spans, split_wall
 
 
 def model_input_shape(cfg: ExperimentConfig):
@@ -352,6 +355,38 @@ def _build_schedule(cfg: ExperimentConfig, steps_per_epoch: int):
     return make_schedule(kind, base_lr, **kw)
 
 
+#: the `setup` event's names for the spans inside `setup/build_trainer`
+#: (innermost first); `other` is what none of them covers
+SETUP_BUCKETS = {"setup/imports": "imports", "setup/init_state": "init_state"}
+
+
+def _setup_journaled(build):
+    """`build` under the span `setup/build_trainer`; then where its wall
+    went, over the `setup/*` spans inside it: one `setup` event in the
+    trainer's journal and one line on stderr."""
+    @functools.wraps(build)
+    def wrapper(*args, **kw):
+        import jax  # noqa: F401  (a span is recorded once jax is loaded)
+
+        with span("setup/build_trainer") as whole:
+            trainer = build(*args, **kw)
+        split = split_wall(
+            spans(since_ns=whole.start_ns, thread=threading.get_ident()),
+            whole.start_ns, whole.end_ns, SETUP_BUCKETS)
+        split_s = {k: round(v * 1e-9, 3) for k, v in split.items()}
+        total_s = round((whole.end_ns - whole.start_ns) * 1e-9, 3)
+        if trainer.journal is not None:
+            trainer.journal.write("setup", build_trainer_s=total_s,
+                                  split_s=split_s)
+        print(f"[setup] build_trainer {total_s:.1f} s: " + ", ".join(
+            f"{k} {v:.1f}" for k, v in split_s.items()),
+            file=_sys.stderr, flush=True)
+        return trainer
+
+    return wrapper
+
+
+@_setup_journaled
 def build_trainer(cfg: ExperimentConfig, train_fn, ckpt_dir: Optional[str],
                   tb_dir: Optional[str] = None,
                   profile_dir: Optional[str] = None,
@@ -370,19 +405,20 @@ def build_trainer(cfg: ExperimentConfig, train_fn, ckpt_dir: Optional[str],
                   executable_cache=None,
                   sharding_rules=None,
                   telemetry=None):
-    import functools
-
-    from deep_vision_tpu.core import CheckpointManager
-    from deep_vision_tpu.losses import (
-        causal_lm_loss_fn,
-        centernet_loss_fn,
-        classification_loss_fn,
-        hourglass_loss_fn,
-        yolo_train_loss_fn,
-    )
-    from deep_vision_tpu.models import get_model
-    from deep_vision_tpu.train import Trainer, build_optimizer
-    from deep_vision_tpu.train.optimizers import ReduceLROnPlateau
+    with span("setup/imports"):
+        from deep_vision_tpu.core import CheckpointManager
+        from deep_vision_tpu.core.metrics import MetricLogger
+        from deep_vision_tpu.losses import (
+            causal_lm_loss_fn,
+            centernet_loss_fn,
+            classification_loss_fn,
+            hourglass_loss_fn,
+            yolo_train_loss_fn,
+        )
+        from deep_vision_tpu.models import get_model
+        from deep_vision_tpu.obs.registry import get_registry
+        from deep_vision_tpu.train import Trainer, build_optimizer
+        from deep_vision_tpu.train.optimizers import ReduceLROnPlateau
 
     # a --data-service stream has no len(): the caller passes its epoch
     # window so LR schedules are built for the steps that actually run
@@ -433,9 +469,6 @@ def build_trainer(cfg: ExperimentConfig, train_fn, ckpt_dir: Optional[str],
     plateau = ReduceLROnPlateau(**cfg.plateau) if cfg.plateau else None
     # journal-wired: quarantines and sidecar retries become typed events
     ckpt = CheckpointManager(ckpt_dir, journal=journal) if ckpt_dir else None
-    from deep_vision_tpu.core.metrics import MetricLogger
-    from deep_vision_tpu.obs.registry import get_registry
-
     tb = None
     if tb_dir:
         from deep_vision_tpu.core.tensorboard import SummaryWriter

@@ -60,6 +60,8 @@ EVENT_FIELDS = {
     "profile_capture": ("reason", "outcome"),
     "flight_dump": ("reason", "dir", "outcome"),
     "straggler": ("step", "gap_ms", "host"),
+    "setup": ("build_trainer_s", "split_s"),
+    "stall": ("step", "step_time_ms", "median_ms", "cause", "split_ms"),
     "serve_request": ("model", "latency_ms", "outcome"),
     "serve_batch": ("model", "bucket", "size"),
     "serve_drain": ("reason", "outcome", "accepted", "completed"),

@@ -21,10 +21,15 @@ the last decomposition next to the live step-time quantiles.
 The capture is read with `jax.profiler.ProfileData`, as the benchmark's
 reduction reads it (`benchmark/trace.py`, whose interval union and op
 names this file uses): no TensorFlow, no protobuf bindings. Captures taken
-by the program (`obs.trace.start_profiler`) also hold its own spans
-(`train/fetch`, `train/log`, ... — obs/README.md) in the host plane; their
-totals are printed beside the op table, so "the step got slower" splits
-into device ops and what the host loop was doing meanwhile.
+by the program (`--profile-dir`, autoprof) also hold its own spans
+(`train/fetch`, `train/log`, ... — obs/README.md): in `spans.json` in the
+capture's directory, stamped by the program with `time.time_ns()`, the clock
+of the capture's `profile_start_time` (`obs.trace.write_capture_spans`), and
+in the host plane where the session had one. Their totals are printed beside the op table, and on a TPU capture
+the device's idle time a step is split over what the loop's thread was in
+meanwhile (`benchmark/hostspans.py`'s seven names; `ClockMismatch` where
+the spans and the device planes are not on one clock), so "the step got
+slower" splits into device ops and what the host loop was doing.
 """
 from __future__ import annotations
 
@@ -37,10 +42,19 @@ from typing import Dict, List
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmark.trace import OPS_LINE, short_name, union_ns  # noqa: E402
+from benchmark import hostspans  # noqa: E402
+from benchmark.trace import (  # noqa: E402
+    DEVICE_PLANE_PREFIX,
+    MODULES_LINE,
+    OPS_LINE,
+    short_name,
+    union_ns,
+)
 
-__all__ = ["find_xplanes", "digest", "render_digest", "CATEGORIES",
-           "SPAN_PREFIXES"]
+from deep_vision_tpu.obs.trace import CAPTURE_SPANS  # noqa: E402
+
+__all__ = ["find_xplanes", "digest", "render_digest", "capture_epoch_ns",
+           "capture_spans", "host_gaps", "CATEGORIES", "SPAN_PREFIXES"]
 
 CATEGORIES = ("compute", "collective", "host")
 
@@ -89,6 +103,80 @@ def find_xplanes(path: str) -> List[str]:
     return sorted(found, reverse=True)
 
 
+_EPOCH_PLANE = "Task Environment"
+
+
+def capture_epoch_ns(profile_planes) -> "tuple[int, int]":
+    """`(profile_start_time, profile_stop_time)` of an opened capture, in
+    Unix nanoseconds: every event's `start_ns` in it counts from the
+    first, and `time.time_ns()` (the spans' stamps) is the same clock.
+    Raises where the capture has no `Task Environment` plane with both."""
+    for plane in profile_planes:
+        if plane.name == _EPOCH_PLANE:
+            stats = dict(plane.stats)
+            if "profile_start_time" in stats and "profile_stop_time" in stats:
+                return (int(stats["profile_start_time"]),
+                        int(stats["profile_stop_time"]))
+    raise ValueError(
+        f"no {_EPOCH_PLANE!r} plane with profile_start_time and "
+        "profile_stop_time: planes "
+        + ", ".join(p.name for p in profile_planes))
+
+
+def capture_spans(xplane_path: str, profile_planes):
+    """The program's spans of a capture: those of the `spans.json` in its
+    directory (`<capture>/plugins/profile/<session>/*.xplane.pb`) that lie
+    inside `[profile_start_time, profile_stop_time]`, `start_ns` and
+    `end_ns` counted from `profile_start_time` like the capture's own
+    events. None where the capture has no `spans.json`."""
+    session = os.path.dirname(os.path.abspath(xplane_path))
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        session))), CAPTURE_SPANS)
+    if not os.path.exists(path):
+        return None
+    start, stop = capture_epoch_ns(profile_planes)
+    with open(path) as f:
+        held = json.load(f)["spans"]
+    return [{**sp, "start_ns": sp["start_ns"] - start,
+             "end_ns": sp["end_ns"] - start}
+            for sp in held if sp["start_ns"] >= start and sp["end_ns"] <= stop]
+
+
+def host_gaps(profile_planes, spans) -> "dict | None":
+    """`hostspans.reduce_planes` over a capture's planes: the device's
+    idle seconds a step under the seven names. The host plane is the
+    capture's own where it holds `train/dispatch`, else one built from
+    `spans` (`capture_spans`). None where the capture has no TPU plane or
+    no spans of the loop; raises `hostspans.ClockMismatch`."""
+    planes = {}
+    for plane in profile_planes:
+        if plane.name == hostspans.HOST_PLANE:
+            planes[plane.name] = {
+                line.name: [hostspans._event(e) for e in line.events]
+                for line in plane.lines}
+        elif plane.name.startswith(DEVICE_PLANE_PREFIX):
+            planes[plane.name] = {
+                line.name: [(e.name, e.start_ns, e.duration_ns)
+                            for e in line.events]
+                for line in plane.lines
+                if line.name in (MODULES_LINE, OPS_LINE)}
+    if not any(n.startswith(DEVICE_PLANE_PREFIX) for n in planes):
+        return None
+    if not any(e[0] == hostspans.DISPATCH
+               for events in planes.get(hostspans.HOST_PLANE, {}).values()
+               for e in events):
+        by_thread: Dict[int, list] = {}
+        for sp in spans or ():
+            by_thread.setdefault(sp["thread"], []).append(
+                (sp["name"], sp["start_ns"], sp["end_ns"] - sp["start_ns"],
+                 sp.get("step")))
+        if not any(e[0] == hostspans.DISPATCH
+                   for events in by_thread.values() for e in events):
+            return None
+        planes[hostspans.HOST_PLANE] = by_thread
+    return hostspans.reduce_planes(planes)
+
+
 def digest(path: str, *, top_k: int = 12) -> dict:
     """Per-op time decomposition of the newest capture under `path`.
 
@@ -96,7 +184,9 @@ def digest(path: str, *, top_k: int = 12) -> dict:
     "mean_us"}...] top-k by total time, "totals": {compute_ms,
     collective_ms, host_ms} (each the union of its intervals per line, so
     nested events count once), "spans": the program's own spans by name,
-    "op_count", and "error" instead when the capture can't be parsed}.
+    "gaps" (a TPU capture with the loop's spans: `host_gaps`, in ms a
+    step), "op_count", and "error" instead when the capture can't be
+    parsed or its spans lie on another clock than its device planes}.
     """
     planes = find_xplanes(path)
     if not planes:
@@ -149,11 +239,33 @@ def digest(path: str, *, top_k: int = 12) -> dict:
         r["total_ms"] = round(r["total_ms"], 4)
         r["mean_us"] = round(r["total_ms"] * 1e3 / max(1, r["count"]), 2)
     totals = {f"{c}_ms": round(covered[c], 3) for c in CATEGORIES}
+    try:
+        beside = capture_spans(src, planes)
+    except ValueError as e:
+        return {"source": src, "error": f"spans.json beside a capture with {e}"}
+    if not spans:  # no host plane, or one without the program's spans
+        for sp in beside or ():
+            if _is_span(sp["name"]):
+                row = spans.setdefault(sp["name"], {
+                    "span": sp["name"], "count": 0, "total_ms": 0.0})
+                row["count"] += 1
+                row["total_ms"] += (sp["end_ns"] - sp["start_ns"]) / 1e6
     span_rows = sorted(spans.values(), key=lambda r: -r["total_ms"])
     for r in span_rows:
         r["total_ms"] = round(r["total_ms"], 4)
     out = {"source": src, "op_count": len(ops), "totals": totals,
            "ops": ops[:max(1, int(top_k))], "spans": span_rows}
+    try:
+        red = host_gaps(planes, beside)
+    except hostspans.ClockMismatch as e:
+        return {"source": src, "error": f"ClockMismatch: {e}"}
+    if red is not None:
+        gap_ms = {n: v * 1e3 for n, v in red["gap_s_per_step"].items()}
+        out["gaps"] = {"periods": red["periods"],
+                       "steps_checked": red["steps_checked"],
+                       "devices": len(red["devices"]),
+                       "gap_ms_per_step": gap_ms,
+                       "host_gap_ms": sum(gap_ms.values())}
     try:  # surface the decomposition on the live /statusz perf section
         from deep_vision_tpu.obs import perfwatch
 
@@ -186,6 +298,14 @@ def render_digest(d: dict) -> str:
         for r in d["spans"]:
             lines.append(f"{r['span']:<{w}}  {r['count']:>6}  "
                          f"{r['total_ms']:>9.3f}")
+    if d.get("gaps"):
+        g = d["gaps"]
+        lines.append(f"device idle {g['host_gap_ms']:.4f} ms a step over "
+                     f"{g['periods']} periods ({g['devices']} device(s), "
+                     f"clocks checked on {g['steps_checked']} steps), by "
+                     "where the loop's thread was:")
+        lines += [f"  {n:<16}  {v:>9.4f}"
+                  for n, v in g["gap_ms_per_step"].items()]
     return "\n".join(lines)
 
 
