@@ -22,7 +22,10 @@ learned scale and `eps` (`nn/layers.RMSNorm`):
   head (`allow_neg_eigval`: the 2); `g_t = -exp(A_log) * softplus(W_a x +
   dt_bias)` per head; the gated delta rule (`ops/gated_delta.py`); the
   output `W_o(RMSNorm_head(o_t) * silu(W_g x))`, the norm over each head's
-  `dv`.
+  `dv`. The rule works chunk-major: q, k, v cross over (`to_chunks`) as
+  their convolutions leave them, `o` crosses back as the gate takes it,
+  and the two normalisations, one (token, head) row each, are taken in
+  float32 on the chunk side: the same numbers, one narrow pass a tensor.
 
 Every width, the vocabulary and `layer_types` are arguments; the registered
 `olmo_hybrid_7b` holds the published ones. A vocabulary below the published
@@ -58,8 +61,10 @@ from deep_vision_tpu.obs.registry import get_registry
 from deep_vision_tpu.ops.gated_delta import (
     CHUNK,
     INVERSE_NAME,
-    gated_delta_rule,
+    from_chunks,
+    gated_delta_chunks,
     short_conv,
+    to_chunks,
 )
 
 LINEAR, FULL = "linear_attention", "full_attention"
@@ -160,9 +165,14 @@ class GatedDeltaNet(nn.Module):
             return y * jax.lax.rsqrt(
                 jnp.sum(jnp.square(y), axis=-1, keepdims=True) + 1e-6)
 
-        q = unit(mixed("q", dk).astype(jnp.float32)) * dk ** -0.5
-        k = unit(mixed("k", dk).astype(jnp.float32))
-        v = mixed("v", dv)
+        # a length that chunks do not divide (a tiny test) is one chunk
+        chunked = functools.partial(to_chunks,
+                                    chunk=CHUNK if t % CHUNK == 0 else t)
+        # each tensor crosses to chunk-major once, in the dtype it is
+        # stored in; the float32 row math stands on the chunk side
+        q = unit(chunked(mixed("q", dk)).astype(jnp.float32)) * dk ** -0.5
+        k = unit(chunked(mixed("k", dk)).astype(jnp.float32))
+        v = chunked(mixed("v", dv))
         f32 = functools.partial(_dense, dtype=jnp.float32)
         beta = jax.nn.sigmoid(f32(h, name="b")(x))
         if self.allow_neg_eigval:
@@ -170,13 +180,12 @@ class GatedDeltaNet(nn.Module):
         a_log = self.param("A_log", _a_log_init, (h,), jnp.float32)
         dt_bias = self.param("dt_bias", _dt_bias_init, (h,), jnp.float32)
         g = -jnp.exp(a_log) * jax.nn.softplus(f32(h, name="a")(x) + dt_bias)
-        # a length that chunks do not divide (a tiny test) is one chunk
-        o = gated_delta_rule(q, k, v, g, beta,
-                             chunk=CHUNK if t % CHUNK == 0 else t,
-                             mm_dtype=self.dtype or x.dtype)
+        o = gated_delta_chunks(q, k, v, chunked(g), chunked(beta),
+                               mm_dtype=self.dtype or x.dtype)
         gate = _dense(h * dv, self.dtype, "g")(x).reshape(b, t, h, dv)
-        o = RMSNorm(self.eps, name="o_norm")(o).astype(gate.dtype) \
-            * nn.silu(gate)
+        # and back once, rounded: the norm is over each (token, head) row
+        o = from_chunks(RMSNorm(self.eps, name="o_norm")(o).astype(
+            gate.dtype)) * nn.silu(gate)
         return _dense(x.shape[-1], self.dtype, "o")(o.reshape(b, t, h * dv))
 
 

@@ -49,6 +49,27 @@ again: from `G = d(W, U)`,
 Those three products are float32 in and out at `Precision.HIGHEST`, as the
 solve's own were: `W` and `U` are rounded to `mm_dtype` only where the
 scan's body takes them.
+
+Where the crossing stands (PR 39). The model's tensors are token-major,
+`(B, T, H, d)`; everything above is chunk-major, `(N, B, H, C, d)`, and
+the change between the two is a pass over HBM (on a TPU two: the minor
+dimension changes under the tiling, a `reshape`, and the chunks come to
+the front, a `copy`). `to_chunks` and `from_chunks` are that change and
+nothing else: they move a tensor in the dtype it arrives in, so a caller
+crosses where the tensor is narrowest (`models/olmo_hybrid.GatedDeltaNet`:
+q, k, v as the bf16 their convolutions leave, `o` as the bf16 the gate
+multiplies) and does its float32 row math (the l2 norm of q and k, the
+output's RMSNorm) on the chunk side, where it costs no pass of its own.
+`gated_delta_chunks` is the rule between the crossings and widens its
+operands where its row math first needs float32; `gated_delta_rule` is
+`from_chunks(gated_delta_chunks(to_chunks(...)))` for a token-major caller.
+`to_chunks` pins its result (`lax.optimization_barrier`): left free, XLA
+fuses the consumer's widening `convert` into the producer in front of the
+crossing and moves float32 after all (one layer, compiled for a v5e: 11
+float32 movers of 1.20 GB with the barrier left out, 1 of 0.06 GB with
+it; `tests/test_tpu_lowering.py` holds the count). The barrier's transpose
+is a barrier, so the cotangents cross back as narrow as autodiff's
+transpose of the caller's `astype` rounds them.
 """
 from __future__ import annotations
 
@@ -131,26 +152,45 @@ def _solve_bwd(residuals, d_wu):
 _solve.defvjp(_solve_fwd, _solve_bwd)
 
 
+def to_chunks(x, chunk: int):
+    """Token-major `(B, T, H, ...)` -> chunk-major `(N, B, H, C, ...)`, in
+    x's dtype: the crossing moves what it is given and widens nothing, and
+    its result is a value of the program (the barrier: XLA may not fuse a
+    consumer's widening back in front of the move)."""
+    b, t, h = x.shape[:3]
+    assert t % chunk == 0, f"{t} tokens do not divide into chunks of {chunk}"
+    x = x.reshape(b, t // chunk, chunk, h, *x.shape[3:])
+    return lax.optimization_barrier(jnp.moveaxis(x, (1, 3), (0, 2)))
+
+
+def from_chunks(x):
+    """`to_chunks`'s inverse: `(N, B, H, C, ...)` -> `(B, T, H, ...)`."""
+    n, b, h, c = x.shape[:4]
+    return jnp.moveaxis(x, (0, 2), (1, 3)).reshape(b, n * c, h, *x.shape[4:])
+
+
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK,
                      mm_dtype=jnp.float32):
     """q, k: (B, T, H, dk); v: (B, T, H, dv); g (log-decay, <= 0) and beta:
     (B, T, H). -> o (B, T, H, dv) float32. T divides into chunks."""
+    operands = (to_chunks(x, chunk) for x in (q, k, v, g, beta))
+    return from_chunks(gated_delta_chunks(*operands, mm_dtype=mm_dtype))
+
+
+def gated_delta_chunks(q, k, v, g, beta, *, mm_dtype=jnp.float32):
+    """The rule on chunk-major operands (`to_chunks`'s): q, k (N, B, H, C,
+    dk); v (N, B, H, C, dv); g and beta (N, B, H, C); any float dtype.
+    -> o (N, B, H, C, dv) float32."""
     with jax.named_scope("gated_delta"):
-        return _chunked(q, k, v, g, beta, chunk, jnp.dtype(mm_dtype))
+        return _chunked(q, k, v, g, beta, jnp.dtype(mm_dtype))
 
 
-def _chunked(q, k, v, g, beta, chunk, mm_dtype):
-    b, t, h, dk = q.shape
+def _chunked(q, k, v, g, beta, mm_dtype):
+    _, b, h, chunk, dk = q.shape
     dv = v.shape[-1]
-    assert t % chunk == 0, f"{t} tokens do not divide into chunks of {chunk}"
-    n = t // chunk
     mm = functools.partial(_mm, mm_dtype=mm_dtype)
-
-    def chunks(x):  # (B, T, H, ...) -> (N, B, H, C, ...)
-        x = x.astype(jnp.float32).reshape(b, n, chunk, h, *x.shape[3:])
-        return jnp.moveaxis(x, (1, 3), (0, 2))
-
-    q, k, v, g, beta = map(chunks, (q, k, v, g, beta))
+    # the row math below is float32; the products round to `mm_dtype`
+    q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
     y = jnp.cumsum(g, axis=-1)  # (N, B, H, C)
     diff = y[..., :, None] - y[..., None, :]  # y_i - y_j
     row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
@@ -179,5 +219,4 @@ def _chunked(q, k, v, g, beta, chunk, mm_dtype):
 
     _, o = lax.scan(body, jnp.zeros((b, h, dk, dv), jnp.float32),
                     (w, u, q_in, qk, k_out, carry_decay))
-    # (N, B, H, C, dv) -> (B, T, H, dv)
-    return jnp.moveaxis(o, (0, 2), (1, 3)).reshape(b, t, h, dv)
+    return o

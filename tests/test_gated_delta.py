@@ -21,8 +21,11 @@ from jax import lax
 from deep_vision_tpu.ops.gated_delta import (
     _solve,
     _unit_lower_inverse,
+    from_chunks,
+    gated_delta_chunks,
     gated_delta_rule,
     short_conv,
+    to_chunks,
 )
 from deep_vision_tpu.ops.pallas.tril_inverse import (
     tril_inverse,
@@ -191,6 +194,45 @@ def test_one_token_a_chunk_and_one_chunk_a_sequence_agree():
                      gated_delta_rule(*args, chunk=T)) < TOL
     with pytest.raises(AssertionError, match="do not divide"):
         gated_delta_rule(*args, chunk=5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int32])
+@pytest.mark.parametrize("trailing", [(), (8,), (4, 4)],
+                         ids=["rank3", "rank4", "rank5"])
+def test_the_two_crossings_are_inverses_and_keep_the_dtype(trailing, dtype):
+    """`to_chunks`: token t = n C + c of (B, T, H, ...) lands at [n, b, h,
+    c]; `from_chunks` brings it back; neither widens what it moves (g and
+    beta are rank 3, q, k, v rank 4, a triangle would be rank 5)."""
+    shape = (B, T, H) + trailing
+    x = jnp.arange(np.prod(shape)).reshape(shape).astype(dtype)
+    there = to_chunks(x, 8)
+    assert there.shape == (T // 8, B, H, 8) + trailing
+    assert there.dtype == x.dtype
+    np.testing.assert_array_equal(there[2, 1, 0, 3], x[1, 2 * 8 + 3, 0])
+    back = from_chunks(there)
+    assert back.dtype == x.dtype
+    np.testing.assert_array_equal(back, x)
+    np.testing.assert_array_equal(to_chunks(back, 8), there)
+    with pytest.raises(AssertionError, match="do not divide"):
+        to_chunks(x, 5)
+
+
+def test_the_token_major_rule_is_the_core_between_the_crossings():
+    """`gated_delta_rule` is `from_chunks(core(to_chunks(...)))`: the core
+    takes chunk-major operands in any float dtype and widens them itself,
+    where its row math first needs float32."""
+    args, _ = inputs(1.0, 1.0, seed=5)
+    there = [to_chunks(x, 8) for x in args]
+    o = gated_delta_chunks(*there)
+    assert o.shape == (T // 8, B, H, 8, DV) and o.dtype == jnp.float32
+    np.testing.assert_array_equal(from_chunks(o),
+                                  gated_delta_rule(*args, chunk=8))
+    # bfloat16 operands: the values the core sees are the rounded ones
+    rounded = [x.astype(jnp.bfloat16) for x in there[:3]] + there[3:]
+    widened = [x.astype(jnp.float32) for x in rounded[:3]] + there[3:]
+    assert gated_delta_chunks(*rounded).dtype == jnp.float32
+    np.testing.assert_array_equal(gated_delta_chunks(*rounded),
+                                  gated_delta_chunks(*widened))
 
 
 def test_short_conv_is_causal_and_depthwise():

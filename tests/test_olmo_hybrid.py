@@ -4,7 +4,9 @@ counters it leaves, and the repairs to `train_cli` that a rank-1 integer
 input asked for. The model against its plain reference:
 `tests/benchmark/test_olmo_hybrid_cell.py`."""
 import dataclasses
+from typing import Optional
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,8 +19,10 @@ from deep_vision_tpu.configs import (
     get_config,
     register_config,
 )
-from deep_vision_tpu.models import get_model
+from deep_vision_tpu.models import get_model, olmo_hybrid as olmo
+from deep_vision_tpu.nn.layers import RMSNorm
 from deep_vision_tpu.obs.registry import get_registry
+from deep_vision_tpu.ops.gated_delta import CHUNK, gated_delta_rule, short_conv
 
 TINY = {"hidden_size": 32, "intermediate_size": 48, "num_attention_heads": 2,
         "linear_num_heads": 2, "linear_key_head_dim": 8,
@@ -129,3 +133,142 @@ def test_build_trainer_names_what_is_wrong_with_a_task(task, says):
     cfg = ExperimentConfig(name="x", task=task, model="lenet5")
     with pytest.raises(ValueError, match=says):
         train_cli.build_trainer(cfg, lambda: [], None, steps_per_epoch=1)
+
+
+class TokenSideRowMath(nn.Module):
+    """`GatedDeltaNet` in the order it had before PR 39, from the public
+    helpers: `unit()` token-major in float32 before the crossing, `o_norm`
+    token-major after it (`gated_delta_rule` crosses both ways itself).
+    The same names, so the same parameters apply."""
+
+    num_heads: int
+    key_dim: int
+    value_dim: int
+    dtype: Optional[jnp.dtype] = None
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, _ = x.shape
+        h, dk, dv = self.num_heads, self.key_dim, self.value_dim
+
+        def mixed(name, width):
+            y = olmo._dense(h * width, self.dtype, name)(x)
+            kernel = self.param(name + "_conv", olmo._conv_init,
+                                (4, h * width), jnp.float32)
+            return nn.silu(short_conv(y, kernel)).reshape(b, t, h, width)
+
+        def unit(y):
+            return y * jax.lax.rsqrt(
+                jnp.sum(jnp.square(y), axis=-1, keepdims=True) + 1e-6)
+
+        q = unit(mixed("q", dk).astype(jnp.float32)) * dk ** -0.5
+        k = unit(mixed("k", dk).astype(jnp.float32))
+        v = mixed("v", dv)
+        beta = 2.0 * jax.nn.sigmoid(olmo._dense(h, jnp.float32, "b")(x))
+        a_log = self.param("A_log", olmo._a_log_init, (h,), jnp.float32)
+        dt_bias = self.param("dt_bias", olmo._dt_bias_init, (h,),
+                             jnp.float32)
+        g = -jnp.exp(a_log) * jax.nn.softplus(
+            olmo._dense(h, jnp.float32, "a")(x) + dt_bias)
+        o = gated_delta_rule(q, k, v, g, beta,
+                             chunk=CHUNK if t % CHUNK == 0 else t,
+                             mm_dtype=self.dtype or x.dtype)
+        gate = olmo._dense(h * dv, self.dtype, "g")(x).reshape(b, t, h, dv)
+        o = RMSNorm(1e-6, name="o_norm")(o).astype(gate.dtype) \
+            * nn.silu(gate)
+        return olmo._dense(x.shape[-1], self.dtype, "o")(
+            o.reshape(b, t, h * dv))
+
+
+def _two_orders(t, dtype):
+    """-> (the layer, the parent's order, shared parameters, x, a
+    cotangent), 2 rows of `t` tokens at the tiny widths."""
+    heads, dk, dv = 2, 8, 16
+    layer = olmo.GatedDeltaNet(heads, dk, dv, dtype=dtype)
+    before = TokenSideRowMath(heads, dk, dv, dtype=dtype)
+    ks = jax.random.split(jax.random.PRNGKey(t), 3)
+    x = jax.random.normal(ks[0], (2, t, 32), dtype or jnp.float32)
+    params = layer.init(ks[1], x)["params"]
+    assert jax.tree.structure(before.init(ks[1], x)["params"]) \
+        == jax.tree.structure(params)
+    return layer, before, params, x, jax.random.normal(ks[2], x.shape)
+
+
+@pytest.mark.parametrize("t", [128, 16], ids=["two_chunks_of_64",
+                                              "one_chunk_of_16"])
+def test_float32_layer_gives_the_token_side_orders_numbers(t):
+    """Where the row math stands changes no value: `unit()` and `o_norm`
+    work on one (token, head) row, on either side of the crossing. In
+    float32 the two orders agree to rounding, output and every parameter's
+    gradient: 1e-6 of the largest entry (a sum over rows may be taken in
+    another order; nothing else may differ)."""
+    layer, before, params, x, ct = _two_orders(t, None)
+
+    def out_and_grads(module):
+        def loss(p):
+            out = module.apply({"params": p}, x)
+            return jnp.sum(out * ct), out
+
+        (_, out), grads = jax.value_and_grad(loss, has_aux=True)(params)
+        return out, grads
+
+    (got, got_grads), (want, want_grads) = map(out_and_grads,
+                                               (layer, before))
+    assert got.dtype == want.dtype == jnp.float32
+    close = lambda a, b: float(jnp.max(jnp.abs(a - b))) \
+        <= 1e-6 * float(jnp.max(jnp.abs(b)))
+    assert close(got, want)
+    flat = jax.tree_util.tree_flatten_with_path(want_grads)[0]
+    assert len(flat) == 13
+    for (path, b), a in zip(flat, jax.tree.leaves(got_grads)):
+        assert float(jnp.max(jnp.abs(b))) > 0, path
+        assert close(a, b), jax.tree_util.keystr(path)
+
+
+def test_bfloat16_layer_stays_within_an_ulp_of_the_token_side_order():
+    """bf16 activations: q, k, v cross as bf16 and are widened on the
+    chunk side, `o` is rounded before it crosses back. The same values
+    rounded at the same points, so under `jit` the two orders' outputs
+    part by at most one bf16 ulp (2^-8 of the largest entry): what XLA's
+    excess precision across a convert pair may leave, and no more."""
+    layer, before, params, x, _ = _two_orders(128, jnp.bfloat16)
+    got, want = (jax.jit(lambda p, x, m=m: m.apply({"params": p}, x))(
+        params, x) for m in (layer, before))
+    assert got.dtype == want.dtype == jnp.bfloat16
+    got, want = (y.astype(jnp.float32) for y in (got, want))
+    assert float(jnp.max(jnp.abs(want))) > 0.01
+    assert float(jnp.max(jnp.abs(got - want))) \
+        <= 2.0 ** -8 * float(jnp.max(jnp.abs(want)))
+
+
+def test_the_parameter_tree_is_the_one_checkpoints_hold():
+    """Name for name and shape for shape what the tree was before the
+    crossing moved (PR 38's, written out): `o_norm` is the same `RMSNorm`
+    under the same name, over the last axis on either side. A checkpoint
+    of the parent restores."""
+    model = get_model("olmo_hybrid_7b", **TINY)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 16), jnp.int32))["params"]
+    have = {jax.tree_util.keystr(k): v.shape for k, v in
+            jax.tree_util.tree_flatten_with_path(params)[0]}
+    d, inter, h, dk, dv, vocab = 32, 48, 2, 8, 16, 64
+    block = {"['mixer_norm']['scale']": (d,), "['mlp_norm']['scale']": (d,)}
+    block.update({f"['mlp']['{n}']['kernel']": s for n, s in (
+        ("gate", (d, inter)), ("up", (d, inter)), ("down", (inter, d)))})
+    linear = {"['A_log']": (h,), "['dt_bias']": (h,),
+              "['o_norm']['scale']": (dv,),
+              "['q_conv']": (4, h * dk), "['k_conv']": (4, h * dk),
+              "['v_conv']": (4, h * dv)}
+    linear.update({f"['{n}']['kernel']": (d, w) for n, w in (
+        ("q", h * dk), ("k", h * dk), ("v", h * dv), ("g", h * dv),
+        ("a", h), ("b", h))})
+    linear["['o']['kernel']"] = (h * dv, d)
+    full = {f"['{n}']['kernel']": (d, d) for n in "qkvo"}
+    full.update({"['q_norm']['scale']": (d,), "['k_norm']['scale']": (d,)})
+    want = {"['embed']['embedding']": (vocab, d), "['head']": (d, vocab),
+            "['final_norm']['scale']": (d,)}
+    for i, mixer in enumerate((linear, linear, linear, full)):
+        want.update({f"['block_{i}']{k}": v for k, v in block.items()})
+        want.update({f"['block_{i}']['mixer']{k}": v
+                     for k, v in mixer.items()})
+    assert have == want

@@ -122,3 +122,46 @@ def test_a_mask_stored_beside_what_it_masks_is_counted_where_it_moves():
     assert step_bytes.pred_bytes(HLO, over=1000) == 4 * MASK
     assert step_bytes.pred_bytes(HLO, over=10) == 4 * MASK + 64
     assert step_bytes.pred_bytes(HLO) == 0  # nothing here is over 1 MB
+
+
+MOVERS = """HloModule jit_step, is_scheduled=true
+
+ENTRY %main.2 (q: bf16[2,128,60]) -> f32[2,64,6,2,10] {
+  %q = bf16[2,128,60]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %reshape.1 = bf16[2,2,64,6,10]{4,3,2,1,0:T(8,128)(2,1)} reshape(%q), metadata={op_name="jit(step)/block_0/mixer/reshape"}
+  %copy.1 = bf16[2,2,64,6,10]{4,2,3,0,1:T(8,128)(2,1)} copy(%reshape.1), metadata={op_name="jit(step)/block_0/mixer/transpose"}
+  %bitcast.1 = bf16[2,2,6,64,10]{4,3,2,1,0:T(8,128)(2,1)} bitcast(%copy.1)
+  %convert.1 = f32[2,2,6,64,10]{4,3,2,1,0:T(8,128)} convert(%bitcast.1), metadata={op_name="jit(step)/block_0/mixer/gated_delta/convert_element_type"}
+  %transpose.1 = f32[2,64,6,2,10]{4,3,2,1,0:T(8,128)} transpose(%convert.1), dimensions={0,3,2,1,4}, metadata={op_name="jit(step)/block_0/mixer/gated_delta/delta_inverse/transpose"}
+  ROOT %copy.2 = f32[2,64,6,2,10]{3,4,2,1,0:T(8,128)} copy(%transpose.1), metadata={op_name="jit(step)/block_1/mlp/transpose"}
+}
+"""
+Q = 2 * 128 * 60  # elements
+
+
+def test_movers_are_counted_by_the_dtype_they_move():
+    """`mover_gb`: `copy`, `reshape` and `transpose` of the entry
+    computation read what they write; a `bitcast` moves nothing and a
+    `convert` is no mover (PERF.md §6, PR 39)."""
+    assert step_bytes.mover_bytes(MOVERS) == {
+        "bf16": 2 * 2 * (2 * Q), "f32": 2 * 2 * (4 * Q)}
+    assert step_bytes.mover_bytes(HLO) == {"bf16": 2 * ACT}  # `copy.1`
+
+
+def test_scopes_take_each_instruction_once_by_the_first_that_matches():
+    got = step_bytes.classify(MOVERS)
+    seconds = {"copy.1": 1e-3, "transpose.1": 2e-3, "convert.1": 4e-3}
+    rows = step_bytes.by_scope(
+        MOVERS, got, ["/delta_inverse/", "/gated_delta/", r"block_\d/mixer/"],
+        seconds)
+    assert rows["/delta_inverse/"] == {
+        "ops": 1, "gb": 8 * Q / 1e9, "ms": 2.0,
+        "mover_ops": 1, "mover_gb": 8 * Q / 1e9, "mover_ms": 2.0}
+    # the convert: in the scope, not a mover
+    assert rows["/gated_delta/"] == {
+        "ops": 1, "gb": 6 * Q / 1e9, "ms": 4.0,
+        "mover_ops": 0, "mover_gb": 0, "mover_ms": 0}
+    assert rows[r"block_\d/mixer/"]["mover_ops"] == 2
+    assert rows[r"block_\d/mixer/"]["mover_ms"] == 1.0
+    assert rows[""]["mover_gb"] == 8 * Q / 1e9  # `copy.2`: none of them
+    assert sum(r["ops"] for r in rows.values()) == len(got) == 5

@@ -444,12 +444,18 @@ def test_vit_blocks_take_the_qkv_bias_gradient_from_the_kernel(v5e,
             assert " convolution(" in body and "[3,12,64]" in body, name
 
 
+_DELTA_LAYER_HLO = {}
+
+
 def _delta_layer_hlo(v5e, monkeypatch, kept: bool) -> str:
     """The optimized HLO of a `GatedDeltaNet` layer at the cell's shapes
     (2 x 2048 tokens, width 3840, 30 heads of 96 / 192, bf16), forward and
     backward, recomputed as `OlmoHybrid` recomputes a block: under `_KEPT`,
     or under the plain policy that keeps the projections' outputs alone.
-    Traced as a TPU routes it (the kernel compiled, not interpreted)."""
+    Traced as a TPU routes it (the kernel compiled, not interpreted).
+    Compiled once a policy: 20 s each."""
+    if kept in _DELTA_LAYER_HLO:
+        return _DELTA_LAYER_HLO[kept]
     from jax.sharding import SingleDeviceSharding
 
     from deep_vision_tpu.core import backend
@@ -471,7 +477,9 @@ def _delta_layer_hlo(v5e, monkeypatch, kept: bool) -> str:
     specs = jax.tree.map(lambda s: S(s.shape, s.dtype, sharding=here),
                          (variables["params"], x))
     monkeypatch.setattr(backend, "current_platform", lambda: "tpu")
-    return jax.jit(fwd_bwd).lower(*specs).compile().as_text()
+    _DELTA_LAYER_HLO[kept] = jax.jit(fwd_bwd).lower(
+        *specs).compile().as_text()
+    return _DELTA_LAYER_HLO[kept]
 
 
 @pytest.mark.parametrize("kept, inversions", [(True, 1), (False, 2)],
@@ -497,3 +505,42 @@ def test_delta_rule_layer_inverts_its_triangles_once(v5e, monkeypatch, kept,
         assert name.startswith("gdn_inverse"), name
         assert "/gated_delta/delta_inverse/" in op_name, op_name
     assert len(re.findall(r"^\s*%?\S+ = .*? while\(", entry, re.M)) == 3
+
+
+@pytest.mark.parametrize("kept, float32_gb, all_gb", [
+    (True, 0.07, 1.85), (False, 0.13, 2.05)],
+    ids=["kept_by_name", "projections_alone"])
+def test_delta_rule_layer_crosses_to_chunks_in_the_stored_dtype(
+        v5e, monkeypatch, kept, float32_gb, all_gb):
+    """q, k, v and o change between token-major `(B, T, H, d)` and
+    chunk-major `(N, B, H, C, d)` once each way a pass (forward, recomputed
+    forward, backward), as the bf16 they are stored in: `to_chunks` moves
+    what it is given and pins its result, `unit()` and `o_norm` do their
+    float32 row math on the chunk side (PERF.md §6, PR 39). Before, the
+    row math stood token-side of the crossing and XLA moved every one of
+    these tensors as float32, in two and three passes: 22 float32 `copy` /
+    `reshape` / `transpose` ops over 10 MB outside `delta_inverse` moving
+    2.71 GB a layer, 3.41 GB for all movers; without the pin XLA fuses the
+    widening into the silu in front of the crossing and 11 ops / 1.20 GB
+    stay. What is left in float32 is `A`'s layout for `gdn_inverse` (31.5
+    MB, once where `T` is kept, twice where it is made again). Bytes: an
+    op reads what it writes. Limits just above the count, so that a
+    crossing that widens again, or a third pass, shows."""
+    text = _delta_layer_hlo(v5e, monkeypatch, kept)
+    entry = text[text.index("\nENTRY "):]
+    movers = [(dtype, 2 * {"f32": 4, "bf16": 2}[dtype]
+               * math.prod(map(int, dims.split(","))), rest)
+              for dtype, dims, rest in re.findall(
+                  r"= (f32|bf16)\[([\d,]+)\]\S* (?:copy|reshape|transpose)"
+                  r"\((.*)$", entry, re.M)]
+    wide = [moved for dtype, moved, rest in movers
+            if dtype == "f32" and moved > 2 * 10e6
+            and "/delta_inverse/" not in rest]
+    assert sum(wide) / 1e9 <= float32_gb, (len(wide), sum(wide) / 1e9)
+    assert len(wide) == (1 if kept else 2)
+    assert sum(moved for _, moved, _ in movers) / 1e9 <= all_gb
+    # and the crossings are there, narrow: q, k (23.6 MB) and v, o (47.2),
+    # 22 passes (the scans and the one `gdn_inverse`: the test above)
+    narrow = sum(moved for dtype, moved, _ in movers
+                 if dtype == "bf16" and moved > 2 * 10e6)
+    assert narrow / 1e9 == pytest.approx(1.51, abs=0.01)

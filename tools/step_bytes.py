@@ -3,6 +3,7 @@
 
     JAX_PLATFORMS=cpu python tools/step_bytes.py --workload resnet50_train_b128
     ... --ops chiprun_out/<dir>/ops.json   # + traced ms by kind, from a chip
+    ... --scopes /gated_delta/ /mlp/       # + both by where in the model
 
 The step program of a benchmark cell (`BENCHMARK.json`), built the way the
 cell builds it (`benchmark/adapters/train.build_trainer`, the Trainer's own
@@ -13,10 +14,15 @@ computation is then charged its operands' bytes plus its result's, and
 summed by kind. A step bound by HBM bandwidth takes that sum over 819 GB/s
 (PERF.md §5, which also reckons 14.5 GB as ResNet-50's floor at batch 128).
 `pred_gb` is the part of all that in `pred` arrays over 1 MB: a mask
-stored beside what it masks (PR 35). Nothing runs: no time comes from
-here. `--ops` takes a chip's traced table {op name: seconds a step}
-(`--dump-ops`, on the chip, writes one) and lays its milliseconds beside
-the bytes, by the same kinds.
+stored beside what it masks (PR 35); `mover_gb`, by dtype, the part in
+`copy`, `reshape` and `transpose` instructions: a tensor that changes
+layout and nothing else (PR 39). The state's arrays are never built
+(`jax.eval_shape`), so a cell whose state fills a chip counts here too.
+Nothing runs: no time comes from here. `--ops` takes a chip's traced
+table {op name: seconds a step} (`--dump-ops`, on the chip, writes one)
+and lays its milliseconds beside the bytes, by the same kinds; `--scopes`
+sums both by where in the model an instruction comes from (its
+`op_name`), the movers' part apart.
 """
 from __future__ import annotations
 
@@ -105,6 +111,20 @@ def pred_bytes(hlo: str, over: int = 1_000_000) -> int:
         b for b in shapes_bytes(text, "pred") if b > over]))
 
 
+def mover_bytes(hlo: str) -> dict:
+    """{dtype: bytes} that the entry computation's `copy`, `reshape` and
+    `transpose` instructions read and write, by their result's dtype: a
+    tensor that changes layout and nothing else (PERF.md §6, PR 39)."""
+    out = defaultdict(int)
+    for line in computations(hlo)["ENTRY"]:
+        m = _INSTR.match(line)
+        if m and m.group(3) in ("copy", "reshape", "transpose"):
+            # a mover reads what it writes: twice the result
+            out[_SHAPE.search(m.group(2)).group(1)] += 2 * sum(
+                shapes_bytes(m.group(2)))
+    return dict(out)
+
+
 def classify(hlo: str, param_bytes: set = frozenset()) -> dict:
     """{instruction of the entry computation: (kind, bytes)}. A fusion is a
     convolution fusion if a convolution is inside, else a reduction fusion
@@ -148,29 +168,62 @@ def classify(hlo: str, param_bytes: set = frozenset()) -> dict:
     return out
 
 
+def _op_ms(classified: dict, op_seconds: dict | None):
+    """-> ({instruction: traced ms a step}, the ms of ops this program does
+    not have). An async pair's time is its -done's wait; the -start is an
+    issue."""
+    unmatched, op_ms = 0.0, defaultdict(float)
+    for name, seconds in (op_seconds or {}).items():
+        base = re.sub(r"-done(\.\d+)?$", r"-start\1", name)
+        if base in classified:
+            op_ms[base] += seconds * 1e3
+        else:
+            unmatched += seconds * 1e3
+    return op_ms, unmatched
+
+
 def table(classified: dict, op_seconds: dict | None = None,
           largest: int = 12) -> dict:
     """-> {"kinds": {kind: {"ops", "gb", "ms"}}, "largest": the ops that
     move most, as [name, kind, MB, ms], "unmatched_ms": the traced time of
     ops this program does not have (another program was traced)}."""
     rows = {k: {"ops": 0, "gb": 0.0, "ms": 0.0} for k in KINDS}
-    for kind, moved in classified.values():
+    op_ms, unmatched = _op_ms(classified, op_seconds)
+    for name, (kind, moved) in classified.items():
         rows[kind]["ops"] += 1
         rows[kind]["gb"] += moved / 1e9
-    unmatched, op_ms = 0.0, defaultdict(float)
-    for name, seconds in (op_seconds or {}).items():
-        # an async pair's time is its -done's wait; the -start is an issue
-        base = re.sub(r"-done(\.\d+)?$", r"-start\1", name)
-        if base in classified:
-            op_ms[base] += seconds * 1e3
-            rows[classified[base][0]]["ms"] += seconds * 1e3
-        else:
-            unmatched += seconds * 1e3
+        rows[kind]["ms"] += op_ms.get(name, 0.0)
     top = sorted(classified, key=lambda n: -classified[n][1])[:largest]
     return {"kinds": {k: v for k, v in rows.items() if v["ops"]},
             "largest": [[n, classified[n][0], classified[n][1] / 1e6,
                          op_ms.get(n, 0.0)] for n in top],
             "unmatched_ms": unmatched}
+
+
+_MOVERS = ("copy", "reshape/transpose")
+
+
+def by_scope(hlo: str, classified: dict, scopes: list,
+             op_seconds: dict | None = None) -> dict:
+    """-> {scope: {"ops", "gb", "ms", "mover_ops", "mover_gb", "mover_ms"}}:
+    each instruction charged to the first of `scopes` (regular
+    expressions) found in its `op_name`, to "" where none is; `mover_*` the
+    part of it in `copy`, `reshape` and `transpose` instructions."""
+    op_ms, _ = _op_ms(classified, op_seconds)
+    rows = defaultdict(lambda: dict.fromkeys(
+        ("ops", "gb", "ms", "mover_ops", "mover_gb", "mover_ms"), 0))
+    for name, _, rest, *_ in _moves(hlo):
+        if name not in classified:
+            continue
+        kind, moved = classified[name]
+        op_name = re.search(r'op_name="([^"]*)"', rest)
+        row = rows[next((s for s in scopes if op_name and re.search(
+            s, op_name.group(1))), "")]
+        for prefix in ("", "mover_") if kind in _MOVERS else ("",):
+            row[prefix + "ops"] += 1
+            row[prefix + "gb"] += moved / 1e9
+            row[prefix + "ms"] += op_ms.get(name, 0.0)
+    return dict(rows)
 
 
 def _described_mesh(like):
@@ -200,24 +253,51 @@ def _cell(workload: str, manifest_path: str):
 
 def compile_step(workload: str, manifest_path: str):
     """-> (the compiled step of the cell, the byte sizes of its parameter
-    leaves)."""
+    leaves). The state is shapes alone (`jax.eval_shape` of the Trainer's
+    `create_train_state`: a 0.93 B-parameter state is never built here),
+    the batch one of the cell's traffic, zeros."""
     cell, config, traffic, adapter = _cell(workload, manifest_path)
     if cell["chips"] > 1:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={cell['chips']}")
+    from unittest import mock
+
     import jax
     import numpy as np
     from jax.sharding import NamedSharding, SingleDeviceSharding
 
+    from benchmark import traffic as traffic_mod
     from deep_vision_tpu.core import backend
+    from deep_vision_tpu.parallel.mesh import replicated
+    from deep_vision_tpu.train import trainer as trainer_mod
 
-    trainer, _, image_shape = adapter.build_trainer(
-        config, traffic["global_batch"])
-    n = traffic["global_batch"]
+    make_state = trainer_mod.create_train_state
+
+    def place_abstract(self, state):  # as `_place_state` without a table
+        where = replicated(self.mesh)
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=where), state)
+
+    def abstract_state(*args):
+        """Shapes alone, but for the scalars (the step, the optimizer's
+        hyperparameters: the Trainer reads its base rate), which a jit
+        that returns nothing else makes without the arrays."""
+        shapes = jax.eval_shape(lambda: make_state(*args))
+        scalars = iter(jax.jit(lambda: [
+            x for x in jax.tree.leaves(make_state(*args)) if x.ndim == 0])())
+        return jax.tree.map(
+            lambda x: x if x.ndim else next(scalars), shapes)
+
+    with mock.patch.object(trainer_mod, "create_train_state",
+                           abstract_state), \
+            mock.patch.object(trainer_mod.Trainer, "_place_state",
+                              place_abstract):
+        trainer, _, input_shape = adapter.build_trainer(
+            config, traffic["global_batch"])
     batch = trainer._place_one({
-        "image": np.zeros((n, *image_shape), np.float32),
-        "label": np.zeros((n,), np.int32)}).data
+        name: np.zeros(spec.shape, spec.dtype) for name, spec in
+        traffic_mod.batch_spec(traffic, config, input_shape).items()}).data
     mesh = _described_mesh(trainer.mesh)
 
     def described(x):
@@ -270,6 +350,11 @@ def main(argv=None):
     parser.add_argument("--ops", help="a chip's {op: seconds a step} table")
     parser.add_argument("--dump-ops", metavar="OUT",
                         help="on the chip: trace the cell, write its table")
+    parser.add_argument("--scopes", nargs="+", metavar="REGEX",
+                        help="bytes and ms by the first of these found in "
+                        "an instruction's op_name")
+    parser.add_argument("--hlo", metavar="OUT",
+                        help="write the compiled step's text there")
     parser.add_argument("--seed", type=int, default=0)
     parser.add_argument("--seconds", type=float, default=20.0)
     args = parser.parse_args(argv)
@@ -279,11 +364,17 @@ def main(argv=None):
     t0 = time.time()
     compiled, leaves = compile_step(args.workload, args.manifest)
     text = compiled.as_text()
+    if args.hlo:
+        with open(args.hlo, "w") as f:
+            f.write(text)
     ops = None
     if args.ops:
         with open(args.ops) as f:
             ops = json.load(f)["op_s_per_step"]
-    result = table(classify(text, leaves), ops)
+    classified = classify(text, leaves)
+    result = table(classified, ops)
+    if args.scopes:
+        result["scopes"] = by_scope(text, classified, args.scopes, ops)
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
     result.update(
@@ -291,6 +382,7 @@ def main(argv=None):
         tpu_custom_calls=text.count('custom_call_target="tpu_custom_call"'),
         temp_gb=mem.temp_size_in_bytes / 1e9,
         pred_gb=pred_bytes(text) / 1e9,
+        mover_gb={d: b / 1e9 for d, b in sorted(mover_bytes(text).items())},
         cost_analysis_gb=(cost[0] if isinstance(cost, list) else cost).get(
             "bytes accessed", 0.0) / 1e9,
         sync_gb=sum(v["gb"] for k, v in result["kinds"].items()
