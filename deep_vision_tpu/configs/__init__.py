@@ -261,3 +261,21 @@ register_config(ExperimentConfig(
     schedule={"kind": "cosine", "warmup_epochs": 5, "total_epochs": 90},
     dataset={"kind": "fake"},
 ))
+
+register_config(ExperimentConfig(
+    # upstage/Solar-Open2-250B at its published widths, as one chip of the
+    # 40 that share a 4-layer stage holds it: experts 0-7 of 320 (the
+    # router keeps all 320), half the heads (2-way tensor parallel), an
+    # eighth of the vocabulary. The recipe is OLMo 2's, as olmo_hybrid_7b's
+    # (the row gives none); two sequences of 2048 tokens is what 16 GB
+    # holds beside the stage's 12.4 GB training state
+    name="solar_open2_250b", task="causal_lm", model="solar_open2_250b",
+    model_kwargs={"num_hidden_layers": 4, "vocab_size": 24576,
+                  "num_attention_heads": 32, "num_key_value_heads": 4,
+                  "linear_num_heads": 32, "n_routed_experts": 8},
+    input_shape=(2048,), batch_size=2, epochs=1,
+    optimizer={"name": "adamw", "learning_rate": 3e-4, "b1": 0.9,
+               "b2": 0.95, "weight_decay": 0.1},
+    schedule={"kind": "cosine", "warmup_epochs": 5, "total_epochs": 90},
+    dataset={"kind": "fake"},
+))

@@ -35,7 +35,10 @@ def _block_nll(hidden, head, targets, weights):
 
 
 def causal_lm_loss_fn(outputs, batch, block_tokens: int = BLOCK_TOKENS):
-    """-> (loss, {"loss": loss}). batch: {"tokens": int (B, T)}."""
+    """-> (loss, {"loss": loss, **the model's "report"}). batch:
+    {"tokens": int (B, T)}. A model that counts in its step (the held
+    experts' pairs: `models/solar_open2.py`) hands the counts over as
+    `outputs["report"]`, and they ride in the step's metrics."""
     tokens = batch["tokens"]
     hidden, head = outputs["hidden"], outputs["head"]
     b, t = tokens.shape
@@ -49,4 +52,4 @@ def causal_lm_loss_fn(outputs, batch, block_tokens: int = BLOCK_TOKENS):
                            targets[:, i:i + block], weights[:, i:i + block])
                 for i in range(0, t, block))
     loss = total / jnp.maximum(jnp.sum(weights), 1.0)
-    return loss, {"loss": loss}
+    return loss, {"loss": loss, **outputs.get("report", {})}

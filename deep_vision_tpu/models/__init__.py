@@ -41,3 +41,4 @@ from deep_vision_tpu.models import dcgan  # noqa: E402,F401
 from deep_vision_tpu.models import cyclegan  # noqa: E402,F401
 from deep_vision_tpu.models import vit  # noqa: E402,F401
 from deep_vision_tpu.models import olmo_hybrid  # noqa: E402,F401
+from deep_vision_tpu.models import solar_open2  # noqa: E402,F401

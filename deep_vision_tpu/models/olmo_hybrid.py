@@ -55,12 +55,20 @@ import jax
 import jax.numpy as jnp
 
 from deep_vision_tpu.models import register_model
-from deep_vision_tpu.models.vit import attention_path, flash_attention
+from deep_vision_tpu.models.decoder import (
+    INIT as _INIT,
+    KEPT as _KEPT,
+    a_log_init,
+    causal_attention,
+    conv_init,
+    count_mixer_site,
+    dense as _dense,
+    dt_bias_init,
+    l2_unit,
+)
 from deep_vision_tpu.nn.layers import RMSNorm, SwiGLU
-from deep_vision_tpu.obs.registry import get_registry
 from deep_vision_tpu.ops.gated_delta import (
     CHUNK,
-    INVERSE_NAME,
     from_chunks,
     gated_delta_chunks,
     short_conv,
@@ -68,44 +76,6 @@ from deep_vision_tpu.ops.gated_delta import (
 )
 
 LINEAR, FULL = "linear_attention", "full_attention"
-_INIT = nn.initializers.normal(0.02)
-# what a recomputed block keeps from its first forward: every product with
-# a kernel (no batch dimension: not the delta rule's, not the scores), and
-# the delta rule's inverse triangles, by name
-_KEPT = jax.checkpoint_policies.save_from_both_policies(
-    jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-    jax.checkpoint_policies.save_only_these_names(INVERSE_NAME))
-
-
-def _dense(features, dtype, name):
-    return nn.Dense(features, use_bias=False, dtype=dtype, kernel_init=_INIT,
-                    name=name)
-
-
-def _count_site(kind: str) -> None:
-    # counted while tracing, beside `attention_sites_total{path}`
-    get_registry().counter(
-        "sequence_mixer_sites_total", "Sequence mixers traced, by kind",
-        labels={"kind": kind}).inc()
-
-
-def _conv_init(key, shape, dtype=jnp.float32):
-    """torch's `Conv1d` default over a fan-in of the taps: U(-K^-1/2, K^-1/2)."""
-    bound = shape[0] ** -0.5
-    return jax.random.uniform(key, shape, dtype, -bound, bound)
-
-
-def _a_log_init(key, shape, dtype=jnp.float32):
-    """FLA's: `A` uniform in (0, 16), kept as its logarithm."""
-    return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
-
-
-def _dt_bias_init(key, shape, dtype=jnp.float32, lo=1e-3, hi=0.1):
-    """FLA's (Mamba's): a step `dt` log-uniform in (lo, hi), kept as the
-    inverse of softplus, so that `softplus(dt_bias)` starts at `dt`."""
-    dt = jnp.exp(jax.random.uniform(key, shape, dtype)
-                 * (jnp.log(hi) - jnp.log(lo)) + jnp.log(lo))
-    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 class FullAttention(nn.Module):
@@ -118,25 +88,11 @@ class FullAttention(nn.Module):
         b, t, d = x.shape
         h = self.num_heads
         assert d % h == 0, f"dim {d} not divisible by {h} heads"
-        _count_site("full")
+        count_mixer_site("full")
         q = RMSNorm(self.eps, name="q_norm")(_dense(d, self.dtype, "q")(x))
         k = RMSNorm(self.eps, name="k_norm")(_dense(d, self.dtype, "k")(x))
         v = _dense(d, self.dtype, "v")(x)
-        q, k, v = (y.reshape(b, t, h, d // h) for y in (q, k, v))
-        # ViT's choice by shape; its one-block kernel has no causal mask
-        path = attention_path(t, h, d)
-        path = "dense" if path == "fused" else path
-        get_registry().counter(
-            "attention_sites_total", "Attention sites traced, by the path "
-            "their shape chose", labels={"path": path}).inc()
-        if path == "streaming":
-            o = flash_attention(q, k, v, causal=True)
-        else:
-            s = jnp.einsum("bthd,bshd->bhts", q, k) * (d // h) ** -0.5
-            s = jnp.where(jnp.tril(jnp.ones((t, t), bool)),
-                          s.astype(jnp.float32), -jnp.inf)
-            p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-            o = jnp.einsum("bhts,bshd->bthd", p, v)
+        o = causal_attention(*(y.reshape(b, t, h, d // h) for y in (q, k, v)))
         return _dense(d, self.dtype, "o")(o.reshape(b, t, d))
 
 
@@ -153,32 +109,28 @@ class GatedDeltaNet(nn.Module):
     def __call__(self, x):
         b, t, _ = x.shape
         h, dk, dv = self.num_heads, self.key_dim, self.value_dim
-        _count_site("linear")
+        count_mixer_site("linear")
 
         def mixed(name, width):
             y = _dense(h * width, self.dtype, name)(x)
-            kernel = self.param(name + "_conv", _conv_init,
+            kernel = self.param(name + "_conv", conv_init,
                                 (self.conv, h * width), jnp.float32)
             return nn.silu(short_conv(y, kernel)).reshape(b, t, h, width)
-
-        def unit(y):  # float32 in, float32 out
-            return y * jax.lax.rsqrt(
-                jnp.sum(jnp.square(y), axis=-1, keepdims=True) + 1e-6)
 
         # a length that chunks do not divide (a tiny test) is one chunk
         chunked = functools.partial(to_chunks,
                                     chunk=CHUNK if t % CHUNK == 0 else t)
         # each tensor crosses to chunk-major once, in the dtype it is
         # stored in; the float32 row math stands on the chunk side
-        q = unit(chunked(mixed("q", dk)).astype(jnp.float32)) * dk ** -0.5
-        k = unit(chunked(mixed("k", dk)).astype(jnp.float32))
+        q = l2_unit(chunked(mixed("q", dk)).astype(jnp.float32)) * dk ** -0.5
+        k = l2_unit(chunked(mixed("k", dk)).astype(jnp.float32))
         v = chunked(mixed("v", dv))
         f32 = functools.partial(_dense, dtype=jnp.float32)
         beta = jax.nn.sigmoid(f32(h, name="b")(x))
         if self.allow_neg_eigval:
             beta = 2.0 * beta
-        a_log = self.param("A_log", _a_log_init, (h,), jnp.float32)
-        dt_bias = self.param("dt_bias", _dt_bias_init, (h,), jnp.float32)
+        a_log = self.param("A_log", a_log_init, (h,), jnp.float32)
+        dt_bias = self.param("dt_bias", dt_bias_init, (h,), jnp.float32)
         g = -jnp.exp(a_log) * jax.nn.softplus(f32(h, name="a")(x) + dt_bias)
         o = gated_delta_chunks(q, k, v, chunked(g), chunked(beta),
                                mm_dtype=self.dtype or x.dtype)

@@ -14,6 +14,11 @@ import threading
 import time
 from typing import Dict, Iterable, List, Optional, Tuple
 
+# A step's metric named `COUNT_PREFIX + name` is a count the model made in
+# the step: the trainer sums it into the counter `<name>_total` and logs it
+# as `name`.
+COUNT_PREFIX = "count/"
+
 
 def is_primary_host() -> bool:
     """True when this process should own file writers (process 0).

@@ -38,7 +38,7 @@ from row-by-row forward substitution with the chunks in the lanes, one
 Pallas call (`ops/pallas/tril_inverse.py`, `gdn_inverse`; below its sizes,
 a tiny test's chunk, from one `solve_triangular` against the identity),
 under the scope `delta_inverse`, and carries the name `INVERSE_NAME`, by
-which a recomputed block keeps it (`models/olmo_hybrid._KEPT`): 64 x 64
+which a recomputed block keeps it (`models/decoder.KEPT`): 64 x 64
 float32 a chunk, 31.5 MB a layer at 2 x 2048 tokens and 30 heads. `W, U =
 T rhs` is then a product, and so is the backward pass (`_solve`'s
 `custom_vjp`), which reads `T` where autodiff through a solve would invert
@@ -70,6 +70,25 @@ float32 movers of 1.20 GB with the barrier left out, 1 of 0.06 GB with
 it; `tests/test_tpu_lowering.py` holds the count). The barrier's transpose
 is a barrier, so the cotangents cross back as narrow as autodiff's
 transpose of the caller's `astype` rounds them.
+
+A decay per key channel (Kimi Delta Attention, arXiv:2510.26692: `S_t =
+(I - b_t k_t k_t^T) Diag(e^{g_t}) S_{t-1} + b_t k_t v_t^T` with `g_t` in
+R^{dk}) is the same rule with `y` of `(C, dk)`, the running sum per
+channel. The shape of `g` selects it: `(N, B, H, C)` is the scalar decay
+above, `(N, B, H, C, dk)` the decay per channel (scope `kda`). Then
+
+    A_ij = b_i sum_d k_id k_jd e^{y_id - y_jd}           j < i
+    W = T (b K e^{y}),   U = T (b V),   V' = U - W M
+    O = (Q e^{y}) M + tril(sum_d q_id k_jd e^{y_id - y_jd}) V'
+    M <- Diag(e^{y_C}) M + (K e^{y_C - y})^T V'
+
+and a `y` broadcast over `dk` gives the lines above. The sum over `d` no
+longer splits into a decay times one product, and `e^{y_i}` alone may
+underflow along a chunk where `e^{-y_j}` alone overflows. So `A` and the
+`Q K^T` term are taken in sub-chunks of `SUB` tokens: between two of
+them through the first token `r` of the later one, `e^{y_i - y_r}
+e^{y_r - y_j}`, both exponents <= 0, as one product; on the diagonal
+blocks the exponent pairwise, summed over `d` in one fusion.
 """
 from __future__ import annotations
 
@@ -87,7 +106,8 @@ from deep_vision_tpu.ops.pallas.tril_inverse import (
 )
 
 CHUNK = 64
-# the name `T` carries to a recomputation's policy (`models/olmo_hybrid.py`)
+SUB = 16  # a per-channel decay's sub-chunk: its exponents taken pairwise
+# the name `T` carries to a recomputation's policy (`models/decoder.KEPT`)
 INVERSE_NAME = "delta_rule_inverse"
 _EXACT = lax.Precision.HIGHEST
 
@@ -171,26 +191,31 @@ def from_chunks(x):
 
 def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK,
                      mm_dtype=jnp.float32):
-    """q, k: (B, T, H, dk); v: (B, T, H, dv); g (log-decay, <= 0) and beta:
-    (B, T, H). -> o (B, T, H, dv) float32. T divides into chunks."""
+    """q, k: (B, T, H, dk); v: (B, T, H, dv); g (log-decay, <= 0): (B, T,
+    H), or (B, T, H, dk) a decay per key channel; beta: (B, T, H). -> o
+    (B, T, H, dv) float32. T divides into chunks."""
     operands = (to_chunks(x, chunk) for x in (q, k, v, g, beta))
     return from_chunks(gated_delta_chunks(*operands, mm_dtype=mm_dtype))
 
 
 def gated_delta_chunks(q, k, v, g, beta, *, mm_dtype=jnp.float32):
     """The rule on chunk-major operands (`to_chunks`'s): q, k (N, B, H, C,
-    dk); v (N, B, H, C, dv); g and beta (N, B, H, C); any float dtype.
-    -> o (N, B, H, C, dv) float32."""
+    dk); v (N, B, H, C, dv); g (N, B, H, C), or (N, B, H, C, dk) a decay
+    per key channel; beta (N, B, H, C); any float dtype. -> o (N, B, H, C,
+    dv) float32."""
+    if g.ndim == q.ndim:
+        with jax.named_scope("kda"):
+            return _chunked_per_channel(q, k, v, g, beta,
+                                        jnp.dtype(mm_dtype))
     with jax.named_scope("gated_delta"):
         return _chunked(q, k, v, g, beta, jnp.dtype(mm_dtype))
 
 
 def _chunked(q, k, v, g, beta, mm_dtype):
-    _, b, h, chunk, dk = q.shape
-    dv = v.shape[-1]
     mm = functools.partial(_mm, mm_dtype=mm_dtype)
     # the row math below is float32; the products round to `mm_dtype`
     q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
+    chunk, dk = q.shape[-2:]
     y = jnp.cumsum(g, axis=-1)  # (N, B, H, C)
     diff = y[..., :, None] - y[..., None, :]  # y_i - y_j
     row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
@@ -207,6 +232,14 @@ def _chunked(q, k, v, g, beta, mm_dtype):
     q_in = q * jnp.exp(y)[..., None]
     k_out = k * jnp.exp(y[..., -1:] - y)[..., None]
     carry_decay = jnp.exp(y[..., -1])[..., None, None]  # (N, B, H, 1, 1)
+    return _scan(w, u, q_in, qk, k_out, carry_decay, v.shape[-1], mm)
+
+
+def _scan(w, u, q_in, qk, k_out, carry_decay, dv, mm):
+    """The three products a chunk that need the state, chunk after chunk:
+    `M` (dk x dv) starts at zero. `carry_decay` is `e^{y_C}`, (N, B, H, 1,
+    1) or (N, B, H, dk, 1)."""
+    _, b, h, _, dk = w.shape
 
     @jax.checkpoint
     def body(m, xs):
@@ -220,3 +253,54 @@ def _chunked(q, k, v, g, beta, mm_dtype):
     _, o = lax.scan(body, jnp.zeros((b, h, dk, dv), jnp.float32),
                     (w, u, q_in, qk, k_out, carry_decay))
     return o
+
+
+def _chunked_per_channel(q, k, v, g, beta, mm_dtype):
+    """The rule with `g` of (N, B, H, C, dk): `A` and `Q K^T` by sub-chunks
+    of `SUB` (module docstring), the rest as `_chunked`'s."""
+    mm = functools.partial(_mm, mm_dtype=mm_dtype)
+    q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
+    chunk, dk = q.shape[-2:]
+    sub = SUB if chunk % SUB == 0 else chunk
+    n_sub = chunk // sub
+    y = jnp.cumsum(g, axis=-2)  # (N, B, H, C, dk)
+    by_sub = lambda x: x.reshape(*x.shape[:-2], n_sub, sub, dk)
+    ys, ks, qs = by_sub(y), by_sub(k), by_sub(q)
+    # the diagonal blocks: e^{y_i - y_j} pairwise, masked before the
+    # exponential (above the diagonal the exponent is >= 0), summed over d
+    row = lax.broadcasted_iota(jnp.int32, (sub, sub, 1), 0)
+    col = lax.broadcasted_iota(jnp.int32, (sub, sub, 1), 1)
+    pair = jnp.exp(jnp.where(row >= col, ys[..., :, None, :]
+                             - ys[..., None, :, :], -jnp.inf))
+    a_diag = jnp.sum(jnp.where(row > col, ks[..., :, None, :]
+                               * ks[..., None, :, :] * pair, 0.0), axis=-1)
+    qk_diag = jnp.sum(qs[..., :, None, :] * ks[..., None, :, :] * pair,
+                      axis=-1)  # (N, B, H, n_sub, sub, sub)
+    # between sub-chunks: through the first token r of the later one, both
+    # factors' exponents <= 0; the rows of sub-chunk I meet columns < r
+    into_r = jnp.exp(ys - ys[..., :, :1, :])
+    off_a = off_qk = [jnp.zeros(q.shape[:-2] + (sub, chunk))]
+    for i in range(1, n_sub):
+        r = i * sub
+        right = k[..., :r, :] * jnp.exp(y[..., r:r + 1, :] - y[..., :r, :])
+        side = lambda x: jnp.pad(
+            mm("...sd,...jd->...sj", x[..., i, :, :] * into_r[..., i, :, :],
+               right), [(0, 0)] * (x.ndim - 3) + [(0, 0), (0, chunk - r)])
+        off_a, off_qk = off_a + [side(ks)], off_qk + [side(qs)]
+    # the diagonal blocks in: (N, B, H, n_sub, sub, sub) -> (N, B, H, C, C)
+    diag = jnp.eye(n_sub, dtype=jnp.float32)[:, None, :, None]
+    whole = lambda off, d: (jnp.concatenate(off, axis=-2)
+                            + (d[..., :, :, None, :] * diag).reshape(
+                                *d.shape[:-3], chunk, chunk))
+    a = beta[..., None] * whole(off_a, a_diag)
+    qk = whole(off_qk, qk_diag)
+    rhs = jnp.concatenate([beta[..., None] * jnp.exp(y) * k,
+                           beta[..., None] * v], axis=-1)
+    wu = _solve(a, rhs)
+    w, u = wu[..., :dk], wu[..., dk:]
+    q_in = q * jnp.exp(y)
+    k_out = k * jnp.exp(y[..., -1:, :] - y)
+    carry_decay = jnp.exp(y[..., -1, :])[..., None]  # (N, B, H, dk, 1)
+    # what the scan's body only multiplies goes in rounded, as it would be
+    w, q_in, qk, k_out = (x.astype(mm_dtype) for x in (w, q_in, qk, k_out))
+    return _scan(w, u, q_in, qk, k_out, carry_decay, v.shape[-1], mm)

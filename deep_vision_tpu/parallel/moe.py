@@ -14,6 +14,20 @@ tokens), experts sharded over the same axis (each device owns E/n experts).
 Capacity is static (TPU shapes must be): each expert accepts at most C
 tokens per device per step; overflow tokens fall through the residual
 connection untouched — the standard Switch-Transformer semantics.
+
+`held_experts` is the other formulation: a chip's share of an expert
+layer whose router scores every expert with a sigmoid and picks the top
+`k` by score plus a correction bias (DeepSeek-V3's auxiliary-loss-free
+routing, the `n_routed_experts` / `norm_topk_prob` / `routed_scaling_
+factor` family), of which this chip holds `n` consecutive experts from
+`held_offset`, plus a shared expert every token passes through. No pair
+is dropped: the (token, choice) pairs are sorted so that the held
+experts' come first, grouped by expert, in a buffer of `T k` rows (every
+choice of every token may be held), and the grouped products
+(`megablox.gmm`, a Pallas kernel: its grid visits the tiles of the held
+groups alone, so its time follows the routed pairs, not the buffer) run
+over the held groups. What the absent experts would add is left out, as
+the chips that hold them would add it.
 """
 from __future__ import annotations
 
@@ -24,7 +38,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from deep_vision_tpu.core import backend
+from deep_vision_tpu.obs.registry import get_registry
 from deep_vision_tpu.parallel.mesh import DATA_AXIS
+
+GMM_ROWS = 128  # the grouped product's row tile: the buffer is padded to it
+BIAS_RATE = 1e-3  # the correction bias's step a training step
 
 
 def expert_ffn(params, x):
@@ -186,3 +205,161 @@ def moe_ffn_dense(router_w, expert_params, x, *,
         all_out, choice[None, :, None], axis=0
     )[0]
     return picked * prob
+
+
+# -- a chip's share of a sigmoid-routed expert layer, no pair dropped --------
+
+def sigmoid_route(x, kernel, bias, top_k: int, scaling: float = 1.0):
+    """Scores `s = sigmoid(x W)` over every expert, in float32; the choice
+    `top_k(s + bias)`; the weights the chosen scores over their sum, times
+    `scaling`. x: (T, D); kernel: (D, E); bias: (E,), not trained. ->
+    (choice (T, k) int32, weights (T, k) float32)."""
+    logits = jnp.dot(x.astype(jnp.float32), kernel.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, choice = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    chosen = jnp.take_along_axis(scores, choice, axis=-1)
+    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scaling
+    return choice, weights
+
+
+def bias_update(bias, load, rate: float = BIAS_RATE):
+    """The correction bias after a step whose expert loads (E,) were
+    `load`: up where an expert took fewer pairs than the mean, down where
+    more (DeepSeek-V3, arXiv:2412.19437, sec. 2.1.2)."""
+    load = load.astype(jnp.float32)
+    return bias + rate * jnp.sign(jnp.mean(load) - load)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch(x, order, inv, held, k):
+    """(T, D) -> (R, D): buffer row `p` is the token of pair `order[p]`.
+    The transpose gathers too (`inv`), where autodiff would scatter-add
+    all `T k` rows; rows of pairs not held are garbage the products never
+    visit, and are not read back."""
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, inv, held, k):
+    return _dispatch(x, order, inv, held, k), (order, inv, held)
+
+
+def _dispatch_bwd(k, res, g):
+    order, inv, held = res
+    rows = jnp.where(held[:, None], g[inv], 0).astype(jnp.float32)
+    dx = jnp.sum(rows.reshape(-1, k, g.shape[-1]), axis=1)
+    return dx.astype(g.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _combine(y, weights, order, inv, held, k):
+    """(R, D) buffer rows -> (T, D) float32: `sum_j weights[t, j] y[inv[t k
+    + j]]` over the held pairs of each token."""
+    return _combine_fwd(y, weights, order, inv, held, k)[0]
+
+
+def _combine_fwd(y, weights, order, inv, held, k):
+    rows = jnp.where(held[:, None], y[inv], 0).astype(jnp.float32)
+    rows = rows.reshape(-1, k, y.shape[-1])
+    out = jnp.einsum("tkd,tk->td", rows, weights)
+    return out, (y, weights, order, inv, held)
+
+
+def _combine_bwd(k, res, g):
+    y, weights, order, inv, held = res
+    rows = jnp.where(held[:, None], y[inv], 0).astype(jnp.float32)
+    d_weights = jnp.einsum("tkd,td->tk", rows.reshape(-1, k, y.shape[-1]), g)
+    # the (T k, D) gather in y's dtype, not float32: `g` is the cotangent
+    # of a sum the caller rounds to y's dtype, so it holds no more bits
+    d_y = g.astype(y.dtype)[order // k] * weights.reshape(-1)[order][:, None]
+    return d_y.astype(y.dtype), d_weights, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _tile(dim: int, cap: int) -> int:
+    """The largest multiple of 128 up to `cap` that divides `dim`; the whole
+    dimension where none does (a tiny test's)."""
+    for t in range(cap, 127, -128):
+        if dim % t == 0:
+            return t
+    return dim
+
+
+def gmm_tiling(m: int, k: int, n: int):
+    """(rows, contraction, columns) tiles of a grouped product."""
+    return GMM_ROWS, _tile(k, 512), _tile(n, 1024)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """`lhs` rows grouped in order by `group_sizes` (n,), each group times
+    its matrix of `rhs` (n, K, N) -> (rows, N) in lhs's dtype, float32
+    accumulation. Rows past the groups are left unwritten."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    return gmm(lhs, rhs, group_sizes, lhs.dtype, gmm_tiling, None, None,
+               False, backend.pallas_interpret())
+
+
+def _swiglu(x, gate, up, down):
+    h = jax.nn.silu(x @ gate.astype(x.dtype)) * (x @ up.astype(x.dtype))
+    return h @ down.astype(x.dtype)
+
+
+def held_experts(x, router, bias, experts, shared=None, *, top_k: int,
+                 held_offset: int = 0, scaling: float = 1.0):
+    """A chip's share of a sigmoid-routed expert layer (module docstring).
+
+    x: (T, D) in the compute dtype; router: (D, E) over every expert; bias:
+    (E,) the correction bias; experts: {"gate", "up": (n, D, F), "down":
+    (n, F, D)}, the held experts `held_offset .. + n`; shared: {"gate",
+    "up": (D, F_s), "down": (F_s, D)} or None.
+    -> (y (T, D) in x's dtype, {"load": (E,) pairs each expert was chosen
+    for, int32; "pairs": the pairs the held experts computed; "load_max":
+    the largest held expert's}). Each expert is `down(silu(gate x) * up
+    x)`, weighted by its routing weight."""
+    get_registry().counter(
+        "moe_sites_total", "Expert layers traced (held_experts)").inc()
+    t, d = x.shape
+    n, e = experts["gate"].shape[0], router.shape[-1]
+    with jax.named_scope("moe/route"):
+        choice, weights = sigmoid_route(x, router, bias, top_k, scaling)
+        load = jnp.sum(jax.nn.one_hot(choice, e, dtype=jnp.int32),
+                       axis=(0, 1))
+    with jax.named_scope("moe/dispatch"):
+        local = choice.reshape(-1) - held_offset
+        held = (local >= 0) & (local < n)
+        key = jnp.where(held, local, n)  # the held experts' groups, then rest
+        pairs = t * top_k
+        rows = -(-pairs // GMM_ROWS) * GMM_ROWS
+        first = jax.nn.one_hot(key, n + 1, dtype=jnp.int32)
+        sizes = jnp.sum(first, axis=0)
+        # a pair's buffer row: its group's start, then its rank inside it
+        starts = jnp.cumsum(sizes) - sizes
+        inv = jnp.sum((starts + jnp.cumsum(first, axis=0) - 1) * first,
+                      axis=1)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        order = jnp.pad(order, (0, rows - pairs))
+        xs = _dispatch(x, order, inv, held, top_k)
+        group_sizes = sizes[:n].astype(jnp.int32)
+    with jax.named_scope("moe/experts"):
+        # gate and up as one product: one pass over the buffer each way
+        f = experts["gate"].shape[-1]
+        gate_up = grouped_matmul(xs, jnp.concatenate(
+            [experts["gate"], experts["up"]], axis=-1).astype(x.dtype),
+            group_sizes)
+        h = jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]
+        ys = grouped_matmul(h, experts["down"].astype(x.dtype), group_sizes)
+    with jax.named_scope("moe/combine"):
+        y = _combine(ys, weights * held.reshape(t, top_k), order, inv, held,
+                     top_k)
+    if shared is not None:
+        with jax.named_scope("moe/shared"):
+            y = y + _swiglu(x, shared["gate"], shared["up"], shared["down"])
+    stats = {"load": load, "pairs": jnp.sum(group_sizes),
+             "load_max": jnp.max(group_sizes)}
+    return y.astype(x.dtype), stats

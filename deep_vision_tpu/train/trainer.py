@@ -33,6 +33,7 @@ from deep_vision_tpu.data.device_prefetch import DevicePrefetcher, PlacedBatch
 from deep_vision_tpu.obs import perfwatch
 from deep_vision_tpu.obs.alerts import AlertEngine, default_training_rules
 from deep_vision_tpu.obs.goodput import GoodputMeter
+from deep_vision_tpu.obs.registry import COUNT_PREFIX
 from deep_vision_tpu.obs.stepclock import StallRule, StepClock, stall_split
 from deep_vision_tpu.obs.trace import span, watch_gc
 from deep_vision_tpu.parallel.mesh import (
@@ -1380,6 +1381,12 @@ class Trainer:
             lrs = [self._scheduled_lr(s - 1) for s in steps]
         else:
             lrs = [self._scheduled_lr(opt_step)] * len(steps)
+        for key in [k for k in host if k.startswith(COUNT_PREFIX)]:
+            name = key[len(COUNT_PREFIX):]
+            host[name] = host.pop(key)
+            self.clock.registry.counter(
+                name + "_total", "a count the model made, summed over "
+                "steps").inc(float(np.sum(host[name])))
         rows = [{name: float(np.atleast_1d(v)[i]) for name, v in host.items()}
                 for i in range(len(steps))]
         last, lr = rows[-1], lrs[-1]

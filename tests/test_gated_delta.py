@@ -34,13 +34,17 @@ from deep_vision_tpu.ops.pallas.tril_inverse import (
 
 def gated_delta_recurrent(q, k, v, g, beta):
     """The recurrence itself, token by token, float32: `S_t = a_t S_{t-1}
-    (I - b_t k_t k_t^T) + b_t v_t k_t^T`, `o_t = S_t q_t`. Same arguments
-    and result as `gated_delta_rule`."""
+    (I - b_t k_t k_t^T) + b_t v_t k_t^T`, `o_t = S_t q_t`; with `g` of (B,
+    T, H, dk) a decay per key channel, `S_t = S_{t-1} Diag(e^{g_t}) (I -
+    b_t k_t k_t^T) + b_t v_t k_t^T` (KDA's rule, S transposed). Same
+    arguments and result as `gated_delta_rule`."""
     f32 = lambda x: jnp.moveaxis(x.astype(jnp.float32), 1, 0)
 
     def step(s, x):
         q, k, v, g, beta = x  # (B, H, ...)
-        s = jnp.exp(g)[..., None, None] * s
+        decay = jnp.exp(g)
+        s = (decay[..., None, :] if g.ndim == 3 else decay[..., None, None]) \
+            * s
         written = beta[..., None] * (v - jnp.einsum("bhvk,bhk->bhv", s, k))
         s = s + written[..., :, None] * k[..., None, :]
         return s, jnp.einsum("bhvk,bhk->bhv", s, q)
@@ -112,6 +116,80 @@ def test_alike_keys_and_beta_near_2_stay_the_recurrence():
     k = jnp.broadcast_to(k[:, :1], k.shape)
     beta = jnp.full_like(beta, 1.99)
     is_the_recurrence((q, k, v, g, beta), ct, chunk=64)
+
+
+def per_channel(args, decay: float, seed: int = 3):
+    """The same operands with a decay per key channel, `-decay *
+    softplus(normal)` a token a channel."""
+    q, k, v, _, beta = args
+    g = -decay * jax.nn.softplus(jax.random.normal(
+        jax.random.PRNGKey(seed), q.shape))
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("decay", [0.01, 1.0, 5.0],
+                         ids=["weak_decay", "unit_decay", "strong_decay"])
+def test_a_decay_per_channel_is_the_recurrence_outputs_and_all_gradients(
+        chunk, decay):
+    """KDA's rule (`g` of (B, T, H, dk)): at the cell's chunk of 64, four
+    sub-chunks of 16 with the exponents between them factored through a
+    boundary, and at 16, one sub-chunk whose exponents are all pairwise."""
+    args, ct = inputs(1.0, 3.0, seed=4, t=128)
+    is_the_recurrence(per_channel(args, decay), ct, chunk)
+
+
+def test_decays_down_to_minus_30_a_token_stay_finite_and_the_recurrence():
+    """Down to -30 a token a channel, `y` falls to about -1,300 along a
+    chunk: `e^{y_i}` alone underflows and `e^{-y_j}` alone overflows, which
+    the factoring through each sub-chunk's first token never forms. The
+    decay's own gradient is the sum of contributions of `e^{-20}` and less,
+    and parts from the recurrence's by up to 3e-5 of its norm; the rest by
+    the float32 rounding of the other tests."""
+    (q, k, v, _, beta), ct = inputs(1.0, 3.0, seed=5, t=128)
+    g = -30.0 * jax.random.uniform(jax.random.PRNGKey(6), q.shape)
+    assert float(jnp.min(g)) < -29.9
+    args = (q, k, v, g, beta)
+    with jax.default_matmul_precision("highest"):
+        chunked = lambda *a: gated_delta_rule(*a, chunk=64)
+        got = chunked(*args)
+        grads = jax.grad(lambda *a: jnp.sum(chunked(*a) * ct),
+                         argnums=range(5))(*args)
+        want = jax.grad(lambda *a: jnp.sum(gated_delta_recurrent(*a) * ct),
+                        argnums=range(5))(*args)
+        assert apart(got, gated_delta_recurrent(*args)) < TOL
+    for name, a, b in zip("q k v g beta".split(), grads, want):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        assert apart(a, b) < (1e-4 if name == "g" else TOL), name
+    assert bool(jnp.all(jnp.isfinite(got)))
+
+
+def test_a_scalar_decay_broadcast_over_the_keys_is_the_scalar_path():
+    """The shape of `g` selects the form: a scalar decay broadcast over
+    `dk` runs the per-channel form and gives the scalar form's numbers to
+    float32 rounding (the per-channel form multiplies `e^{y_i - y_r}
+    e^{y_r - y_j}` where the scalar one takes `e^{y_i - y_j}`, so not to
+    the bit), outputs and gradients."""
+    args, ct = inputs(1.0, 3.0, seed=7, t=128)
+    q, k, v, g, beta = args
+    wide = jnp.broadcast_to(g[..., None], q.shape)
+    with jax.default_matmul_precision("highest"):
+        rule = lambda g: gated_delta_rule(q, k, v, g, beta, chunk=64)
+        assert apart(rule(wide), rule(g)) < 2e-6
+        d_wide = jax.grad(lambda g: jnp.sum(rule(g) * ct))(wide)
+        d_g = jax.grad(lambda g: jnp.sum(rule(g) * ct))(g)
+    assert apart(jnp.sum(d_wide, axis=-1), d_g) < 1e-5
+
+
+def test_each_form_runs_under_its_own_scope():
+    """The per-channel form's ops are `kda`'s, the scalar form's
+    `gated_delta`'s: a trace's ops are counted apart by them."""
+    args, _ = inputs(1.0, 1.0, t=64)
+    text = lambda *a: jax.jit(lambda *a: gated_delta_rule(
+        *a, chunk=64)).lower(*a).compile().as_text()
+    scalar, wide = text(*args), text(*per_channel(args, 1.0))
+    assert "gated_delta/" in scalar and "kda/" not in scalar
+    assert "kda/" in wide and "gated_delta/" not in wide
 
 
 def lower_triangles(c: int, lead=(2, 2, 3), seed: int = 0):

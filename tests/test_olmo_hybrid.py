@@ -153,7 +153,7 @@ class TokenSideRowMath(nn.Module):
 
         def mixed(name, width):
             y = olmo._dense(h * width, self.dtype, name)(x)
-            kernel = self.param(name + "_conv", olmo._conv_init,
+            kernel = self.param(name + "_conv", olmo.conv_init,
                                 (4, h * width), jnp.float32)
             return nn.silu(short_conv(y, kernel)).reshape(b, t, h, width)
 
@@ -165,8 +165,8 @@ class TokenSideRowMath(nn.Module):
         k = unit(mixed("k", dk).astype(jnp.float32))
         v = mixed("v", dv)
         beta = 2.0 * jax.nn.sigmoid(olmo._dense(h, jnp.float32, "b")(x))
-        a_log = self.param("A_log", olmo._a_log_init, (h,), jnp.float32)
-        dt_bias = self.param("dt_bias", olmo._dt_bias_init, (h,),
+        a_log = self.param("A_log", olmo.a_log_init, (h,), jnp.float32)
+        dt_bias = self.param("dt_bias", olmo.dt_bias_init, (h,),
                              jnp.float32)
         g = -jnp.exp(a_log) * jax.nn.softplus(
             olmo._dense(h, jnp.float32, "a")(x) + dt_bias)

@@ -459,7 +459,8 @@ def _delta_layer_hlo(v5e, monkeypatch, kept: bool) -> str:
     from jax.sharding import SingleDeviceSharding
 
     from deep_vision_tpu.core import backend
-    from deep_vision_tpu.models.olmo_hybrid import _KEPT, GatedDeltaNet
+    from deep_vision_tpu.models.decoder import KEPT as _KEPT
+    from deep_vision_tpu.models.olmo_hybrid import GatedDeltaNet
 
     policy = _KEPT if kept else \
         jax.checkpoint_policies.dots_with_no_batch_dims_saveable
@@ -544,3 +545,45 @@ def test_delta_rule_layer_crosses_to_chunks_in_the_stored_dtype(
     narrow = sum(moved for dtype, moved, _ in movers
                  if dtype == "bf16" and moved > 2 * 10e6)
     assert narrow / 1e9 == pytest.approx(1.51, abs=0.01)
+
+
+def test_held_experts_compile_to_grouped_kernels_and_gathers(v5e,
+                                                             monkeypatch):
+    """`parallel/moe.held_experts` at `solar_open2_250b_train`'s shapes (2 x
+    2048 tokens, width 4096, 8 held of 320 experts of 1280, a shared one),
+    forward and backward, for a v5e: the grouped products are `megablox`'s
+    Pallas calls (`gmm` forward and for the input's gradient, `tgmm` for
+    the experts'), and no activation is scattered: the dispatch's and the
+    combine's transposes gather (autodiff's would scatter-add 32,768 rows
+    of 4096). The scatters left are the kernels' group metadata, a few KB."""
+    from deep_vision_tpu.core import backend
+    from deep_vision_tpu.parallel import moe
+
+    n, e, d, f, t = 8, 320, 4096, 1280, 4096
+    names = ("gate", "up", "down", "shared_gate", "shared_up", "shared_down")
+    shapes = ((n, d, f), (n, d, f), (n, f, d), (d, f), (d, f), (f, d))
+
+    def fwd_bwd(x, router, bias, *weights):
+        def loss(x, router, weights):
+            w = dict(zip(names, weights))
+            y, _ = moe.held_experts(
+                x, router, bias, {k: w[k] for k in names[:3]},
+                {k: w["shared_" + k] for k in names[:3]}, top_k=8)
+            return jnp.sum(jnp.square(y.astype(jnp.float32)))
+        return jax.grad(loss, argnums=(0, 1, 2))(x, router, weights)
+
+    monkeypatch.setattr(backend, "current_platform", lambda: "tpu")
+    text = compile_for_v5e(
+        v5e, fwd_bwd, S((t, d), jnp.bfloat16), S((d, e), jnp.float32),
+        S((e,), jnp.float32),
+        *(S(shape, jnp.float32) for shape in shapes)).as_text()
+    entry = text[text.index("\nENTRY "):]
+    calls = re.findall(
+        r"^\s*%?(\S+) = .*custom_call_target=\"tpu_custom_call\"", entry,
+        re.M)
+    kinds = sorted(re.sub(r"\.\d+$", "", c) for c in calls)
+    assert kinds == ["gmm"] * 4 + ["tgmm"] * 2, calls
+    scattered = [math.prod(map(int, dims.split(",")))
+                 for dims in re.findall(r"= \w+\[([\d,]*)\]\S* scatter\(",
+                                        entry)]
+    assert max(scattered, default=0) < 1e5, scattered
