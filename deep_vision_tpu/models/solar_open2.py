@@ -49,9 +49,11 @@ slice of the vocabulary (ids and logits over the slice).
 The model returns `{"hidden", "head", "report"}`: the final norm's output
 and the head's kernel for `losses/causal_lm.py`, and the step's report of
 its experts, the (token, choice) pairs the held experts computed over all
-layers and the largest held expert's load in any layer, named as counts
-(`obs.registry.COUNT_PREFIX`: `Trainer` folds them into
-`moe_routed_pairs_total`, `moe_held_load_max_total`). Each block
+layers, the largest held expert's load in any layer, and the buffer tiles
+the expert layers' row passes visited of those their buffers have, named
+as counts (`obs.registry.COUNT_PREFIX`: `Trainer` folds them into
+`moe_routed_pairs_total`, `moe_held_load_max_total`,
+`moe_live_tiles_total`, `moe_buffer_tiles_total`). Each block
 is recomputed in the backward pass but for the products with a kernel and
 the delta rule's inverse triangles (`models/decoder.KEPT`).
 """
@@ -243,12 +245,14 @@ class SolarBlock(nn.Module):
                         self.top_k, self.width, self.shared_width,
                         self.scaling, self.train, self.dtype, name="moe")
         y, stats = moe(RMSNorm(self.eps, name="moe_norm")(h))
-        return h + y, (stats["pairs"], stats["load_max"])
+        return h + y, {name: stats[name] for name in (
+            "pairs", "load_max", "live_tiles", "buffer_tiles")}
 
 
 class SolarOpen2(nn.Module):
     """tokens int32 (B, T) -> {"hidden": (B, T, D), "head": (D, V),
-    "report": {"count/moe_routed_pairs", "count/moe_held_load_max"}}."""
+    "report": {"count/moe_routed_pairs", "count/moe_held_load_max",
+    "count/moe_live_tiles", "count/moe_buffer_tiles"}}."""
 
     vocab_size: int
     hidden_size: int
@@ -278,9 +282,9 @@ class SolarOpen2(nn.Module):
         x = nn.Embed(self.vocab_size, self.hidden_size, embedding_init=INIT,
                      name="embed")(tokens).astype(dt)
         block_cls = nn.remat(SolarBlock, policy=KEPT)
-        pairs, load_max = jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32)
+        counts = []
         for i in range(self.num_hidden_layers):
-            x, (p, m) = block_cls(
+            x, layer_counts = block_cls(
                 GQA if i in self.gqa_layers else KDA,
                 self.num_attention_heads, self.num_key_value_heads,
                 self.head_dim, self.use_gqa_gate, self.linear_num_heads,
@@ -291,13 +295,18 @@ class SolarOpen2(nn.Module):
                 self.n_shared_experts * self.moe_intermediate_size,
                 self.routed_scaling_factor, self.rms_norm_eps, train,
                 self.dtype, name=f"block_{i}")(x)
-            pairs, load_max = pairs + p, jnp.maximum(load_max, m)
+            counts.append(layer_counts)
+        total = lambda name: sum(c[name] for c in counts)
         head = self.param("head", INIT, (self.hidden_size, self.vocab_size),
                           jnp.float32)
         return {"hidden": RMSNorm(self.rms_norm_eps, name="final_norm")(x),
                 "head": head,
-                "report": {COUNT_PREFIX + "moe_routed_pairs": pairs,
-                           COUNT_PREFIX + "moe_held_load_max": load_max}}
+                "report": {
+                    COUNT_PREFIX + "moe_routed_pairs": total("pairs"),
+                    COUNT_PREFIX + "moe_held_load_max": functools.reduce(
+                        jnp.maximum, [c["load_max"] for c in counts]),
+                    COUNT_PREFIX + "moe_live_tiles": total("live_tiles"),
+                    COUNT_PREFIX + "moe_buffer_tiles": total("buffer_tiles")}}
 
 
 @register_model("solar_open2_250b")

@@ -26,8 +26,11 @@ experts' come first, grouped by expert, in a buffer of `T k` rows (every
 choice of every token may be held), and the grouped products
 (`megablox.gmm`, a Pallas kernel: its grid visits the tiles of the held
 groups alone, so its time follows the routed pairs, not the buffer) run
-over the held groups. What the absent experts would add is left out, as
-the chips that hold them would add it.
+over the held groups. Every other pass over the buffer (the dispatch, the
+experts' gate, the combine and their transposes) is one of
+`ops/pallas/moe_rows.py`'s, which visit its live tiles alone as well; the
+step's report counts those tiles beside the buffer's. What the absent
+experts would add is left out, as the chips that hold them would add it.
 """
 from __future__ import annotations
 
@@ -40,9 +43,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deep_vision_tpu.core import backend
 from deep_vision_tpu.obs.registry import get_registry
+from deep_vision_tpu.ops.pallas import moe_rows
 from deep_vision_tpu.parallel.mesh import DATA_AXIS
 
-GMM_ROWS = 128  # the grouped product's row tile: the buffer is padded to it
+# the grouped products' and the row passes' row tile: the buffer is padded
+# to it
+GMM_ROWS = moe_rows.TILE
 BIAS_RATE = 1e-3  # the correction bias's step a training step
 
 
@@ -231,51 +237,53 @@ def bias_update(bias, load, rate: float = BIAS_RATE):
     return bias + rate * jnp.sign(jnp.mean(load) - load)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _dispatch(x, order, inv, held, k):
-    """(T, D) -> (R, D): buffer row `p` is the token of pair `order[p]`.
-    The transpose gathers too (`inv`), where autodiff would scatter-add
-    all `T k` rows; rows of pairs not held are garbage the products never
-    visit, and are not read back."""
-    return x[order // k]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _dispatch(x, order, lo, hi, live, k):
+    """(T, D) -> (R, D): buffer row `p < live` is the token of pair
+    `order[p]`; the rows past the live tiles are left unwritten. The
+    transpose sums each token's live rows (`moe_rows.sum_rows`), where
+    autodiff would scatter-add all `T k` rows."""
+    return moe_rows.gather_rows(x, order // k, live)
 
 
-def _dispatch_fwd(x, order, inv, held, k):
-    return _dispatch(x, order, inv, held, k), (order, inv, held)
+def _dispatch_fwd(x, order, lo, hi, live, k):
+    return _dispatch(x, order, lo, hi, live, k), (len(x), order, lo, hi)
 
 
 def _dispatch_bwd(k, res, g):
-    order, inv, held = res
-    rows = jnp.where(held[:, None], g[inv], 0).astype(jnp.float32)
-    dx = jnp.sum(rows.reshape(-1, k, g.shape[-1]), axis=1)
-    return dx.astype(g.dtype), None, None, None
+    tokens, order, lo, hi = res
+    dx = moe_rows.sum_rows(g, order, lo, hi, tokens=tokens, k=k,
+                           dtype=g.dtype)
+    return dx, None, None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _combine(y, weights, order, inv, held, k):
-    """(R, D) buffer rows -> (T, D) float32: `sum_j weights[t, j] y[inv[t k
-    + j]]` over the held pairs of each token."""
-    return _combine_fwd(y, weights, order, inv, held, k)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _combine(y, weights, order, lo, hi, live, k):
+    """(R, D) buffer rows -> (T, D) float32: `sum_j weights[t, j] y[row of
+    pair t k + j]` over the held pairs of each token, in the order of the
+    held experts. The transpose reads the same rows for the weights'
+    gradient and fills the buffer's live tiles with the weighted
+    cotangent (`moe_rows.gather_rows`): it never scatter-adds."""
+    return _combine_fwd(y, weights, order, lo, hi, live, k)[0]
 
 
-def _combine_fwd(y, weights, order, inv, held, k):
-    rows = jnp.where(held[:, None], y[inv], 0).astype(jnp.float32)
-    rows = rows.reshape(-1, k, y.shape[-1])
-    out = jnp.einsum("tkd,tk->td", rows, weights)
-    return out, (y, weights, order, inv, held)
+def _combine_fwd(y, weights, order, lo, hi, live, k):
+    scale = weights.reshape(-1)[order]  # a buffer row's routing weight
+    out = moe_rows.sum_rows(y, order, lo, hi, scale, tokens=weights.shape[0],
+                            k=k)
+    return out, (y, order, lo, hi, live, scale)
 
 
 def _combine_bwd(k, res, g):
-    y, weights, order, inv, held = res
-    rows = jnp.where(held[:, None], y[inv], 0).astype(jnp.float32)
-    d_weights = jnp.einsum("tkd,td->tk", rows.reshape(-1, k, y.shape[-1]), g)
-    # the (T k, D) gather in y's dtype, not float32: `g` is the cotangent
-    # of a sum the caller rounds to y's dtype, so it holds no more bits
-    d_y = g.astype(y.dtype)[order // k] * weights.reshape(-1)[order][:, None]
-    return d_y.astype(y.dtype), d_weights, None, None, None
+    y, order, lo, hi, live, scale = res
+    d_weights = moe_rows.dot_rows(y, order, lo, hi, g, k=k)
+    # the rows in y's dtype, not float32: `g` is the cotangent of a sum the
+    # caller rounds to y's dtype, so it holds no more bits
+    d_y = moe_rows.gather_rows(g.astype(y.dtype), order // k, live, scale)
+    return d_y, d_weights, None, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -320,8 +328,10 @@ def held_experts(x, router, bias, experts, shared=None, *, top_k: int,
     "up": (D, F_s), "down": (F_s, D)} or None.
     -> (y (T, D) in x's dtype, {"load": (E,) pairs each expert was chosen
     for, int32; "pairs": the pairs the held experts computed; "load_max":
-    the largest held expert's}). Each expert is `down(silu(gate x) * up
-    x)`, weighted by its routing weight."""
+    the largest held expert's; "live_tiles": the buffer's row tiles the
+    passes visit, of "buffer_tiles"}). Each expert is `down(silu(gate x) *
+    up x)`, weighted by its routing weight. T is a multiple of 8
+    (`moe_rows.token_tile`)."""
     get_registry().counter(
         "moe_sites_total", "Expert layers traced (held_experts)").inc()
     t, d = x.shape
@@ -338,28 +348,31 @@ def held_experts(x, router, bias, experts, shared=None, *, top_k: int,
         rows = -(-pairs // GMM_ROWS) * GMM_ROWS
         first = jax.nn.one_hot(key, n + 1, dtype=jnp.int32)
         sizes = jnp.sum(first, axis=0)
-        # a pair's buffer row: its group's start, then its rank inside it
-        starts = jnp.cumsum(sizes) - sizes
-        inv = jnp.sum((starts + jnp.cumsum(first, axis=0) - 1) * first,
-                      axis=1)
+        group_sizes = sizes[:n].astype(jnp.int32)
+        live = jnp.sum(group_sizes)  # the buffer's rows [0, live) are live
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         order = jnp.pad(order, (0, rows - pairs))
-        xs = _dispatch(x, order, inv, held, top_k)
-        group_sizes = sizes[:n].astype(jnp.int32)
+        # a group keeps token order: a held expert's rows for a tile of
+        # tokens are one range of the buffer, [lo, hi)
+        tt = moe_rows.token_tile(t)
+        per = jnp.sum(first.reshape(t // tt, tt * top_k, n + 1)[..., :n],
+                      axis=1)
+        lo = jnp.cumsum(sizes[:n]) - sizes[:n] + jnp.cumsum(per, axis=0) - per
+        hi = lo + per
+        xs = _dispatch(x, order, lo, hi, live, top_k)
     with jax.named_scope("moe/experts"):
-        # gate and up as one product: one pass over the buffer each way
-        f = experts["gate"].shape[-1]
+        # gate and up as one product: one pass over the live tiles each way
         gate_up = grouped_matmul(xs, jnp.concatenate(
             [experts["gate"], experts["up"]], axis=-1).astype(x.dtype),
             group_sizes)
-        h = jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]
+        h = moe_rows.gate_rows(gate_up, live)
         ys = grouped_matmul(h, experts["down"].astype(x.dtype), group_sizes)
     with jax.named_scope("moe/combine"):
-        y = _combine(ys, weights * held.reshape(t, top_k), order, inv, held,
-                     top_k)
+        y = _combine(ys, weights, order, lo, hi, live, top_k)
     if shared is not None:
         with jax.named_scope("moe/shared"):
             y = y + _swiglu(x, shared["gate"], shared["up"], shared["down"])
-    stats = {"load": load, "pairs": jnp.sum(group_sizes),
-             "load_max": jnp.max(group_sizes)}
+    stats = {"load": load, "pairs": live, "load_max": jnp.max(group_sizes),
+             "live_tiles": (live + GMM_ROWS - 1) // GMM_ROWS,
+             "buffer_tiles": jnp.int32(rows // GMM_ROWS)}
     return y.astype(x.dtype), stats

@@ -97,13 +97,14 @@ def test_the_held_shares_add_up_to_the_uncut_layer():
 def test_no_pair_is_dropped_where_every_choice_is_held():
     """The worst case: the correction bias sends every token to the 8 held
     experts, 8 pairs a token in a buffer of `T k` rows, all of them
-    computed."""
+    computed: every tile of the buffer is live."""
     x, p, bias = layer(seed=1, tokens=40)
     offset = 16
     forced = bias.at[offset:offset + HELD].add(100.0)
     y, stats = program(x, p, forced, offset, HELD)
     assert int(stats["pairs"]) == 40 * K
     assert int(stats["load_max"]) == 40
+    assert int(stats["live_tiles"]) == int(stats["buffer_tiles"]) == 3
     assert int(stats["load"][offset:offset + HELD].sum()) == 40 * K
     theirs, _ = reference_layer(
         cfg(HELD, offset), x,
@@ -112,10 +113,30 @@ def test_no_pair_is_dropped_where_every_choice_is_held():
     assert apart(y, theirs) < TOL
 
 
-def test_every_gradient_is_the_references():
-    """Through the dispatch's and combine's transposes (gathers, where
-    autodiff would scatter-add), the grouped products' own backward and the
-    routing weights' normalisation, to x and every parameter."""
+def nan_past_the_groups(monkeypatch):
+    """The grouped products' rows past their groups, which they leave
+    unwritten, read NaN: every pass after them must leave those rows
+    unread."""
+    product = moe.grouped_matmul
+
+    def grouped(lhs, rhs, group_sizes):
+        out = product(lhs, rhs, group_sizes)
+        rows = jnp.arange(out.shape[0])[:, None]
+        return jnp.where(rows < jnp.sum(group_sizes), out, jnp.nan)
+
+    monkeypatch.setattr(moe, "grouped_matmul", grouped)
+
+
+@pytest.mark.parametrize("dead", ["as_left", "nan"],
+                         ids=["dead_rows_as_left", "nan_in_dead_rows"])
+def test_every_gradient_is_the_references(monkeypatch, dead):
+    """Through the dispatch's and combine's transposes (sums over each
+    token's live rows, where autodiff would scatter-add), the gate's and
+    the grouped products' own backward and the routing weights'
+    normalisation, to x and every parameter; and again with NaN in the
+    buffer's dead rows, which no pass may read."""
+    if dead == "nan":
+        nan_past_the_groups(monkeypatch)
     x, p, bias = layer(seed=2)
     offset = 8
     held = {n: p[n][offset:offset + HELD] for n in ("gate", "up", "down")}

@@ -120,9 +120,15 @@ def test_loss_every_gradient_and_the_bias_update_are_the_references(seeded):
     for got, ref in zip(jax.tree.leaves(stats), jax.tree.leaves(want_stats)):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
         assert float(jnp.max(jnp.abs(got))) == pytest.approx(1e-3)
-    # the report: every pair a held expert computed, over the four layers
-    assert 0 < int(metrics["count/moe_routed_pairs"]) <= 4 * 256 * 4
+    # the report: every pair a held expert computed, over the four layers,
+    # and the buffer tiles the row passes visited, each layer's holding its
+    # pairs and at most 127 rows more, of the 8 its buffer has
+    pairs = int(metrics["count/moe_routed_pairs"])
+    assert 0 < pairs <= 4 * 256 * 4
     assert 0 < int(metrics["count/moe_held_load_max"]) <= 256
+    live = int(metrics["count/moe_live_tiles"])
+    assert pairs <= 128 * live < pairs + 4 * 128
+    assert live < int(metrics["count/moe_buffer_tiles"]) == 4 * 8
 
 
 def test_a_step_outside_training_leaves_the_bias():
@@ -152,3 +158,40 @@ def test_query_heads_share_their_kv_head_by_index():
         o = layer.apply({"params": {**params, **eye}}, x).reshape(1, 16, 4, 8)
     np.testing.assert_allclose(o[:, :, 0], o[:, :, 1], rtol=1e-6)
     assert float(jnp.max(jnp.abs(o[:, :, 0] - o[:, :, 2]))) > 1e-3
+
+
+def test_the_step_report_counts_the_buffer_tiles_the_passes_visit(tmp_path):
+    """Two steps of 8 x 16 tokens through `train.py` with every choice held
+    (all 16 experts on this chip): each of the four expert layers reports
+    the buffer tiles its row passes visited and the tiles its buffer has
+    (512 pairs, 4 tiles of 128), the Trainer folds both into
+    `moe_live_tiles_total` and `moe_buffer_tiles_total`, and every tile is
+    live: the share is 1."""
+    import dataclasses
+
+    from deep_vision_tpu import train_cli
+    from deep_vision_tpu.configs import (CONFIG_REGISTRY, get_config,
+                                         register_config)
+    from deep_vision_tpu.obs.registry import get_registry
+
+    def counter(name):
+        return sum(m.value for m in get_registry().metrics()
+                   if m.name == name and m.kind == "counter")
+
+    register_config(dataclasses.replace(
+        get_config("solar_open2_250b"), name="solar_open2_tiny",
+        input_shape=(16,), batch_size=8,
+        model_kwargs={**KWARGS, "n_routed_experts": 16, "held_offset": 0}))
+    names = ("moe_live_tiles_total", "moe_buffer_tiles_total",
+             "moe_routed_pairs_total")
+    before = {name: counter(name) for name in names}
+    try:
+        assert train_cli.main([
+            "-m", "solar_open2_tiny", "--fake-data", "--fake-batches", "2",
+            "--epochs", "1", "--skip-preflight", "--ckpt-dir",
+            str(tmp_path / "ck")]) == 0
+    finally:
+        del CONFIG_REGISTRY["solar_open2_tiny"]
+    live, tiles, pairs = (counter(name) - before[name] for name in names)
+    assert pairs == 2 * 4 * 8 * 16 * 4
+    assert live == tiles == 2 * 4 * 4

@@ -22,6 +22,7 @@ projection matmul's epilogue. The last holds the delta rule's inverse (PR
 by its name, and no inversion of XLA's.
 """
 import functools
+import json
 import math
 import os
 import re
@@ -37,6 +38,7 @@ from deep_vision_tpu.ops.pallas.flash_attention import (
     flash_attention,
     fused_attention,
 )
+from deep_vision_tpu.ops.pallas import moe_rows
 from deep_vision_tpu.ops.pallas.nms import pallas_nms
 from deep_vision_tpu.ops.pallas.tril_inverse import tril_inverse
 
@@ -107,6 +109,16 @@ _Q = S((2, 1024, 12, 64), jnp.bfloat16)
     ("attn_fused_bwd", _fused_fwd_bwd, (S((2, 196, 2304), jnp.bfloat16),)),
     ("gdn_inverse", lambda a: tril_inverse(a, interpret=False),
      (S((2, 32, 30, 64, 64), jnp.float32),)),
+    ("moe_gather_rows", lambda x, i: moe_rows.gather_rows(
+        x, i, jnp.sum(i), interpret=False),
+     (S((256, 512), jnp.bfloat16), S((1024,), jnp.int32))),
+    ("moe_sum_rows", lambda b, o, lo: moe_rows.sum_rows(
+        b, o, lo, lo + 1, tokens=256, k=4, interpret=False),
+     (S((1024, 512), jnp.bfloat16), S((1024,), jnp.int32),
+      S((2, 8), jnp.int32))),
+    ("moe_gate", lambda gu, live: moe_rows.gate_rows(gu, live[0],
+                                                     interpret=False),
+     (S((1024, 512), jnp.bfloat16), S((1,), jnp.int32))),
 ])
 def test_each_kernel_carries_its_name_into_the_program(name, fn, specs):
     """A `name=` on every `pallas_call`: a trace's reader finds the kernel
@@ -553,9 +565,17 @@ def test_held_experts_compile_to_grouped_kernels_and_gathers(v5e,
     2048 tokens, width 4096, 8 held of 320 experts of 1280, a shared one),
     forward and backward, for a v5e: the grouped products are `megablox`'s
     Pallas calls (`gmm` forward and for the input's gradient, `tgmm` for
-    the experts'), and no activation is scattered: the dispatch's and the
-    combine's transposes gather (autodiff's would scatter-add 32,768 rows
-    of 4096). The scatters left are the kernels' group metadata, a few KB."""
+    the experts'), and every other pass over the `T k` = 32,768-row buffer
+    is one of `ops/pallas/moe_rows.py`'s, which visit its live tiles alone:
+    the dispatch and the combine's backward fill it (`moe_gather_rows`),
+    the combine, its weights' gradient and the dispatch's transpose read
+    it by token (`moe_sum_rows`), the gate crosses it each way
+    (`moe_gate`). No XLA instruction of the dispatch or the combine, and
+    no elementwise one of the experts, has an operand or result of the
+    buffer's rows and 128 or more columns (before, 3.86 GB a layer of such
+    passes: PERF.md section 6), and no activation is scattered. The
+    new kernels' names are outside the prefixes the Solar cell's
+    `expert_kernel_ops` and `delta_rule_ops` read."""
     from deep_vision_tpu.core import backend
     from deep_vision_tpu.parallel import moe
 
@@ -582,7 +602,37 @@ def test_held_experts_compile_to_grouped_kernels_and_gathers(v5e,
         r"^\s*%?(\S+) = .*custom_call_target=\"tpu_custom_call\"", entry,
         re.M)
     kinds = sorted(re.sub(r"\.\d+$", "", c) for c in calls)
-    assert kinds == ["gmm"] * 4 + ["tgmm"] * 2, calls
+    assert kinds == (["gmm"] * 4 + ["moe_gate"] * 2 + ["moe_gather_rows"] * 2
+                     + ["moe_sum_rows"] * 3 + ["tgmm"] * 2), calls
+    with open(os.path.join(REPO, "benchmark", "cells",
+                           "solar_open2_250b_train.json")) as fh:
+        cell = json.load(fh)
+    for kind in {"moe_gate", "moe_gather_rows", "moe_sum_rows"}:
+        assert not kind.startswith(tuple(cell["expert_kernel_ops"]
+                                         + cell["delta_rule_ops"])), kind
+    shape_of = dict(re.findall(r"^\s*(?:ROOT )?%?(\S+) = \w+\[([\d,]*)\]",
+                               entry, re.M))
+    rows = t * 8
+
+    def buffer_sized(dims):
+        dims = [int(v) for v in dims.split(",") if v]
+        return len(dims) >= 2 and dims[0] == rows and dims[1] >= 128
+
+    wide = []
+    for line in entry.splitlines():
+        m = re.match(r"^\s*(?:ROOT )?%?(\S+) = \w+\[([\d,]*)\]\S* (\S+?)\((.*)",
+                     line)
+        scope = re.search(r'op_name="[^"]*(moe/(?:dispatch|combine|experts))',
+                          line)
+        if not m or not scope or m.group(3) == "custom-call":
+            continue
+        if scope.group(1) == "moe/experts" and "convolution" in line:
+            continue  # a product, not an elementwise pass
+        operands = re.findall(r"%([\w.\-]+)", m.group(4).split("), ")[0])
+        if buffer_sized(m.group(2)) or any(
+                buffer_sized(shape_of.get(o, "")) for o in operands):
+            wide.append((m.group(1), m.group(3), scope.group(1)))
+    assert not wide, wide
     scattered = [math.prod(map(int, dims.split(",")))
                  for dims in re.findall(r"= \w+\[([\d,]*)\]\S* scatter\(",
                                         entry)]
