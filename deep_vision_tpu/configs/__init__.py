@@ -279,3 +279,22 @@ register_config(ExperimentConfig(
     schedule={"kind": "cosine", "warmup_epochs": 5, "total_epochs": 90},
     dataset={"kind": "fake"},
 ))
+
+register_config(ExperimentConfig(
+    # poolside/Laguna-XS.2 at its published widths, as one chip of the 8
+    # that share each layer by expert parallelism holds it: the leading
+    # dense layer and one period [sliding x 3, full] of 39, experts 0-31 of
+    # 256 (the router keeps all 256; the 224 held elsewhere computed by
+    # the held expert in their place, `stand_in`), every head, an eighth
+    # of the vocabulary. The recipe is OLMo 2's, as the other decoders'
+    # (the row gives none); two sequences of 4096 tokens, the pre-training
+    # context
+    name="laguna_xs2", task="causal_lm", model="laguna_xs2",
+    model_kwargs={"num_hidden_layers": 5, "vocab_size": 12544,
+                  "num_experts": 32, "stand_in": True},
+    input_shape=(4096,), batch_size=2, epochs=1,
+    optimizer={"name": "adamw", "learning_rate": 3e-4, "b1": 0.9,
+               "b2": 0.95, "weight_decay": 0.1},
+    schedule={"kind": "cosine", "warmup_epochs": 5, "total_epochs": 90},
+    dataset={"kind": "fake"},
+))

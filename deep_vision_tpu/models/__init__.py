@@ -42,3 +42,4 @@ from deep_vision_tpu.models import cyclegan  # noqa: E402,F401
 from deep_vision_tpu.models import vit  # noqa: E402,F401
 from deep_vision_tpu.models import olmo_hybrid  # noqa: E402,F401
 from deep_vision_tpu.models import solar_open2  # noqa: E402,F401
+from deep_vision_tpu.models import laguna  # noqa: E402,F401

@@ -70,6 +70,7 @@ from deep_vision_tpu.models import register_model
 from deep_vision_tpu.models.decoder import (
     INIT,
     KEPT,
+    SparseMoe,
     a_log_init,
     causal_attention,
     conv_init,
@@ -87,7 +88,6 @@ from deep_vision_tpu.ops.gated_delta import (
     short_conv,
     to_chunks,
 )
-from deep_vision_tpu.parallel.moe import bias_update, held_experts
 
 GQA, KDA = "gqa", "kda"
 
@@ -164,46 +164,6 @@ class KimiDeltaAttention(nn.Module):
         o = from_chunks(RMSNorm(self.eps, name="o_norm")(o).astype(
             gate.dtype)) * jax.nn.sigmoid(gate)
         return dense(x.shape[-1], self.dtype, "o")(o.reshape(b, t, h * d))
-
-
-class SparseMoe(nn.Module):
-    """The held experts' part of a sigmoid-routed layer and the shared
-    expert: `parallel/moe.held_experts` over this module's parameters, the
-    router's correction bias in `batch_stats`."""
-
-    router_experts: int
-    held: int
-    held_offset: int
-    top_k: int
-    width: int
-    shared_width: int
-    scaling: float = 1.0
-    train: bool = True
-    dtype: Optional[jnp.dtype] = None
-
-    @nn.compact
-    def __call__(self, x):
-        b, t, d = x.shape
-        n, f = self.held, self.width
-        param = lambda name, *shape: self.param(name, INIT, shape,
-                                                jnp.float32)
-        router = param("router", d, self.router_experts)
-        experts = {"gate": param("gate", n, d, f), "up": param("up", n, d, f),
-                   "down": param("down", n, f, d)}
-        shared = None
-        if self.shared_width:
-            shared = {"gate": param("shared_gate", d, self.shared_width),
-                      "up": param("shared_up", d, self.shared_width),
-                      "down": param("shared_down", self.shared_width, d)}
-        bias = self.variable("batch_stats", "router_bias", jnp.zeros,
-                             (self.router_experts,), jnp.float32)
-        y, stats = held_experts(
-            x.reshape(b * t, d).astype(self.dtype or x.dtype), router,
-            bias.value, experts, shared, top_k=self.top_k,
-            held_offset=self.held_offset, scaling=self.scaling)
-        if self.train and not self.is_initializing():
-            bias.value = bias_update(bias.value, stats["load"])
-        return y.reshape(b, t, d), stats
 
 
 class SolarBlock(nn.Module):

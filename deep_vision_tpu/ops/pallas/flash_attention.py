@@ -87,9 +87,74 @@ def _block_visible(causal: bool, qi, ki, block_q: int, block_k: int):
     )
 
 
+# -- causal sliding windows by block skipping --------------------------------
+#
+# With a window W a query t sees keys t - W + 1 .. t. A query block's row of
+# the grid then spans only the key blocks that meet its band, `span` of
+# them, ending at the block of its last query (`_window_key_block`); the
+# dkv kernel's column spans the query blocks that see a key block, from the
+# block of its first key. Both are sized to the widest band in the
+# sequence, so near the sequence's ends a few steps fall outside it: their
+# block index is clamped to the sequence (a repeat of the block the next
+# step holds, so its DMA is skipped) and `pl.when` skips their work. The
+# grids, and the kernels' work, follow T W, not T^2 / 2; the band's edge is
+# masked inside the blocks it cuts.
+
+
+def window_span(t: int, block_q: int, block_k: int, window: int):
+    """(key blocks a query block's row visits, query blocks a key block's
+    column visits): the most that any of the sequence's blocks needs."""
+    rows = max((qs + block_q - 1) // block_k - max(qs - window + 1, 0)
+               // block_k + 1 for qs in range(0, t, block_q))
+    cols = max(min(ks + block_k + window - 2, t - 1) // block_q
+               - ks // block_q + 1 for ks in range(0, t, block_k))
+    return rows, cols
+
+
+def window_key_blocks(t: int, block_q: int, block_k: int, window: int):
+    """(key blocks the windowed forward attends over, those a causal grid
+    attends over), summed over the query blocks of one (batch, head)."""
+    visited = causal = 0
+    for qs in range(0, t, block_q):
+        last = (qs + block_q - 1) // block_k
+        visited += last - max(qs - window + 1, 0) // block_k + 1
+        causal += last + 1
+    return visited, causal
+
+
+def _window_key_block(qi, j, block_q: int, block_k: int, span: int):
+    """The key block that step j of query block qi's row holds, before it
+    is clamped to the sequence (it runs below 0 near the start)."""
+    return (qi * block_q + block_q - 1) // block_k - (span - 1) + j
+
+
+def _window_query_block(ki, j, block_q: int, block_k: int):
+    """The query block that step j of key block ki's column holds, before
+    it is clamped to the sequence (it runs past the last near the end)."""
+    return ki * block_k // block_q + j
+
+
+def _window_visible(qb, kb, block_q: int, block_k: int, window: int,
+                    q_blocks: int):
+    """Whether key block kb meets query block qb's band: in the sequence,
+    not above the diagonal, not wholly before the window."""
+    return ((kb >= 0) & (qb <= q_blocks - 1)
+            & (kb * block_k <= qb * block_q + block_q - 1)
+            & (kb * block_k + block_k - 1 >= qb * block_q - window + 1))
+
+
+def _band_mask(s, qb, kb, block_q, block_k, window):
+    qpos = qb * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+    kpos = kb * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    return jnp.where((qpos >= kpos) & (qpos - kpos < window), s, NEG_INF)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
                   scale: float, causal: bool, block_q: int, block_k: int,
-                  need_lse: bool):
+                  need_lse: bool, window: Optional[int] = None,
+                  span: int = 0, q_blocks: int = 0):
     if need_lse:
         lse_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -104,8 +169,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # with causality, blocks strictly above the diagonal contribute nothing
-    visible = _block_visible(causal, qi, ki, block_q, block_k)
+    if window is None:
+        # with causality, blocks strictly above the diagonal contribute
+        # nothing
+        visible = _block_visible(causal, qi, ki, block_q, block_k)
+    else:
+        kb = _window_key_block(qi, ki, block_q, block_k, span)
+        visible = _window_visible(qi, kb, block_q, block_k, window,
+                                  q_blocks)
 
     @pl.when(visible)
     def _attend():
@@ -115,7 +186,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (bq, bk)
-        if causal:
+        if window is not None:
+            s = _band_mask(s, qi, kb, block_q, block_k, window)
+        elif causal:
             s = _causal_mask(s, qi, ki, block_q, block_k)
 
         m_prev = m_scr[:, :1]  # (bq, 1)
@@ -150,7 +223,8 @@ def _flash_forward(q, k, v, **kw):
 
 
 def _flash_forward_shard(q, k, v, *, causal: bool, scale: float, block_q: int,
-                         block_k: int, interpret: bool, need_lse: bool = True):
+                         block_k: int, interpret: bool, need_lse: bool = True,
+                         window: Optional[int] = None):
     """Returns (out (B,T,H,D), lse (B*H, T, 128) f32 lane-broadcast, o32
     (B,T,H,D) float32).
 
@@ -175,10 +249,22 @@ def _flash_forward_shard(q, k, v, *, causal: bool, scale: float, block_q: int,
     kr = k.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
     vr = v.transpose(0, 2, 1, 3).reshape(b * h, tk, d)
 
-    kernel = functools.partial(
-        _flash_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, need_lse=need_lse,
-    )
+    if window is None:
+        kernel = functools.partial(
+            _flash_kernel, scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k, need_lse=need_lse,
+        )
+        nk, name = tk // block_k, "flash_fwd"
+        k_map = lambda bh, qi, ki: (bh, ki, 0)
+    else:
+        nk, _ = window_span(t, block_q, block_k, window)
+        kernel = functools.partial(
+            _flash_kernel, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, need_lse=need_lse, window=window, span=nk,
+            q_blocks=t // block_q)
+        name = "flash_window_fwd"
+        k_map = lambda bh, qi, j: (bh, jnp.maximum(_window_key_block(
+            qi, j, block_q, block_k, nk), 0), 0)
     out_shape = [jax.ShapeDtypeStruct(
         (b * h, t, d), jnp.float32 if need_lse else q.dtype)]
     out_specs = [pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0))]
@@ -193,11 +279,11 @@ def _flash_forward_shard(q, k, v, *, causal: bool, scale: float, block_q: int,
     res = pl.pallas_call(
         kernel,
         out_shape=out_shape,
-        grid=(b * h, t // block_q, tk // block_k),
+        grid=(b * h, t // block_q, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
+            pl.BlockSpec((1, block_k, d), k_map),
+            pl.BlockSpec((1, block_k, d), k_map),
         ],
         out_specs=out_specs,
         scratch_shapes=[
@@ -206,7 +292,7 @@ def _flash_forward_shard(q, k, v, *, causal: bool, scale: float, block_q: int,
             pltpu.VMEM((block_q, d), jnp.float32),    # output accumulator
         ],
         interpret=interpret,
-        name="flash_fwd",
+        name=name,
     )(qr, kr, vr)
     o = res[0].reshape(b, h, t, d).transpose(0, 2, 1, 3)
     if not need_lse:
@@ -216,7 +302,8 @@ def _flash_forward_shard(q, k, v, *, causal: bool, scale: float, block_q: int,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                dq_scr, *, scale: float, causal: bool, block_q: int,
-               block_k: int):
+               block_k: int, window: Optional[int] = None, span: int = 0,
+               q_blocks: int = 0):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -225,7 +312,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    visible = _block_visible(causal, qi, ki, block_q, block_k)
+    if window is None:
+        visible = _block_visible(causal, qi, ki, block_q, block_k)
+    else:
+        kb = _window_key_block(qi, ki, block_q, block_k, span)
+        visible = _window_visible(qi, kb, block_q, block_k, window,
+                                  q_blocks)
 
     @pl.when(visible)
     def _accum():
@@ -238,7 +330,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
-        if causal:
+        if window is not None:
+            s = _band_mask(s, qi, kb, block_q, block_k, window)
+        elif causal:
             s = _causal_mask(s, qi, ki, block_q, block_k)
         p = jnp.exp(s - lse)  # (bq, bk); masked entries -> 0
         dp = jax.lax.dot_general(
@@ -256,7 +350,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
-                causal: bool, block_q: int, block_k: int):
+                causal: bool, block_q: int, block_k: int,
+                window: Optional[int] = None, q_blocks: int = 0):
     ki = pl.program_id(1)  # note: k is the OUTER loop here
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
@@ -266,7 +361,12 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    visible = _block_visible(causal, qi, ki, block_q, block_k)
+    if window is None:
+        visible = _block_visible(causal, qi, ki, block_q, block_k)
+    else:
+        qb = _window_query_block(ki, qi, block_q, block_k)
+        visible = _window_visible(qb, ki, block_q, block_k, window,
+                                  q_blocks)
 
     @pl.when(visible)
     def _accum():
@@ -279,7 +379,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale
-        if causal:
+        if window is not None:
+            s = _band_mask(s, qb, ki, block_q, block_k, window)
+        elif causal:
             s = _causal_mask(s, qi, ki, block_q, block_k)
         p = jnp.exp(s - lse)  # (bq, bk)
         # dV += P^T dO
@@ -295,8 +397,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    # the last q block is on/below the diagonal for every k block, so the
-    # write step always executes
+    # a column's last step writes, whether its block was visited or skipped
     @pl.when(qi == nq - 1)
     def _write():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
@@ -312,7 +413,8 @@ def _flash_backward(q, k, v, o32, lse, g, *, delta_shift=None, **kw):
 
 def _flash_backward_shard(q, k, v, o32, lse, g, delta_shift=None, *,
                           causal: bool, scale: float, block_q: int,
-                          block_k: int, interpret: bool):
+                          block_k: int, interpret: bool,
+                          window: Optional[int] = None):
     b, t, h, d = q.shape
     tk = k.shape[1]
     block_q = min(block_q, t)
@@ -337,34 +439,55 @@ def _flash_backward_shard(q, k, v, o32, lse, g, delta_shift=None, *,
     delta = jnp.broadcast_to(delta_row, (b * h, t, 128))
 
     q_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0))
     row_spec = pl.BlockSpec((1, block_q, 128), lambda bh, qi, ki: (bh, qi, 0))
+    if window is None:
+        nk, nq = tk // block_k, t // block_q
+        dq_kernel = functools.partial(_dq_kernel, scale=scale, causal=causal,
+                                      block_q=block_q, block_k=block_k)
+        dkv_kernel = functools.partial(_dkv_kernel, scale=scale,
+                                       causal=causal, block_q=block_q,
+                                       block_k=block_k)
+        names = ("flash_bwd_dq", "flash_bwd_dkv")
+        k_map = lambda bh, qi, ki: (bh, ki, 0)
+        q_map2 = lambda bh, ki, qi: (bh, qi, 0)
+    else:
+        nk, nq = window_span(t, block_q, block_k, window)
+        last = t // block_q - 1
+        dq_kernel = functools.partial(
+            _dq_kernel, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, window=window, span=nk, q_blocks=last + 1)
+        dkv_kernel = functools.partial(
+            _dkv_kernel, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, window=window, q_blocks=last + 1)
+        names = ("flash_window_bwd_dq", "flash_window_bwd_dkv")
+        k_map = lambda bh, qi, j: (bh, jnp.maximum(_window_key_block(
+            qi, j, block_q, block_k, nk), 0), 0)
+        q_map2 = lambda bh, ki, j: (bh, jnp.minimum(_window_query_block(
+            ki, j, block_q, block_k), last), 0)
+    k_spec = pl.BlockSpec((1, block_k, d), k_map)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
+        dq_kernel,
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-        grid=(b * h, t // block_q, tk // block_k),
+        grid=(b * h, t // block_q, nk),
         in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-        name="flash_bwd_dq",
+        name=names[0],
     )(qr, kr, vr, dor, lse, delta)
 
     # swapped grid: k blocks outer, q blocks inner
-    q_spec2 = pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0))
+    q_spec2 = pl.BlockSpec((1, block_q, d), q_map2)
     k_spec2 = pl.BlockSpec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0))
-    row_spec2 = pl.BlockSpec((1, block_q, 128),
-                             lambda bh, ki, qi: (bh, qi, 0))
+    row_spec2 = pl.BlockSpec((1, block_q, 128), q_map2)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
+        dkv_kernel,
         out_shape=[
             jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
             jax.ShapeDtypeStruct((b * h, tk, d), v.dtype),
         ],
-        grid=(b * h, tk // block_k, t // block_q),
+        grid=(b * h, tk // block_k, nq),
         in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, row_spec2, row_spec2],
         out_specs=[k_spec2, k_spec2],
         scratch_shapes=[
@@ -372,7 +495,7 @@ def _flash_backward_shard(q, k, v, o32, lse, g, delta_shift=None, *,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name=names[1],
     )(qr, kr, vr, dor, lse, delta)
 
     unshape = lambda x, tt: x.reshape(b, h, tt, d).transpose(0, 2, 1, 3)
@@ -756,26 +879,27 @@ def _dense_reference(q, k, v, causal, scale):
     return jnp.einsum("bhts,bshd->bthd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, scale, block_q, block_k, interpret, window):
     # primal (inference) path: skip computing/writing the lse residual
     return _flash_forward(q, k, v, causal=causal, scale=scale,
                           block_q=block_q, block_k=block_k,
-                          interpret=interpret, need_lse=False)[0]
+                          interpret=interpret, need_lse=False,
+                          window=window)[0]
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, window):
     out, lse, o32 = _flash_forward(q, k, v, causal=causal, scale=scale,
                                    block_q=block_q, block_k=block_k,
-                                   interpret=interpret)
+                                   interpret=interpret, window=window)
     return out, (q, k, v, o32, lse)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res, g):
     q, k, v, o32, lse = res
     return _flash_backward(q, k, v, o32, lse, g, causal=causal, scale=scale,
                            block_q=block_q, block_k=block_k,
-                           interpret=interpret)
+                           interpret=interpret, window=window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -840,7 +964,7 @@ def flash_attention_with_lse(
 def flash_attention(
     q, k, v, *, causal: bool = False, scale: Optional[float] = None,
     block_q: int = 512, block_k: int = 1024,
-    interpret: Optional[bool] = None,
+    interpret: Optional[bool] = None, window: Optional[int] = None,
 ):
     """Fused attention. q: (B, Tq, H, D); k, v: (B, Tk, H, D).
 
@@ -848,12 +972,22 @@ def flash_attention(
     (O(T) memory), so the op is safe for long-sequence *training*, not just
     inference.
 
+    `window` W (with `causal`, over a query's own sequence: Tq = Tk) keeps
+    keys t - W + 1 .. t of query t, and the kernels visit only the blocks
+    that meet that band (`flash_window_fwd`, `flash_window_bwd_dq`,
+    `flash_window_bwd_dkv`); None is the whole causal or full range.
+
     `interpret=None` auto-selects: compiled on TPU, interpreter elsewhere
     (the CPU test path; `conftest.py` meshes run it interpreted).
     """
+    if window is not None and not (causal and q.shape[1] == k.shape[1]
+                                   and window >= 1):
+        raise ValueError("a window is a causal band over the query's own "
+                         f"sequence: causal={causal}, Tq={q.shape[1]}, "
+                         f"Tk={k.shape[1]}, window={window}")
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
         interpret = dvt_backend.pallas_interpret()
     return _flash(q, k, v, causal, float(scale), int(block_q), int(block_k),
-                  bool(interpret))
+                  bool(interpret), None if window is None else int(window))

@@ -207,7 +207,6 @@ def _sum_kernel(order_ref, lo_ref, hi_ref, *refs, mode: str, k: int,
     d = buf_hbm.shape[-1]
     c = _lanes(d)
     tt = out_ref.shape[0]
-    per = slots.shape[0] // n  # slots a held expert: its blocks in a tile
     tile = pl.program_id(0)
 
     def blocks(e):
@@ -219,26 +218,27 @@ def _sum_kernel(order_ref, lo_ref, hi_ref, *refs, mode: str, k: int,
                                         (hi - 1) // _SUB - first + 1, 0)
 
     def each_block(body):
-        """`body(e, j, lo, hi, first)` over the blocks of every held
-        expert, in the experts' order."""
-        def expert(e, carry):
+        """`body(slot, j, lo, hi, first)` over the blocks of every held
+        expert, in the experts' order: the tile's blocks take consecutive
+        slots."""
+        def expert(e, base):
             lo, hi, first, count = blocks(e)
 
-            def block(j, carry2):
-                body(e, j, lo, hi, first)
-                return carry2
+            def block(j, carry):
+                body(base + j, j, lo, hi, first)
+                return carry
 
             lax.fori_loop(0, count, block, 0)
-            return carry
+            return base + count
 
         lax.fori_loop(0, n, expert, 0)
 
-    def dma(e, j, first):
-        return _block(buf_hbm, (first + j) * _SUB, slots.at[e * per + j], sem)
+    def dma(slot, j, first):
+        return _block(buf_hbm, (first + j) * _SUB, slots.at[slot], sem)
 
-    each_block(lambda e, j, lo, hi, first: dma(e, j, first).start())
+    each_block(lambda slot, j, lo, hi, first: dma(slot, j, first).start())
     acc[...] = jnp.zeros_like(acc)
-    each_block(lambda e, j, lo, hi, first: dma(e, j, first).wait())
+    each_block(lambda slot, j, lo, hi, first: dma(slot, j, first).wait())
     sub = lax.broadcasted_iota(jnp.int32, (_SUB, c), 0)
     choice = lax.broadcasted_iota(jnp.int32, (_SUB, k), 1)
     at = lax.broadcasted_iota(jnp.int32, (_SUB, k), 0)
@@ -276,11 +276,11 @@ def _sum_kernel(order_ref, lo_ref, hi_ref, *refs, mode: str, k: int,
 
         _each_lane(d, add)
 
-    def rows_of_block(e, j, lo, hi, first):
+    def rows_of_block(slot, j, lo, hi, first):
         top = (first + j) * _SUB
 
         def one(p, carry):
-            pair(p, e * per + j)
+            pair(p, slot)
             return carry
 
         lax.fori_loop(jnp.maximum(top, lo), jnp.minimum(top + _SUB, hi), one,
@@ -308,6 +308,10 @@ def _sum_call(mode, buf, order, lo, hi, extra, *, tokens, k, dtype,
     elif mode == "dots":
         in_specs.append(pl.BlockSpec((tt, d), lambda i, *_: (i, 0)))
         args.append(extra.astype(jnp.float32))
+    # a tile's held rows, at most `tt k` (a held expert may hold several
+    # pairs of a token), lie in fewer than an eighth of them plus two
+    # aligned blocks an expert
+    blocks = tt * k // _SUB + 2 * n
     return pl.pallas_call(
         functools.partial(_sum_kernel, mode=mode, k=k, n=n),
         out_shape=jax.ShapeDtypeStruct((tokens, width), dtype),
@@ -316,11 +320,8 @@ def _sum_call(mode, buf, order, lo, hi, extra, *, tokens, k, dtype,
             grid=(tiles,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((tt, width), lambda i, *_: (i, 0)),
-            # a held expert holds at most one pair of a token: at most tt
-            # rows of a tile, in tt / 8 + 1 aligned blocks
             scratch_shapes=[pltpu.VMEM((tt, width), jnp.float32),
-                            pltpu.VMEM((n * (tt // _SUB + 1), _SUB, d),
-                                       buf.dtype),
+                            pltpu.VMEM((blocks, _SUB, d), buf.dtype),
                             pltpu.SemaphoreType.DMA(())]),
         compiler_params=_params(),
         interpret=interpret,

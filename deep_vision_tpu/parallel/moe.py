@@ -16,21 +16,25 @@ tokens per device per step; overflow tokens fall through the residual
 connection untouched — the standard Switch-Transformer semantics.
 
 `held_experts` is the other formulation: a chip's share of an expert
-layer whose router scores every expert with a sigmoid and picks the top
-`k` by score plus a correction bias (DeepSeek-V3's auxiliary-loss-free
-routing, the `n_routed_experts` / `norm_topk_prob` / `routed_scaling_
-factor` family), of which this chip holds `n` consecutive experts from
-`held_offset`, plus a shared expert every token passes through. No pair
-is dropped: the (token, choice) pairs are sorted so that the held
-experts' come first, grouped by expert, in a buffer of `T k` rows (every
-choice of every token may be held), and the grouped products
-(`megablox.gmm`, a Pallas kernel: its grid visits the tiles of the held
-groups alone, so its time follows the routed pairs, not the buffer) run
-over the held groups. Every other pass over the buffer (the dispatch, the
-experts' gate, the combine and their transposes) is one of
+layer whose router scores every expert, with a sigmoid and the top `k` by
+score plus a correction bias (DeepSeek-V3's auxiliary-loss-free routing,
+the `n_routed_experts` / `norm_topk_prob` / `routed_scaling_factor`
+family: `sigmoid_route`) or with a softmax and the top `k` by probability,
+renormalised (`softmax_route`, no bias), of which this chip holds `n`
+consecutive experts from `held_offset`, plus a shared expert every token
+passes through. No pair is dropped: the (token, choice) pairs are sorted
+so that the held experts' come first, grouped by expert, in a buffer of
+`T k` rows (every choice of every token may be held), and the grouped
+products (`megablox.gmm`, a Pallas kernel: its grid visits the tiles of
+the held groups alone, so its time follows the routed pairs, not the
+buffer) run over the held groups. Every other pass over the buffer (the
+dispatch, the experts' gate, the combine and their transposes) is one of
 `ops/pallas/moe_rows.py`'s, which visit its live tiles alone as well; the
 step's report counts those tiles beside the buffer's. What the absent
-experts would add is left out, as the chips that hold them would add it.
+experts would add is left out, as the chips that hold them would add it;
+or, with `stand_in`, each absent expert is computed by the held expert in
+its place on this chip (`(e - held_offset) mod n`), so that every choice
+has an output and the chip does its deployment's share of the pairs.
 """
 from __future__ import annotations
 
@@ -213,7 +217,7 @@ def moe_ffn_dense(router_w, expert_params, x, *,
     return picked * prob
 
 
-# -- a chip's share of a sigmoid-routed expert layer, no pair dropped --------
+# -- a chip's share of a routed expert layer, no pair dropped ----------------
 
 def sigmoid_route(x, kernel, bias, top_k: int, scaling: float = 1.0):
     """Scores `s = sigmoid(x W)` over every expert, in float32; the choice
@@ -225,6 +229,19 @@ def sigmoid_route(x, kernel, bias, top_k: int, scaling: float = 1.0):
     scores = jax.nn.sigmoid(logits)
     _, choice = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
     chosen = jnp.take_along_axis(scores, choice, axis=-1)
+    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scaling
+    return choice, weights
+
+
+def softmax_route(x, kernel, top_k: int, scaling: float = 1.0):
+    """Probabilities `p = softmax(x W)` over every expert, in float32; the
+    choice `top_k(p)`; the weights the chosen probabilities over their sum,
+    times `scaling`. x: (T, D); kernel: (D, E). -> (choice (T, k) int32,
+    weights (T, k) float32)."""
+    logits = jnp.dot(x.astype(jnp.float32), kernel.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    chosen, choice = jax.lax.top_k(probs, top_k)
     weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scaling
     return choice, weights
 
@@ -319,13 +336,17 @@ def _swiglu(x, gate, up, down):
 
 
 def held_experts(x, router, bias, experts, shared=None, *, top_k: int,
-                 held_offset: int = 0, scaling: float = 1.0):
-    """A chip's share of a sigmoid-routed expert layer (module docstring).
+                 held_offset: int = 0, scaling: float = 1.0,
+                 stand_in: bool = False):
+    """A chip's share of a routed expert layer (module docstring).
 
     x: (T, D) in the compute dtype; router: (D, E) over every expert; bias:
-    (E,) the correction bias; experts: {"gate", "up": (n, D, F), "down":
-    (n, F, D)}, the held experts `held_offset .. + n`; shared: {"gate",
-    "up": (D, F_s), "down": (F_s, D)} or None.
+    (E,) the correction bias of the sigmoid route (`sigmoid_route`), or
+    None for the softmax route (`softmax_route`); experts: {"gate", "up":
+    (n, D, F), "down": (n, F, D)}, the held experts `held_offset .. + n`;
+    shared: {"gate", "up": (D, F_s), "down": (F_s, D)} or None; stand_in:
+    expert `e` held elsewhere is computed by held expert `(e - held_offset)
+    mod n` (E a multiple of n), where it is otherwise left out.
     -> (y (T, D) in x's dtype, {"load": (E,) pairs each expert was chosen
     for, int32; "pairs": the pairs the held experts computed; "load_max":
     the largest held expert's; "live_tiles": the buffer's row tiles the
@@ -336,12 +357,20 @@ def held_experts(x, router, bias, experts, shared=None, *, top_k: int,
         "moe_sites_total", "Expert layers traced (held_experts)").inc()
     t, d = x.shape
     n, e = experts["gate"].shape[0], router.shape[-1]
+    if stand_in and e % n:
+        raise ValueError(f"stand_in: {e} experts are not whole copies of "
+                         f"the {n} held")
     with jax.named_scope("moe/route"):
-        choice, weights = sigmoid_route(x, router, bias, top_k, scaling)
+        if bias is None:
+            choice, weights = softmax_route(x, router, top_k, scaling)
+        else:
+            choice, weights = sigmoid_route(x, router, bias, top_k, scaling)
         load = jnp.sum(jax.nn.one_hot(choice, e, dtype=jnp.int32),
                        axis=(0, 1))
     with jax.named_scope("moe/dispatch"):
         local = choice.reshape(-1) - held_offset
+        if stand_in:
+            local = local % n
         held = (local >= 0) & (local < n)
         key = jnp.where(held, local, n)  # the held experts' groups, then rest
         pairs = t * top_k

@@ -4,11 +4,13 @@ gathers equal to the bit, the sums to float32 rounding (1e-6 of a norm;
 the limit 1e-5 stands far under a row missed or counted twice, order
 1e-1).
 
-Four buffers, each a case of every test: no live row; every row live (every
+Five buffers, each a case of every test: no live row; every row live (every
 choice held, `T k` a whole number of tiles); groups whose edges fall inside
-a tile; and the last again with every row the kernels must not read set to
+a tile; the last again with every row the kernels must not read set to
 NaN (the buffer's dead rows, the tokens no held pair names), where the
-result must stay finite and equal."""
+result must stay finite and equal; and every choice computed by the held
+expert in its place (`held_experts`' `stand_in`), so that a held expert
+holds up to three pairs of one token."""
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -20,7 +22,7 @@ T, K, E, HELD, D, F = 64, 4, 12, 4, 256, 128
 R = -(-T * K // moe_rows.TILE) * moe_rows.TILE
 TOL = 1e-5
 CASES = ["no_live_row", "every_row_live", "edges_inside_tiles",
-         "nan_in_dead_rows"]
+         "nan_in_dead_rows", "copies_of_the_held"]
 
 
 def apart(a, b):
@@ -37,9 +39,18 @@ def routing(case):
                            for _ in range(T)])
     elif case == "every_row_live":
         choice = np.stack([rs.permutation(HELD)[:K] for _ in range(T)])
+    elif case == "copies_of_the_held":
+        # half the tokens choose the three copies of one held expert
+        choice = np.stack([
+            np.r_[np.arange(rs.randint(HELD), E, HELD),
+                  rs.randint(HELD)][:K] if t % 2 else rs.permutation(E)[:K]
+            for t in range(T)])
+        choice[1::2, -1] = (choice[1::2, 0] + 1) % HELD
     else:
         choice = np.stack([rs.permutation(E)[:K] for _ in range(T)])
     local = choice.reshape(-1)
+    if case == "copies_of_the_held":
+        local = local % HELD
     held = local < HELD
     key = np.where(held, local, HELD)
     order = np.pad(np.argsort(key, kind="stable"), (0, R - T * K))
@@ -52,7 +63,12 @@ def routing(case):
     inv[order[:T * K]] = np.arange(T * K)
     live = int(sizes[:HELD].sum())
     assert (live == 0) == (case == "no_live_row")
-    assert (live == T * K == R) == (case == "every_row_live")
+    assert (live == T * K == R) == (case in ("every_row_live",
+                                             "copies_of_the_held"))
+    # the most pairs of one token in one held expert
+    held_of_token = np.eye(HELD + 1, dtype=int)[key].reshape(T, K, -1)
+    assert np.max(held_of_token.sum(1)[:, :HELD]) == (
+        3 if case == "copies_of_the_held" else int(live > 0))
     return {"order": jnp.asarray(order, jnp.int32), "live": live,
             "lo": jnp.asarray(lo, jnp.int32),
             "hi": jnp.asarray(lo + per, jnp.int32),
